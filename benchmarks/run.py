@@ -17,6 +17,8 @@ def main(argv=None) -> int:
                     help="comma-separated figure list, e.g. fig10,fig11")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks.common import CsvReport
     from benchmarks import (fig9_data_parallel, fig10_datastore,
                             fig11_ltfb_scaling, fig12_quality,

@@ -8,13 +8,14 @@
 # `--dry-run` prints the environment and command instead of running
 # (used by CI on runners without docker).
 #
-# Tuning (same recipe the paper's training clusters used — see
-# SNIPPETS.md and docs/deployment.md):
+# Tuning (see docs/deployment.md):
 #   * tcmalloc via LD_PRELOAD when present — glibc malloc arena churn
 #     slows XLA's large transient host allocations;
 #   * TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD raised so numpy buffers
 #     don't spam allocation warnings;
 #   * TF_CPP_MIN_LOG_LEVEL=4 silences XLA's C++ chatter;
+#   * JAX_PLATFORMS is left as given: JAX uses the accelerator the
+#     installation finds, and JAX_PLATFORMS=cpu asks for the CPU;
 #   * REPRO_HOST_DEVICES=N emulates N devices on CPU
 #     (--xla_force_host_platform_device_count) for mesh serving.
 set -eu
@@ -40,7 +41,6 @@ for lib in /usr/lib/x86_64-linux-gnu/libtcmalloc.so.4 \
 done
 export TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD="${TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD:-60000000000}"
 export TF_CPP_MIN_LOG_LEVEL="${TF_CPP_MIN_LOG_LEVEL:-4}"
-export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 
 HOST_DEVICES="${REPRO_HOST_DEVICES:-1}"
 case "${XLA_FLAGS:-}" in
@@ -53,7 +53,7 @@ if [ "$DRY_RUN" = 1 ]; then
     echo "  LD_PRELOAD=${LD_PRELOAD:-<none>}"
     echo "  TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD=$TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD"
     echo "  TF_CPP_MIN_LOG_LEVEL=$TF_CPP_MIN_LOG_LEVEL"
-    echo "  JAX_PLATFORMS=$JAX_PLATFORMS"
+    echo "  JAX_PLATFORMS=${JAX_PLATFORMS:-<unset>}"
     echo "  XLA_FLAGS=$XLA_FLAGS"
     echo "  exec: python -m $ENTRY $*"
     exit 0

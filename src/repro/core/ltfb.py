@@ -181,15 +181,13 @@ def make_ltfb_step(metric_fn: MetricFn, num_trainers: int,
         return (_unsqueeze0(new_params), jnp.reshape(m_local, (1,)),
                 jnp.reshape(m_other, (1,)))
 
-    from repro.parallel.sharding import shard_map_compat
-
-    shard_fn = shard_map_compat(
+    shard_fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(param_specs if param_specs is not None else in_spec,
                   batch_specs if batch_specs is not None else in_spec,
                   P()),
         out_specs=(param_specs if param_specs is not None else in_spec,
-                   in_spec, in_spec))
+                   in_spec, in_spec), check_vma=False)
     return jax.jit(shard_fn)
 
 

@@ -19,6 +19,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
+import jax
 import numpy as np
 
 from repro.core import ltfb
@@ -88,9 +89,11 @@ class Population:
 
         Wall time is attributed per trainer: ``data_wait_seconds`` is
         the slice of ``train_seconds`` spent blocked in ``loader()``
-        (prefetch stall), the rest is compute.  With ``telemetry`` set,
-        each step emits ``data_wait`` + ``step`` spans on the trainer's
-        trace row.
+        (prefetch stall), the rest is compute.  Each step blocks until
+        its new params are ready, so ``train_seconds`` and the spans
+        cover the device work and not only its dispatch.  With
+        ``telemetry`` set, each step emits ``data_wait`` + ``step``
+        spans on the trainer's trace row.
         """
         metrics = []
         tel = self.telemetry
@@ -107,6 +110,7 @@ class Population:
                 wait += w1 - w0
                 t.params, t.opt_state, m = self.fns.train_step(
                     t.params, t.opt_state, batch, t.hparams)
+                jax.block_until_ready(t.params)
                 t.steps += 1
                 if tel is not None:
                     tel.trainer_span("data_wait", i, w0, w1)
@@ -116,7 +120,6 @@ class Population:
             t.train_seconds += t1 - t0
             t.data_wait_seconds += wait
             if m is not None:
-                # forces the async dispatch, making the timing honest
                 t.last_metrics = {k: float(v) for k, v in m.items()}
             if tel is not None:
                 tel.trainer_span("train_round", i, t0, t1, phase=None,

@@ -269,17 +269,14 @@ class TournamentOrchestrator:
         if self._flops_probed or self.telemetry is None:
             return
         self._flops_probed = True
-        try:
-            from repro.train.telemetry import step_flops
-            t0 = self.population.trainers[0]
-            perm = self.stores[0].epoch_permutation(0)
-            batch = self.plan.adapt(
-                self.stores[0].get_batch(perm, 0, self.cfg.batch_size))
-            self._flops_per_step = step_flops(
-                self.fns.train_step, t0.params, t0.opt_state, batch,
-                t0.hparams)
-        except Exception:
-            self._flops_per_step = None
+        from repro.train.telemetry import step_flops
+        t0 = self.population.trainers[0]
+        perm = self.stores[0].epoch_permutation(0)
+        batch = self.plan.adapt(
+            self.stores[0].get_batch(perm, 0, self.cfg.batch_size))
+        self._flops_per_step = step_flops(
+            self.fns.train_step, t0.params, t0.opt_state, batch,
+            t0.hparams)
 
     def run(self, rounds: int, steps_per_round: int, ckpt_every: int = 0,
             log: Optional[Callable[[str], None]] = None) -> List[float]:
@@ -429,6 +426,7 @@ class TournamentOrchestrator:
             # training (uncommitted, default device) can proceed
             t.params = jax.tree.map(lambda x, i=i: np.asarray(x[i]), new_p)
             adopted = bool(m_other[i] < m_local[i])
+            t.tournament_metric = float(m_local[i])
             j = int(partner[i])
             log["metrics"].append((i, j, float(m_local[i]),
                                    float(m_other[i])))

@@ -25,11 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU-specific memory spaces; interpret mode works without them
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -107,10 +103,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         causal=causal, num_k_blocks=nk)
 
     scratch = [
-        _VMEM((block_q, D), jnp.float32) if _VMEM else
-        pl.MemorySpace.ANY,   # pragma: no cover (non-TPU build)
-        _VMEM((block_q,), jnp.float32) if _VMEM else pl.MemorySpace.ANY,
-        _VMEM((block_q,), jnp.float32) if _VMEM else pl.MemorySpace.ANY,
+        pltpu.VMEM((block_q, D), jnp.float32),
+        pltpu.VMEM((block_q,), jnp.float32),
+        pltpu.VMEM((block_q,), jnp.float32),
     ]
 
     return pl.pallas_call(

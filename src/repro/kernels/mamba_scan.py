@@ -29,11 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(dt_ref, xc_ref, bm_ref, cm_ref, a_ref, y_ref, h_ref, *,
@@ -76,7 +72,7 @@ def mamba_scan(dt: jax.Array, xc: jax.Array, bm: jax.Array, cm: jax.Array,
         chunk -= 1
     grid = (B, d_in // block_d, S // chunk)
 
-    scratch = [_VMEM((1, block_d, N), jnp.float32)] if _VMEM else []
+    scratch = [pltpu.VMEM((1, block_d, N), jnp.float32)]
 
     return pl.pallas_call(
         functools.partial(_kernel, chunk=chunk),

@@ -97,10 +97,11 @@ def paged_attention_sharded(mesh, q, k_pages, v_pages, tables, lengths,
         return _paged_attention_local(qs, ks, vs, local_t, ls,
                                       interpret=interpret)
 
-    return _sharding.shard_map_compat(
-        local, mesh,
+    return jax.shard_map(
+        local, mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec, P("data", None), P("data")),
-        out_specs=q_spec)(q, k_pages, v_pages, tables, lengths)
+        out_specs=q_spec, check_vma=False)(q, k_pages, v_pages, tables,
+                                           lengths)
 
 
 def paged_attention(q, k_pages, v_pages, tables, lengths,

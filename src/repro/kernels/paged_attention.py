@@ -11,9 +11,14 @@ for ``K >= 1`` query tokens per row — K = 1 is the classic decode step,
 K > 1 is the speculative-decoding verify step where the K queries of a
 row are consecutive positions of the same request:
 
-  * grid = (batch, kv_heads, table_width) with the page dimension
-    innermost, so the (K * group, head_dim) accumulator lives in VMEM
+  * grid = (batch, table_width) with the page dimension innermost, so
+    the (kv_heads, K * group, head_dim) accumulator lives in VMEM
     scratch across a request's page sweep;
+  * each grid step DMAs one whole page ``(block_size, kv_heads,
+    head_dim)`` and loops over kv heads statically: Mosaic requires a
+    block's last two dims to be (8, 128)-aligned or whole, so a block of
+    one kv head out of the (kv_heads, head_dim) minor dims is refused,
+    and so is a dynamic kv-head index into the page;
   * the block table and per-row sequence lengths ride in as
     **scalar-prefetch** operands (``pltpu.PrefetchScalarGridSpec``): the
     K/V BlockSpec index_map reads ``tables[b, p]`` to DMA the right
@@ -25,7 +30,7 @@ row are consecutive positions of the same request:
   * pages wholly past every query's reach are skipped via ``pl.when``
     (their table entries are the null page), and the tail page is
     masked positionwise;
-  * GQA is expressed by blocking q as (kv_heads, K * group) so q head
+  * GQA is expressed by laying q out as (kv_heads, K * group) so q head
     ``h*g+j`` meets kv head ``h`` without duplication.
 
 Numerics match ``repro.kernels.ref.paged_attention_ref``: online
@@ -40,17 +45,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-    _GRIDSPEC = pltpu.PrefetchScalarGridSpec
-except Exception:  # pragma: no cover (build without pallas.tpu)
-    # no functional fallback: scalar-prefetch index maps ARE the gather;
-    # callers without pallas.tpu must use kernels.ref.paged_attention_ref
-    # (which ops.paged_attention selects automatically off-TPU)
-    _VMEM = None
-    _GRIDSPEC = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -59,8 +54,8 @@ def _kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
             acc_ref, m_ref, l_ref, *, scale: float, block_size: int,
             group: int, k_tokens: int):
     b = pl.program_id(0)
-    p = pl.program_id(2)
-    num_p = pl.num_programs(2)
+    p = pl.program_id(1)
+    num_p = pl.num_programs(1)
     length = lengths_ref[b]
 
     @pl.when(p == 0)
@@ -73,34 +68,35 @@ def _kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
     # page or stale state — skip the compute, keep the accumulator
     @pl.when(p * block_size < length + k_tokens - 1)
     def _compute():
-        rows = q_ref.shape[2]                             # K * g
-        q = q_ref[0, 0].astype(jnp.float32) * scale       # (K*g, d)
-        k = k_ref[0, :, 0].astype(jnp.float32)            # (bs, d)
-        v = v_ref[0, :, 0].astype(jnp.float32)            # (bs, d)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        n_kv, rows, _ = acc_ref.shape                     # Hkv, K * g
         pos = p * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (rows, block_size), 1)
         # accumulator row r is query token r // group: it reaches
         # length + r // group tokens (intra-block causal staircase)
         reach = length + jax.lax.broadcasted_iota(
             jnp.int32, (rows, block_size), 0) // group
-        s = jnp.where(pos < reach, s, NEG_INF)            # tail-page mask
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        w = jnp.exp(s - m_new[:, None])
-        w = jnp.where(s <= NEG_INF / 2, 0.0, w)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(w, axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-            w, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        for h in range(n_kv):                             # static index
+            q = q_ref[0, h].astype(jnp.float32) * scale   # (K*g, d)
+            k = k_ref[0, :, h, :].astype(jnp.float32)     # (bs, d)
+            v = v_ref[0, :, h, :].astype(jnp.float32)     # (bs, d)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(pos < reach, s, NEG_INF)        # tail-page mask
+            m_prev = m_ref[h]                             # (K*g, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            w = jnp.exp(s - m_new)
+            w = jnp.where(s <= NEG_INF / 2, 0.0, w)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + jnp.sum(w, axis=1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+                w, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
 
     @pl.when(p == num_p - 1)
     def _finalize():
         denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
@@ -116,11 +112,6 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     lengths: (B,) int32 valid KV tokens for the FIRST query of each row
     (query t sees ``lengths[b] + t``).  Returns the same rank as ``q``.
     """
-    if _GRIDSPEC is None:  # pragma: no cover
-        raise RuntimeError(
-            "jax.experimental.pallas.tpu is unavailable in this build; "
-            "use repro.kernels.ref.paged_attention_ref (ops."
-            "paged_attention does this automatically off-TPU)")
     squeeze = q.ndim == 3
     if squeeze:
         q = q[:, None]
@@ -137,23 +128,18 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
 
     kernel = functools.partial(_kernel, scale=scale, block_size=bs,
                                group=g, k_tokens=K)
-    grid_spec = _GRIDSPEC(
+    page = pl.BlockSpec((1, bs, Hkv, D),
+                        lambda b, p, tbl, ln: (tbl[b, p], 0, 0, 0))
+    rows = pl.BlockSpec((1, Hkv, K * g, D), lambda b, p, tbl, ln: (b, 0, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hkv, W),
-        in_specs=[
-            pl.BlockSpec((1, 1, K * g, D),
-                         lambda b, h, p, tbl, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda b, h, p, tbl, ln: (tbl[b, p], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda b, h, p, tbl, ln: (tbl[b, p], 0, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, K * g, D),
-                               lambda b, h, p, tbl, ln: (b, h, 0, 0)),
+        grid=(B, W),
+        in_specs=[rows, page, page],
+        out_specs=rows,
         scratch_shapes=[
-            _VMEM((K * g, D), jnp.float32),
-            _VMEM((K * g,), jnp.float32),
-            _VMEM((K * g,), jnp.float32),
+            pltpu.VMEM((Hkv, K * g, D), jnp.float32),
+            pltpu.VMEM((Hkv, K * g, 1), jnp.float32),
+            pltpu.VMEM((Hkv, K * g, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(

@@ -27,11 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(gx_ref, r_ref, o_ref, h_ref, c_ref, n_ref, m_ref, *,
@@ -97,8 +93,7 @@ def slstm_scan(gx: jax.Array, r_h: jax.Array, block_b: int = 8,
         chunk -= 1
     grid = (B // block_b, S // chunk)
 
-    scratch = [_VMEM((block_b, d), jnp.float32) for _ in range(4)] \
-        if _VMEM else []
+    scratch = [pltpu.VMEM((block_b, d), jnp.float32) for _ in range(4)]
 
     return pl.pallas_call(
         functools.partial(_kernel, chunk=chunk, num_heads=H),

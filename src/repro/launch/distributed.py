@@ -14,7 +14,9 @@ Two modes in one CLI:
 
 * **spawn** (``--procs N``): pick a free coordinator port, launch N
   worker copies of this module, supervise them (first failure kills
-  the rest), exit with the workers' status;
+  the rest), exit with the workers' status.  The workers run on the
+  CPU, and the spawner refuses to start unless ``JAX_PLATFORMS=cpu``
+  is set: on an accelerator host each worker would open every chip;
 * **worker** (``--process-id I --num-processes N --coordinator
   HOST:PORT``): ``jax.distributed.initialize``, build the scheduler,
   run the request trace (host 0) or the replay loop (followers), and
@@ -22,8 +24,8 @@ Two modes in one CLI:
   ``.pI``) so token identity across processes is checkable from the
   outside.
 
-  python -m repro.launch.distributed --procs 2 --smoke \
-      --requests 4 --max-new 8 --out-json /tmp/dist.json
+  JAX_PLATFORMS=cpu python -m repro.launch.distributed --procs 2 \
+      --smoke --requests 4 --max-new 8 --out-json /tmp/dist.json
 """
 from __future__ import annotations
 
@@ -184,10 +186,16 @@ def spawn(args, argv: List[str]) -> int:
     from repro.launch.serve import parse_lens  # no jax at import time
     from repro.serve.mesh import parse_mesh
 
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        # every spawned worker would open every chip of this host
+        raise SystemExit(
+            "[dist] --procs spawns CPU workers on this host and needs "
+            "JAX_PLATFORMS=cpu set explicitly; on an accelerator host "
+            "start one worker per host yourself (--process-id, "
+            "--num-processes, --coordinator)")
     data, model = parse_mesh(args.mesh)
     _ = parse_lens(args.prompt_lens)    # fail fast on a bad trace spec
     env = dict(os.environ)
-    env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count="
                         f"{data * model}").strip()
@@ -260,6 +268,8 @@ def _spawn_once(args, passthrough: List[str], env: dict) -> int:
 def run_worker(args) -> int:
     """One worker process: initialize the process group, serve the
     trace (host 0) or replay plans (followers), write results."""
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import jax
 
     if args.num_processes > 1:

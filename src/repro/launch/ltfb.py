@@ -28,6 +28,7 @@ from repro.core.tournament import (
     TournamentConfig,
     TournamentOrchestrator,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.telemetry import (
     enable_json_logs,
     json_logs_enabled,
@@ -198,8 +199,12 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
-def main(argv=None) -> int:
-    """CLI entry point: parse args, run the LTFB tournament."""
+def run(argv=None) -> TournamentOrchestrator:
+    """Parse ``argv`` and run the LTFB tournament.
+
+    Returns the closed orchestrator: its population (params, wins,
+    adoptions) and :meth:`~TournamentOrchestrator.stats` stay readable.
+    """
     args = build_parser().parse_args(argv)
 
     if args.log_json:
@@ -293,6 +298,13 @@ def main(argv=None) -> int:
             gen.close()
         if server is not None:
             server.close()
+    return orch
+
+
+def main(argv=None) -> int:
+    """CLI entry point: parse args, run the LTFB tournament."""
+    enable_compile_cache()
+    run(argv)
     return 0
 
 

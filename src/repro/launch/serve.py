@@ -48,6 +48,7 @@ import numpy as np
 
 from repro.configs.registry import ARCHS, get_config
 from repro.data.tokens import token_stream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import telemetry as telemetry_mod
 from repro.serve.registry import ModelRegistry
 from repro.serve.scheduler import Request, Scheduler
@@ -282,7 +283,7 @@ def run_lm(args) -> Dict[str, object]:
         journal.close()
     out = {"stats": sched.stats.as_dict(), "pool": pd,
            "registry_step": registry.step if registry else None,
-           "results": results}
+           "results": results, "scheduler": sched}
     if arena is not None:
         out["arena"] = arena.snapshot()
     _maybe_write_json(args, out)
@@ -615,6 +616,7 @@ def build_parser() -> argparse.ArgumentParser:
 def main(argv=None) -> int:
     """CLI entry point: parse args, pick the workload, run it."""
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
 
     if args.log_json:
         telemetry_mod.enable_json_logs()

@@ -24,6 +24,7 @@ from repro.checkpoint import ckpt
 from repro.configs.base import MeshConfig, OptimizerConfig
 from repro.configs.registry import ARCHS, get_config
 from repro.data.tokens import train_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.parallel.sharding import tree_shardings, use_sharding
 from repro.train.steps import (init_lm_state, make_lm_eval_metric,
                                make_lm_train_step)
@@ -148,6 +149,7 @@ def build_parser():
 def main(argv=None):
     """CLI entry point: train the selected arch."""
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
 
     if args.arch == "icf-cyclegan":
         train_cyclegan(args)

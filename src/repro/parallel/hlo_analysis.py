@@ -348,12 +348,3 @@ def analyze_hlo(hlo: str, tags: Tuple[str, ...] = DEFAULT_TAGS
     for e in entries:
         walk(e, 1.0)
     return cost
-
-
-def xla_cost_analysis(compiled) -> Dict[str, float]:
-    """Version-portable ``compiled.cost_analysis()``: jax 0.4.x returns a
-    one-element list of dicts, newer jax returns the dict directly."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)

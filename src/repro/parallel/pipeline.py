@@ -99,9 +99,7 @@ def make_pipelined_forward(stage_fn: Callable, mesh: Mesh,
         out = pipeline_apply(params, x_mb, stage_fn, num_stages, axis)
         return out
 
-    from repro.parallel.sharding import shard_map_compat
-
-    return shard_map_compat(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(p_spec, x_spec if x_spec is not None else P()),
-        out_specs=P())
+        out_specs=P(), check_vma=False)

@@ -21,17 +21,6 @@ AxisName = Union[str, None]
 LogicalAxes = Tuple[AxisName, ...]
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs, check: bool = False):
-    """Version-portable shard_map: jax >= 0.6 exposes ``jax.shard_map``
-    (``check_vma``); jax 0.4.x has ``jax.experimental.shard_map``
-    (``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=check)
-
 # ---------------------------------------------------------------------------
 # Default logical -> physical rules
 # ---------------------------------------------------------------------------

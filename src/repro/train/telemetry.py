@@ -28,8 +28,8 @@ scraper for a train→serve→train deployment):
   (``--metrics-port``) for long runs.
 * :func:`efficiency_snapshot` / :func:`step_flops` — the paper's
   speedup/efficiency computed online from instrumented timings, in both
-  samples/s and model-FLOP/s terms (per-compiled-step FLOPs via the
-  ``parallel/hlo_analysis`` cost-analysis shim).
+  samples/s and model-FLOP/s terms (per-compiled-step FLOPs from XLA's
+  cost analysis).
 """
 from __future__ import annotations
 
@@ -195,20 +195,18 @@ def replay_genealogy(path: str) -> List[dict]:
 
 
 def step_flops(train_step, *example_args) -> Optional[float]:
-    """Per-compiled-step FLOPs via the XLA cost-analysis shim.
+    """Per-compiled-step FLOPs from XLA's cost analysis.
 
-    ``train_step`` must be a jitted callable; ``example_args`` are one
-    step's concrete arguments.  Returns None when the backend does not
-    expose cost analysis (the efficiency figures then stay in
-    samples/s only).
+    ``train_step`` is the step callable (jitted or not); ``example_args``
+    are one step's concrete arguments.  Returns None when the cost
+    analysis counts no FLOPs (the efficiency figures then stay in
+    samples/s only); a failure to lower or compile raises.
     """
-    try:
-        from repro.parallel.hlo_analysis import xla_cost_analysis
-        compiled = train_step.lower(*example_args).compile()
-        flops = xla_cost_analysis(compiled).get("flops")
-        return float(flops) if flops else None
-    except Exception:
-        return None
+    import jax
+
+    compiled = jax.jit(train_step).lower(*example_args).compile()
+    flops = compiled.cost_analysis().get("flops")
+    return float(flops) if flops else None
 
 
 def efficiency_snapshot(per_trainer: List[Dict[str, float]],

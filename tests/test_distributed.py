@@ -11,7 +11,7 @@ import sys
 
 import pytest
 
-from repro.launch.distributed import build_parser, find_free_port
+from repro.launch.distributed import build_parser, find_free_port, main
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -50,6 +50,14 @@ def test_build_parser_smoke():
         ["--procs", "2", "--step-timeout", "5"])
     assert args.procs == 2 and args.step_timeout == 5.0
     assert find_free_port() > 0
+
+
+def test_spawn_refuses_without_explicit_cpu(monkeypatch):
+    """Spawned workers would each open every chip of the host, so the
+    spawner starts nothing unless the CPU was asked for explicitly."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(SystemExit, match="JAX_PLATFORMS=cpu"):
+        main(["--procs", "2", "--smoke"])
 
 
 @needs_loopback

@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.parallel.hlo_analysis import analyze_hlo, xla_cost_analysis
+from repro.parallel.hlo_analysis import analyze_hlo
 
 
 def _compile(f, *specs):
@@ -21,7 +21,7 @@ def test_unrolled_matches_xla_cost_analysis():
     co = _compile(f, jax.ShapeDtypeStruct((5, 64, 64), jnp.float32),
                   jax.ShapeDtypeStruct((64, 64), jnp.float32))
     mine = analyze_hlo(co.as_text()).flops
-    xla = xla_cost_analysis(co).get("flops", 0.0)
+    xla = co.cost_analysis().get("flops", 0.0)
     assert abs(mine - xla) / xla < 0.05
 
 

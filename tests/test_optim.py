@@ -90,10 +90,9 @@ def body(gb, eb):
                                          "pod", 2)
     return mean["g"][None], err["g"][None]
 
-from repro.parallel.sharding import shard_map_compat
-f = jax.jit(shard_map_compat(body, mesh=mesh,
+f = jax.jit(jax.shard_map(body, mesh=mesh,
             in_specs=(P("pod"), P("pod")),
-            out_specs=(P("pod"), P("pod"))))
+            out_specs=(P("pod"), P("pod")), check_vma=False))
 mean_ref = np.asarray(jnp.mean(g, axis=0))
 out, err = f(g, e)
 out = np.asarray(out)

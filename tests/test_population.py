@@ -1,5 +1,7 @@
 """Population (host-orchestrated LTFB) behaviour tests on a tiny convex
 problem where tournament dynamics are analytically predictable."""
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -96,3 +98,32 @@ def test_state_dict_roundtrip():
         np.testing.assert_array_equal(np.asarray(a.params["w"]),
                                       np.asarray(b.params["w"]))
     assert pop2.round == pop.round
+
+
+class _SlowParams:
+    """A params leaf whose pending device work takes ``DELAY`` seconds."""
+
+    DELAY = 0.05
+
+    def __init__(self):
+        self.forced = 0
+
+    def block_until_ready(self):
+        time.sleep(self.DELAY)
+        self.forced += 1
+        return self
+
+
+def test_train_seconds_cover_the_forced_step():
+    def init(seed):
+        return {"w": _SlowParams()}, {}, {"lr": 0.1}
+
+    def train_step(params, opt_state, batch, hparams):
+        return params, opt_state, {"loss": 0.0}
+
+    pop = Population(TrainerFns(init, train_step, lambda p, b: 0.0),
+                     [lambda: {}] * 2, [[{}]] * 2)
+    pop.train_round(3)
+    for t in pop.trainers:
+        assert t.params["w"].forced == 3        # every step was forced
+        assert t.train_seconds >= 3 * _SlowParams.DELAY
