@@ -23,6 +23,7 @@ import jax
 import numpy as np
 
 from repro.core import ltfb
+from repro.train.telemetry import span
 
 Params = Any
 
@@ -91,41 +92,37 @@ class Population:
         the slice of ``train_seconds`` spent blocked in ``loader()``
         (prefetch stall), the rest is compute.  Each step blocks until
         its new params are ready, so ``train_seconds`` and the spans
-        cover the device work and not only its dispatch.  With
-        ``telemetry`` set, each step emits ``data_wait`` + ``step``
-        spans on the trainer's trace row.
+        cover the device work and not only its dispatch.  Each round
+        of a trainer is a ``train_round`` span around its ``data_wait``
+        + ``step`` spans: profiler annotations always, and on the
+        trainer's trace row with ``telemetry`` set.
         """
         metrics = []
         tel = self.telemetry
         for i, t in enumerate(self.trainers):
             if not t.alive:
                 continue
-            t0 = time.perf_counter()
             wait = 0.0
             m = None
-            for _ in range(steps):
-                w0 = time.perf_counter()
-                batch = t.loader()
-                w1 = time.perf_counter()
-                wait += w1 - w0
-                t.params, t.opt_state, m = self.fns.train_step(
-                    t.params, t.opt_state, batch, t.hparams)
-                jax.block_until_ready(t.params)
-                t.steps += 1
-                if tel is not None:
-                    tel.trainer_span("data_wait", i, w0, w1)
-                    tel.trainer_span("step", i, w1, time.perf_counter(),
-                                     step=t.steps)
-            t1 = time.perf_counter()
-            t.train_seconds += t1 - t0
+            with span(tel, "train_round", i, round=self.round,
+                      steps=steps) as rnd:
+                for _ in range(steps):
+                    with span(tel, "data_wait", i) as dw:
+                        batch = t.loader()
+                    wait += dw.seconds
+                    with span(tel, "step", i) as st:
+                        t.params, t.opt_state, m = self.fns.train_step(
+                            t.params, t.opt_state, batch, t.hparams)
+                        jax.block_until_ready(t.params)
+                        t.steps += 1
+                        st.args["step"] = t.steps
+            t.train_seconds += rnd.seconds
             t.data_wait_seconds += wait
             if m is not None:
                 t.last_metrics = {k: float(v) for k, v in m.items()}
             if tel is not None:
-                tel.trainer_span("train_round", i, t0, t1, phase=None,
-                                 round=self.round, steps=steps)
                 tel.add_phase("data_wait", wait)
-                tel.add_phase("compute", (t1 - t0) - wait)
+                tel.add_phase("compute", rnd.seconds - wait)
             metrics.append(m)
         return {"last_metrics": metrics}
 
@@ -148,7 +145,15 @@ class Population:
         evaluation is overlapped with the partner exchange
         (:func:`repro.core.ltfb.host_tournament_async`).
         """
-        t0 = time.perf_counter()
+        with span(self.telemetry, "tournament",
+                  round=self.round) as sp:
+            log = self._tournament(executor)
+            sp.args.update(exchanged=log["exchanged"],
+                           exchange_bytes=log["exchange_bytes"])
+        log["seconds"] = sp.seconds
+        return log
+
+    def _tournament(self, executor) -> Dict[str, Any]:
         alive = [t.alive for t in self.trainers]
         partner = ltfb.random_pairing(len(self.trainers), self.round,
                                       self.seed, alive)
@@ -172,13 +177,7 @@ class Population:
                                  for k, v in t.hparams.items()}
         self.round += 1
         log["partner"] = partner.tolist()
-        log["seconds"] = time.perf_counter() - t0
         log["pairing_seed"] = self.seed
-        if self.telemetry is not None:
-            self.telemetry.span("tournament", t0, time.perf_counter(),
-                                round=self.round - 1,
-                                exchanged=log["exchanged"],
-                                exchange_bytes=log["exchange_bytes"])
         return log
 
     def run(self, rounds: int, steps_per_round: int,

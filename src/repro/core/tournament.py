@@ -44,6 +44,7 @@ from repro.datastore.store import (
     partition_files,
 )
 from repro.telemetry import log_event
+from repro.train.telemetry import span
 
 
 @dataclass
@@ -248,13 +249,11 @@ class TournamentOrchestrator:
     def tournament(self) -> Dict[str, Any]:
         t0 = time.perf_counter()
         if self.cfg.backend == "mesh":
-            log = self._tournament_mesh()
-            if self.telemetry is not None:
-                self.telemetry.span("mesh_tournament", t0,
-                                    time.perf_counter(),
-                                    phase="tournament_eval",
-                                    round=self.population.round - 1,
-                                    exchange_bytes=log["exchange_bytes"])
+            with span(self.telemetry, "mesh_tournament",
+                      phase="tournament_eval",
+                      round=self.population.round) as sp:
+                log = self._tournament_mesh()
+                sp.args["exchange_bytes"] = log["exchange_bytes"]
         else:
             log = self.population.tournament(executor=self._executor)
         log.setdefault("seconds", time.perf_counter() - t0)
@@ -471,12 +470,13 @@ class TournamentOrchestrator:
         (keeping the best) the population."""
         if self.cfg.backend == "mesh" and not self._user_mesh:
             self._check_mesh_fits(new_k)
-        t0 = time.perf_counter()
-        self._teardown_data()
-        self._build_data(new_k)
-        info = self.population.resize(new_k, self._loader_fns,
-                                      self._tournament_batches,
-                                      clone_batch=self.val_batch)
+        with span(self.telemetry, "rescale") as sp:
+            self._teardown_data()
+            self._build_data(new_k)
+            info = self.population.resize(new_k, self._loader_fns,
+                                          self._tournament_batches,
+                                          clone_batch=self.val_batch)
+            sp.args.update(info)
         # pairing schedule and trainer-axis size both depend on K
         self._mesh_step = None
         if not self._user_mesh:
@@ -485,18 +485,16 @@ class TournamentOrchestrator:
         if self.genealogy is not None:
             self.genealogy.append("rescale", round=self.population.round,
                                   **info)
-        if self.telemetry is not None:
-            self.telemetry.span("rescale", t0, time.perf_counter(),
-                                **info)
         log_event("ltfb_rescale", round=self.population.round, **info)
 
     # -- checkpoint / restart -----------------------------------------------
     def save_checkpoint(self):
         assert self.cfg.ckpt_dir, "TournamentConfig.ckpt_dir not set"
-        t0 = time.perf_counter()
-        ckpt.save_population(self.cfg.ckpt_dir, self.population.round,
-                             self.population.state_dict())
-        dur = time.perf_counter() - t0
+        with span(self.telemetry, "checkpoint", phase="checkpoint",
+                  round=self.population.round) as sp:
+            ckpt.save_population(self.cfg.ckpt_dir, self.population.round,
+                                 self.population.state_dict())
+        dur = sp.seconds
         self.checkpoint_seconds += dur
         self.events["checkpoints"] += 1
         if self.genealogy is not None:
@@ -505,10 +503,6 @@ class TournamentOrchestrator:
                                   seconds=dur)
             # a checkpoint is a durability point for the ancestry too
             self.genealogy.sync()
-        if self.telemetry is not None:
-            self.telemetry.span("checkpoint", t0, time.perf_counter(),
-                                phase="checkpoint",
-                                round=self.population.round)
         log_event("ltfb_checkpoint", round=self.population.round,
                   seconds=dur)
 
@@ -522,20 +516,18 @@ class TournamentOrchestrator:
             return False
         t0 = self.population.trainers[0]
         like = {"params": t0.params, "opt_state": t0.opt_state}
-        w0 = time.perf_counter()
-        state = ckpt.restore_population(
-            self.cfg.ckpt_dir, step, like,
-            num_trainers=len(self.population.trainers))
-        self.population.load_state_dict(state)
-        dur = time.perf_counter() - w0
+        with span(self.telemetry, "restore", phase="restore",
+                  step=step) as sp:
+            state = ckpt.restore_population(
+                self.cfg.ckpt_dir, step, like,
+                num_trainers=len(self.population.trainers))
+            self.population.load_state_dict(state)
+        dur = sp.seconds
         self.restore_seconds += dur
         self.events["restores"] += 1
         if self.genealogy is not None:
             self.genealogy.append("resume", round=self.population.round,
                                   step=step, seconds=dur)
-        if self.telemetry is not None:
-            self.telemetry.span("restore", w0, time.perf_counter(),
-                                phase="restore", step=step)
         log_event("ltfb_resume", round=self.population.round, step=step,
                   seconds=dur)
         return True

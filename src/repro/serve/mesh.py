@@ -836,7 +836,9 @@ class MeshScheduler(Scheduler):
                        in self._pending_cancels
                        if self._cancel_now(rid, reason)]
             self._pending_cancels.clear()
-            admits = self._admission_phase()
+            with self.telemetry.span("admit") as sp:
+                admits = self._admission_phase()
+                sp.emit = bool(admits)
             plan = self.channel.broadcast(StepPlan(
                 winner=winner, admits=admits, submits=submits,
                 cancels=cancels, promote=promote))
@@ -863,22 +865,13 @@ class MeshScheduler(Scheduler):
             for rid, reason in plan.cancels:
                 self._cancel_now(rid, reason)
             self._replay_admissions(plan.admits)
-        tel = self.telemetry
-        had_pf = bool(self._pending_draft or self._pending_onepass
-                      or self.prefilling)
-        t0 = time.perf_counter()
-        self._prefill_phase()
-        t1 = time.perf_counter()
-        tel.phase("prefill", t0, t1, emit=had_pf)
-        had_dec = bool(self.active)
-        self._decode_phase()
-        tel.phase("decode", t1, time.perf_counter(), emit=had_dec)
+        self._device_phases()
         self._exchange_stats()
         self.stats.sample_step(len(self.queue),
                                len(self.active) + len(self.prefilling))
         self.stats.plan_retries = getattr(self.channel, "retries", 0)
         self._journal_step()
-        tel.step_end()
+        self.telemetry.step_end()
         return plan
 
     def _exchange_stats(self) -> None:

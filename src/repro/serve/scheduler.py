@@ -446,14 +446,17 @@ class Scheduler:
         bucket = self._bucket(req.prompt_len,
                               can_pad=self._draft_can_pad) \
             if self._draft_can_pad else None
-        self.draft.prefill(req.rid, req.prompt, bucket=bucket)
+        with self.telemetry.span("prefill.dispatch"):
+            self.draft.prefill(req.rid, req.prompt, bucket=bucket)
 
     def _prefill_dense(self, act: _Active) -> None:
         req = act.req
         P = req.prompt_len
         bucket = self._bucket(P)
         t0 = time.perf_counter()
-        last = self.session.prefill(req.rid, req.prompt, bucket=bucket)
+        with self.telemetry.span("prefill.dispatch"):
+            last = self.session.prefill(req.rid, req.prompt, bucket=bucket)
+        last = self._fetch_rows(last)
         self.telemetry.req_span(req.rid, "prefill", t0, time.perf_counter(),
                                 tokens=P, bucket=bucket)
         self.stats.prefills += 1
@@ -467,7 +470,9 @@ class Scheduler:
         req = act.req
         P = req.prompt_len
         t0 = time.perf_counter()
-        last = self.session.prefill(req.rid, req.prompt, bucket=None)
+        with self.telemetry.span("prefill.dispatch"):
+            last = self.session.prefill(req.rid, req.prompt, bucket=None)
+        last = self._fetch_rows(last)
         self.telemetry.req_span(req.rid, "prefill", t0, time.perf_counter(),
                                 tokens=P)
         self.stats.prefills += 1
@@ -486,6 +491,7 @@ class Scheduler:
             done += 1
 
     def _prefill_chunk_once(self, act: _Active) -> None:
+        tel = self.telemetry
         req = act.req
         P = req.prompt_len
         # one-shot (prefill_chunk=0) still buckets the chunk size, so a
@@ -496,15 +502,19 @@ class Scheduler:
         final = act.pf_pos + n >= P
         Cb = chunk if (not final or n == chunk) \
             else self._bucket(n, cap=chunk)
-        self.pool.ensure(req.rid, act.pf_pos + n)
+        with tel.span("kv.ensure"):
+            self.pool.ensure(req.rid, act.pf_pos + n)
         W = self._table_bucket(act.pf_pos + n)
         t0 = time.perf_counter()
-        last = self.session.prefill_chunk(
-            req.rid, req.prompt[act.pf_pos:act.pf_pos + n],
-            hist_len=act.pf_pos, prompt_len=P, chunk_bucket=Cb, width=W)
-        self.telemetry.req_span(
-            req.rid, "prefill_chunk", t0, time.perf_counter(),
-            tokens=n, pos=act.pf_pos, prompt_len=P)
+        with tel.span("prefill.dispatch"):
+            last = self.session.prefill_chunk(
+                req.rid, req.prompt[act.pf_pos:act.pf_pos + n],
+                hist_len=act.pf_pos, prompt_len=P, chunk_bucket=Cb,
+                width=W)
+        if final:
+            last = self._fetch_rows(last)
+        tel.req_span(req.rid, "prefill_chunk", t0, time.perf_counter(),
+                     tokens=n, pos=act.pf_pos, prompt_len=P)
         act.pf_pos += n
         self.stats.prefills += 1
         self.stats.prefill_chunks += 1
@@ -514,7 +524,8 @@ class Scheduler:
             # pages fully covered by prefilled prompt tokens are
             # immutable from here on — offer them to future admissions
             # immediately, not only when the whole prompt is done
-            self.pool.register_prefix(req.rid, req.prompt[:act.pf_pos])
+            with tel.span("kv.register_prefix"):
+                self.pool.register_prefix(req.rid, req.prompt[:act.pf_pos])
         if final:
             del self.prefilling[req.rid]
             self._start_decoding(act, last)
@@ -532,6 +543,18 @@ class Scheduler:
             req.rid, "first_token", t=act.first_token_t,
             ttft_s=act.first_token_t - act.submit_t)
         self._accept_token(act, tok)
+
+    def _fetch_rows(self, logits) -> np.ndarray:
+        """Copy device logits to the host as float32, in two spans:
+        ``device_wait`` while the device finishes them (the host waits,
+        the device works) and ``logits_fetch`` for the copy itself (the
+        device has nothing queued).  ``np.asarray`` would block once
+        for both; the split adds no synchronisation."""
+        tel = self.telemetry
+        with tel.span("device_wait"):
+            logits = logits.astype(jnp.float32).block_until_ready()
+        with tel.span("logits_fetch"):
+            return np.asarray(logits)
 
     def _sample(self, logits_row, req: Request, ntok: int) -> int:
         """logits_row: (V,) host array.  Sampling stays on host (Gumbel
@@ -847,24 +870,22 @@ class Scheduler:
                 self._decode_round()
 
     def _timed_phases(self) -> None:
-        """Run admission → prefill → decode with per-phase wall-time
-        attribution (``telemetry.phase_seconds`` + step-timeline spans;
-        spans are emitted only for phases that had work)."""
+        """Run admission → prefill → decode, each in its phase span."""
+        with self.telemetry.span("admit") as sp:
+            sp.emit = bool(self._admission_phase())
+        self._device_phases()
+
+    def _device_phases(self) -> None:
+        """Run prefill → decode, each in its phase span (in the
+        profiler trace always; in the ring only when it had work)."""
         tel = self.telemetry
-        t0 = time.perf_counter()
-        admitted = self._admission_phase()
-        t1 = time.perf_counter()
-        tel.phase("admit", t0, t1, emit=bool(admitted))
-        had_pf = bool(self._pending_draft or self._pending_onepass
-                      or self.prefilling)
-        t0 = t1
-        self._prefill_phase()
-        t1 = time.perf_counter()
-        tel.phase("prefill", t0, t1, emit=had_pf)
-        had_dec = bool(self.active)
-        t0 = t1
-        self._decode_phase()
-        tel.phase("decode", t0, time.perf_counter(), emit=had_dec)
+        with tel.span("prefill") as sp:
+            sp.emit = bool(self._pending_draft or self._pending_onepass
+                           or self.prefilling)
+            self._prefill_phase()
+        with tel.span("decode") as sp:
+            sp.emit = bool(self.active)
+            self._decode_phase()
 
     def profile_steps(self, steps: int, outdir: str) -> None:
         """Arm ``jax.profiler`` around the next ``steps`` scheduler
@@ -878,8 +899,9 @@ class Scheduler:
         — one batched write + fsync (see ``repro.serve.journal``)."""
         if self.journal is None:
             return
-        self.journal.step_commit(self._journal_tokens,
-                                 self._journal_finished)
+        with self.telemetry.span("journal"):
+            self.journal.step_commit(self._journal_tokens,
+                                     self._journal_finished)
         self._journal_tokens = {}
         self._journal_finished = []
 
@@ -937,11 +959,25 @@ class Scheduler:
 
     _SPLIT_RATIO = 4
 
+    def _sample_and_accept(self, picks: List[tuple]) -> None:
+        """One token for each ``(active request, its logits row)``:
+        ``sample`` them all, then ``accept`` them (finishing frees the
+        slot in place)."""
+        tel = self.telemetry
+        with tel.span("sample", rows=len(picks)):
+            toks = [self._sample(row, act.req, act.ntok)
+                    for act, row in picks]
+        with tel.span("accept"):
+            for (act, _), tok in zip(picks, toks):
+                self._accept_token(act, tok)
+
     def _decode_round(self) -> None:
+        tel = self.telemetry
         if self.paged:
             targets = {a.slot: int(self._index[a.slot])
                        for a in self.active.values()}
-            self._ensure_decode_pages(self.pool, targets)
+            with tel.span("kv.ensure"):
+                self._ensure_decode_pages(self.pool, targets)
             groups = self._width_split()
         else:
             groups = [(0, None)]
@@ -949,36 +985,34 @@ class Scheduler:
         if len(groups) == 1:
             # common path: one full-batch dispatch
             width = groups[0][0] if self.paged else None
-            logits = self.session.step(self._next_token[:, None],
-                                       self._index, width=width)
-            rows = np.asarray(logits.astype(jnp.float32))
+            with tel.span("decode.dispatch"):
+                logits = self.session.step(self._next_token[:, None],
+                                           self._index, width=width)
+            rows = self._fetch_rows(logits)
             self.stats.decode_slot_steps += self.pool.num_slots
-            # sample per active slot; finishing frees the slot in-place
-            for act in list(self.active.values()):
-                tok = self._sample(rows[act.slot, 0], act.req, act.ntok)
-                self._accept_token(act, tok)
+            self._sample_and_accept([(a, rows[a.slot, 0])
+                                     for a in self.active.values()])
             return
         # ragged split: one subset dispatch per width group (row counts
         # pow2-bucketed so the compile count stays logarithmic)
         null = self.pool.null_page
         for W, slots in groups:
             n = min(_next_pow2(len(slots)), self.pool.num_slots)
-            tokens = np.zeros((n, 1), np.int32)
-            index = np.full((n,), -1, np.int32)
-            tables = np.full((n, W), null, np.int32)
-            for i, s in enumerate(slots):
-                tokens[i, 0] = self._next_token[s]
-                index[i] = self._index[s]
-                tables[i] = self.pool.tables[s, :W]
-            logits = self.session.step(tokens, index, tables=tables)
-            rows = np.asarray(logits.astype(jnp.float32))
+            with tel.span("decode.dispatch"):
+                tokens = np.zeros((n, 1), np.int32)
+                index = np.full((n,), -1, np.int32)
+                tables = np.full((n, W), null, np.int32)
+                for i, s in enumerate(slots):
+                    tokens[i, 0] = self._next_token[s]
+                    index[i] = self._index[s]
+                    tables[i] = self.pool.tables[s, :W]
+                logits = self.session.step(tokens, index, tables=tables)
+            rows = self._fetch_rows(logits)
             self.stats.decode_slot_steps += n
             self.stats.ragged_splits += 1
-            for i, s in enumerate(slots):
-                act = self._by_slot.get(s)
-                if act is not None:
-                    tok = self._sample(rows[i, 0], act.req, act.ntok)
-                    self._accept_token(act, tok)
+            self._sample_and_accept([(self._by_slot[s], rows[i, 0])
+                                     for i, s in enumerate(slots)
+                                     if s in self._by_slot])
 
     # -- speculative decode --------------------------------------------------
     def _spec_round(self) -> None:
@@ -1013,6 +1047,7 @@ class Scheduler:
         block — a replay write for attention KV, restore + replay for
         recurrent state.
         """
+        tel = self.telemetry
         B = self.pool.num_slots
         acts = list(self.active.values())
         t_rec = self.pool.has_recurrent
@@ -1030,8 +1065,9 @@ class Scheduler:
         if self.paged:
             targets = {a.slot: int(base[a.slot]) + int(cap[a.slot]) - 1
                        for a in acts}
-            self._ensure_decode_pages(self.pool, targets)
-            self._ensure_decode_pages(self.draft.layout, targets)
+            with tel.span("kv.ensure"):
+                self._ensure_decode_pages(self.pool, targets)
+                self._ensure_decode_pages(self.draft.layout, targets)
             W = self._table_bucket(int((base + cap).max()))
         else:
             W = None
@@ -1040,78 +1076,89 @@ class Scheduler:
         ntok0 = {act.slot: act.ntok for act in acts}
 
         d_snap = self.draft.snapshot() if d_rec else ()
-        t_draft = time.perf_counter()
-        if self.spec_fused:
-            # -- fused draft: ONE dispatch for the whole block
-            dlogits, dev = self.draft.draft_block(
-                self._next_token[:, None], base, Kv, valid=cap, width=W)
-            drows = np.asarray(dlogits.astype(jnp.float32))  # (B, Kv, V)
-            dev = np.asarray(dev)                            # (B, Kv)
-            self.stats.spec_draft_steps += 1
-            for act in acts:
-                s = act.slot
-                for t in range(int(cap[s]) - 1):
-                    block[s, t + 1] = self._sample(drows[s, t], act.req,
-                                                   ntok0[s] + t)
-        else:
-            # -- sequential draft: Kv single-token steps (the last
-            # feeds the final proposal so drafter and target caches
-            # stay aligned when everything is accepted)
-            for t in range(Kv):
-                valid_t = (cap > t).astype(np.int32)
-                idx_t = np.where(self._index >= 0, base + t,
-                                 self._idle_index).astype(np.int32)
-                logits = self.draft.step(block[:, t:t + 1], idx_t,
-                                         valid=valid_t, width=W)
+        with tel.span("draft", k=Kv - 1):
+            if self.spec_fused:
+                # -- fused draft: ONE dispatch for the whole block
+                with tel.span("decode.dispatch"):
+                    dlogits, dev = self.draft.draft_block(
+                        self._next_token[:, None], base, Kv, valid=cap,
+                        width=W)
+                drows = self._fetch_rows(dlogits)           # (B, Kv, V)
+                dev = np.asarray(dev)                       # (B, Kv)
                 self.stats.spec_draft_steps += 1
-                if t + 1 >= Kv:
-                    break
-                rows = np.asarray(logits.astype(jnp.float32))
-                for act in acts:
-                    s = act.slot
-                    if t + 1 < cap[s]:
-                        block[s, t + 1] = self._sample(rows[s, 0], act.req,
-                                                       ntok0[s] + t)
-            dev = block          # the drafter was fed the host block
-        t_verify = time.perf_counter()
-        self.telemetry.phase("draft", t_draft, t_verify, k=Kv - 1)
+                with tel.span("sample", rows=len(acts)):
+                    for act in acts:
+                        s = act.slot
+                        for t in range(int(cap[s]) - 1):
+                            block[s, t + 1] = self._sample(
+                                drows[s, t], act.req, ntok0[s] + t)
+            else:
+                # -- sequential draft: Kv single-token steps (the last
+                # feeds the final proposal so drafter and target caches
+                # stay aligned when everything is accepted)
+                for t in range(Kv):
+                    valid_t = (cap > t).astype(np.int32)
+                    idx_t = np.where(self._index >= 0, base + t,
+                                     self._idle_index).astype(np.int32)
+                    with tel.span("decode.dispatch"):
+                        logits = self.draft.step(block[:, t:t + 1], idx_t,
+                                                 valid=valid_t, width=W)
+                    self.stats.spec_draft_steps += 1
+                    if t + 1 >= Kv:
+                        break
+                    rows = self._fetch_rows(logits)
+                    with tel.span("sample", rows=len(acts)):
+                        for act in acts:
+                            s = act.slot
+                            if t + 1 < cap[s]:
+                                block[s, t + 1] = self._sample(
+                                    rows[s, 0], act.req, ntok0[s] + t)
+                dev = block          # the drafter was fed the host block
 
         # -- target: verify the whole block in one K-token step
-        t_snap = self.session.snapshot() if t_rec else ()
-        vlogits = self.session.step(block, base, valid=cap, width=W)
-        rows = np.asarray(vlogits.astype(jnp.float32))   # (B, Kv, V)
-        self.telemetry.phase("verify", t_verify, time.perf_counter(), k=Kv)
+        with tel.span("verify", k=Kv):
+            t_snap = self.session.snapshot() if t_rec else ()
+            with tel.span("decode.dispatch"):
+                vlogits = self.session.step(block, base, valid=cap, width=W)
+            rows = self._fetch_rows(vlogits)                # (B, Kv, V)
         self.stats.decode_steps += 1
         self.stats.spec_rounds += 1
         self.stats.decode_slot_steps += B
 
-        # -- acceptance: longest matching prefix + one target token
+        # -- acceptance: longest matching prefix + one target token.
+        # The target's tokens per row are drawn first (up to the first
+        # that differs from the proposal, an EOS or the row's budget),
+        # then accepted
+        with tel.span("sample", rows=len(acts)):
+            drawn: Dict[int, List[int]] = {}
+            for act in acts:
+                s = act.slot
+                c = min(int(cap[s]), act.req.max_new - act.ntok)
+                toks = drawn[s] = []
+                for t in range(c):
+                    g = self._sample(rows[s, t], act.req, ntok0[s] + t)
+                    toks.append(g)
+                    if g == act.req.eos_id or t + 1 >= c \
+                            or g != int(block[s, t + 1]):
+                        break
         fed_valid = np.zeros((B,), np.int32)
-        for act in acts:
-            s = act.slot
-            c = int(cap[s])
-            n0 = ntok0[s]
-            appended = 0
-            for t in range(c):
-                g = self._sample(rows[s, t], act.req, n0 + t)
-                self._accept_token(act, g)               # may finish
-                appended += 1
-                if act.req.rid not in self.active:
-                    break
-                if t + 1 >= c or g != int(block[s, t + 1]):
-                    break
-            fed_valid[s] = appended
-            offered = max(0, c - 1)
-            accepted = max(0, appended - 1)
-            self.stats.spec_draft_proposed += offered
-            self.stats.spec_draft_accepted += accepted
-            if self.arena is not None:
-                self.arena.record_spec(offered, accepted)
-            if offered:
-                self.stats.spec_k_sum += offered
-                self.stats.spec_k_rows += 1
-                if self.spec_adapt:
-                    self._adapt_depth(act, offered, accepted)
+        with tel.span("accept"):
+            for act in acts:
+                s = act.slot
+                for g in drawn[s]:
+                    self._accept_token(act, g)           # may finish
+                fed_valid[s] = appended = len(drawn[s])
+                offered = max(0, int(cap[s]) - 1)
+                accepted = max(0, appended - 1)
+                self.stats.spec_draft_proposed += offered
+                self.stats.spec_draft_accepted += accepted
+                if self.arena is not None:
+                    self.arena.record_spec(offered, accepted)
+                if offered:
+                    self.stats.spec_k_sum += offered
+                    self.stats.spec_k_rows += 1
+                    if self.spec_adapt:
+                        self._adapt_depth(act, offered, accepted)
 
         # -- rollback
         rb_t = np.zeros((B,), bool)
@@ -1134,12 +1181,14 @@ class Scheduler:
                 rep_d[s] = fed
         if t_rec and rb_t.any():
             self.session.restore(t_snap, rb_t)
-            self.session.step(block, base, valid=rep_t, width=W)
+            with tel.span("decode.dispatch"):
+                self.session.step(block, base, valid=rep_t, width=W)
             self.stats.spec_replays += 1
         if rb_d.any():
             if d_rec:
                 self.draft.restore(d_snap, rb_d)
-            self.draft.step(block, base, valid=rep_d, width=W)
+            with tel.span("decode.dispatch"):
+                self.draft.step(block, base, valid=rep_d, width=W)
             self.stats.spec_replays += 1
 
     def _adapt_depth(self, act: _Active, offered: int,

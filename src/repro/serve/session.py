@@ -156,13 +156,14 @@ class DecodeSession:
 
     # -- prefill -----------------------------------------------------------
     def prefill(self, rid, prompt: np.ndarray,
-                bucket: Optional[int] = None) -> np.ndarray:
+                bucket: Optional[int] = None) -> jax.Array:
         """Full-prompt prefill for ONE request, written into its
         slot/pages.  ``bucket`` right-pads the prompt to a shape bucket
         (attention-only stacks; logits still read at the true last
         token); None prefills at exact length (recurrent families —
         padding would poison their state).  Returns the last-token
-        logits row (V,) as float32 on host.
+        logits row (V,) as float32, still on device (the caller copies
+        it to the host when it needs it).
         """
         P = int(len(prompt))
         L = bucket or P
@@ -175,7 +176,7 @@ class DecodeSession:
             self.layout.insert_prefill(rid, cache, P)
         else:
             self.layout.insert(rid, cache)
-        return np.asarray(logits[0, -1].astype(jnp.float32))
+        return logits[0, -1].astype(jnp.float32)
 
     def prefill_batch(self, tokens: jax.Array) -> jax.Array:
         """Uniform-length batch prefill filling EVERY slot row (the
@@ -187,14 +188,15 @@ class DecodeSession:
 
     def prefill_chunk(self, rid, chunk: np.ndarray, hist_len: int,
                       prompt_len: int, chunk_bucket: int,
-                      width: int) -> np.ndarray:
+                      width: int) -> jax.Array:
         """One chunked-prefill slice scattered into `rid`'s pages.
 
         chunk: the real tokens of this slice (right-padded to
         ``chunk_bucket`` here); hist_len: prompt tokens already
         prefilled; width: block-table columns to expose (pow2-bucketed
         by the caller).  Returns the slice's last-real-token logits row
-        (V,) — only meaningful on the final slice.
+        (V,) as float32, still on device — only meaningful on the final
+        slice, so only then worth copying to the host.
         """
         n = int(len(chunk))
         toks = np.zeros((1, chunk_bucket), np.int32)
@@ -205,7 +207,7 @@ class DecodeSession:
             jnp.asarray(self.layout.tables[slot:slot + 1, :width]),
             jnp.int32(hist_len), jnp.int32(prompt_len),
             jnp.asarray([n - 1], jnp.int32))
-        return np.asarray(logits[0, -1].astype(jnp.float32))
+        return logits[0, -1].astype(jnp.float32)
 
     # -- decode ------------------------------------------------------------
     def step(self, tokens: np.ndarray, index: np.ndarray,

@@ -113,10 +113,9 @@ class SurrogateEngine:
         """Block on the in-flight forward and distribute its results."""
         taken, rows, padded, y = self._pending
         self._pending = None
-        tc = time.perf_counter()
-        y = np.asarray(y.astype(jnp.float32))
+        with self.telemetry.span("surrogate_collect", rows=rows):
+            y = np.asarray(y.astype(jnp.float32))
         now = time.perf_counter()
-        self.telemetry.phase("surrogate_collect", tc, now, rows=rows)
         off = 0
         for rid, q, t0 in taken:
             n = q.shape[0]

@@ -9,9 +9,11 @@ This module is the observability substrate for the serving stack:
   gateway's ``GET /debug/trace`` export the buffer as Chrome-trace /
   Perfetto JSON so one file explains any slow request.
 * :class:`ServeTelemetry` — the per-scheduler façade: owns the tracer,
-  accumulates per-phase wall time (``prefill`` / ``decode`` / ``draft``
-  / ``verify`` …), and arms :func:`jax.profiler.start_trace` around a
-  step window (``POST /debug/profile`` / ``--profile-steps``).
+  opens the step thread's spans (:meth:`ServeTelemetry.span`: each in
+  the profiler trace and the ring, its wall time accumulated per phase
+  for ``admit`` / ``prefill`` / ``decode`` / ``draft`` / ``verify``),
+  and arms :func:`jax.profiler.start_trace` around a step window
+  (``POST /debug/profile`` / ``--profile-steps``).
 * :func:`prometheus_text` / :func:`scheduler_prometheus` — Prometheus
   text-format (0.0.4) exposition of every ``[serve]`` counter, the
   bounded latency histograms, per-``data``-shard page-pool occupancy,
@@ -31,12 +33,11 @@ import this module freely.
 
 from __future__ import annotations
 
-import time
-from contextlib import contextmanager
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Dict, List, Optional
 
 from repro.telemetry import (  # noqa: F401  (re-exported surface)
     SCHED_TID,
+    Span,
     Tracer,
     enable_json_logs,
     json_logs_enabled,
@@ -57,22 +58,27 @@ __all__ = [
     "log_event",
 ]
 
+# the step phases whose wall time ``phase_seconds`` accumulates (and
+# ``phase_seconds_total{phase=...}`` exports); every other span is a
+# part of one of them
+PHASES = frozenset({"admit", "prefill", "decode", "draft", "verify",
+                    "surrogate_collect"})
+
 
 class ServeTelemetry:
-    """Per-scheduler telemetry: tracer + phase attribution + profiler.
+    """Per-scheduler telemetry: tracer + step spans + profiler.
 
-    ``enabled=False`` turns tracing and phase spans into no-ops (the
-    cheap counters in :class:`~repro.serve.metrics.ServeStats` stay on);
-    the profiler window works regardless so ``--profile-steps`` composes
-    with ``--no-telemetry``.
+    ``enabled=False`` keeps spans out of the ring and drops the request
+    events (the cheap counters in :class:`~repro.serve.metrics.ServeStats`
+    and ``phase_seconds`` stay on); the profiler annotations and window
+    work regardless, so ``--profile-steps`` composes with
+    ``--no-telemetry``.
     """
 
     def __init__(self, enabled: bool = True, trace_capacity: int = 8192):
         self.enabled = bool(enabled)
         self.tracer = Tracer(trace_capacity)
-        # cumulative wall seconds per phase: prefill / decode / draft /
-        # verify / admit — the step-timeline attribution profiler runs
-        # are cross-checked against
+        # cumulative wall seconds per phase (PHASES), for /metrics
         self.phase_seconds: Dict[str, float] = {}
         self.phase_calls: Dict[str, int] = {}
         self._profile_req: Optional[tuple] = None  # (steps, outdir)
@@ -107,26 +113,23 @@ class ServeTelemetry:
         if self.enabled:
             self.tracer.instant(name, SCHED_TID, **args)
 
-    # ---- per-step phase attribution ---------------------------------------
+    # ---- step spans --------------------------------------------------------
 
-    def phase(self, name: str, t0: float, t1: float, emit: bool = True,
-              **args: Any) -> None:
-        """Accumulate phase wall time; optionally emit a scheduler span."""
-        dur = max(0.0, t1 - t0)
-        self.phase_seconds[name] = self.phase_seconds.get(name, 0.0) + dur
+    def span(self, name: str, **args: Any) -> Span:
+        """A span on the scheduler row: in the profiler trace while it is
+        open, in the ring when it closes (if enabled and ``emit`` is
+        left true), and added to ``phase_seconds`` when ``name`` is one
+        of :data:`PHASES`."""
+        done = self._add_phase if name in PHASES else None
+        if self.enabled:
+            return self.tracer.span(name, SCHED_TID, done, **args)
+        return Span(name, done=done, **args)
+
+    def _add_phase(self, span: Span) -> None:
+        name = span.name
+        self.phase_seconds[name] = self.phase_seconds.get(name, 0.0) \
+            + span.seconds
         self.phase_calls[name] = self.phase_calls.get(name, 0) + 1
-        if self.enabled and emit:
-            self.tracer.complete(name, SCHED_TID, t0, t1, **args)
-
-    @contextmanager
-    def timed_phase(self, name: str, emit: bool = True,
-                    **args: Any) -> Iterator[None]:
-        """Context manager sugar around :meth:`phase`."""
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.phase(name, t0, time.perf_counter(), emit=emit, **args)
 
     # ---- jax profiler window ----------------------------------------------
 
@@ -174,8 +177,7 @@ class ServeTelemetry:
             log_event("profile_error", error=self.profile_error)
             return
         self.profiles_taken += 1
-        log_event("profile_done", dir=self._profile_dir,
-                  phase_seconds=dict(self.phase_seconds))
+        log_event("profile_done", dir=self._profile_dir)
 
 
 # ---- mesh stats snapshot --------------------------------------------------

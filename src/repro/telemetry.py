@@ -9,6 +9,11 @@ train→serve→train deployment end to end:
   (``ph: X`` complete spans / ``i`` instants / ``M`` metadata), with
   per-entity trace rows lazily assigned by key.  Serving keys rows by
   request id; training keys them by trainer index.
+* :class:`Span` — the one way to time a stretch of host work on the
+  thread that does it: a ``jax.profiler.TraceAnnotation`` of the same
+  name from entry to exit (so the span sits in a profiler trace, on
+  the device's clock, beside the device's operations), then a complete
+  event in the ring on exit.  :meth:`Tracer.span` makes one.
 * :func:`enable_json_logs` / :func:`log_event` — one-line structured
   JSON records (``--log-json``) sharing ONE global switch, so a
   process that both trains and serves emits a single stream.
@@ -21,20 +26,23 @@ train→serve→train deployment end to end:
 ``repro.serve.telemetry`` re-exports the tracer/log surface for
 backward compatibility and layers the serving-specific exposition on
 top; ``repro.train.telemetry`` does the same for training.  Everything
-here is stdlib-only.
+here is stdlib-only, but for the profiler annotation, which imports JAX
+on first use and is skipped where JAX is not installed.
 """
 
 from __future__ import annotations
 
+import contextlib
 import json
 import math
 import sys
 import time
 from collections import deque
-from typing import Any, Dict, Iterable, List, Optional, Tuple
+from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 __all__ = [
     "Tracer",
+    "Span",
     "write_trace",
     "enable_json_logs",
     "json_logs_enabled",
@@ -51,6 +59,71 @@ __all__ = [
 # the serve side, trainer index on the train side) in sighting order.
 _TRACE_PID = 1
 SCHED_TID = 0
+
+
+_TraceAnnotation: Any = None
+
+
+def _annotation(name: str):
+    """A profiler annotation named ``name`` (JAX imported on first use;
+    it records nothing unless a profile is being taken)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        try:
+            from jax.profiler import TraceAnnotation as _TraceAnnotation
+        except ImportError:  # pragma: no cover - JAX is a dependency
+            _TraceAnnotation = contextlib.nullcontext
+    return _TraceAnnotation(name)
+
+
+class Span:
+    """A timed stretch of host work, used as a context manager.
+
+    On entry it opens a profiler annotation named ``name`` on the
+    calling thread; on exit it closes it, then records the complete
+    event ``(name, t0, t1, args)`` on ``tracer``'s row ``tid`` (when a
+    tracer is given and :attr:`emit` is still true) and calls
+    ``done(span)``.  The annotation is why a span must be opened where
+    the work starts: a profiler cannot be told about a span after the
+    fact.  ``args`` and ``emit`` may be changed inside the ``with``
+    block (what a phase did is often known only at its end).
+    """
+
+    __slots__ = ("name", "args", "emit", "t0", "t1", "_tracer", "_tid",
+                 "_done", "_ann")
+
+    def __init__(self, name: str, tracer: Optional["Tracer"] = None,
+                 tid: int = SCHED_TID,
+                 done: Optional[Callable[["Span"], None]] = None,
+                 **args: Any):
+        self.name = name
+        self.args = args
+        self.emit = True
+        self.t0 = self.t1 = 0.0
+        self._tracer = tracer
+        self._tid = tid
+        self._done = done
+        self._ann = None
+
+    @property
+    def seconds(self) -> float:
+        """Wall seconds from entry to exit."""
+        return max(0.0, self.t1 - self.t0)
+
+    def __enter__(self) -> "Span":
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        self.t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        if self._tracer is not None and self.emit:
+            self._tracer.complete(self.name, self._tid, self.t0, self.t1,
+                                  **self.args)
+        if self._done is not None:
+            self._done(self)
 
 
 class Tracer:
@@ -138,6 +211,13 @@ class Tracer:
                 "args": args,
             }
         )
+
+    def span(self, name: str, tid: int = SCHED_TID,
+             done: Optional[Callable[[Span], None]] = None,
+             **args: Any) -> Span:
+        """A :class:`Span` recorded on row ``tid`` of this ring when it
+        closes (and in the profiler trace while it is open)."""
+        return Span(name, self, tid, done, **args)
 
     def instant(
         self, name: str, tid: int, t: Optional[float] = None, **args: Any

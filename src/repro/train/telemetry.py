@@ -12,6 +12,8 @@ scraper for a train→serve→train deployment):
   tournament emits ``tournament_eval`` / ``partner_exchange`` spans
   (also from executor threads — emission is locked), and the
   orchestrator emits round/checkpoint spans on the orchestrator row.
+  Spans of the main thread (:func:`span`) are profiler annotations too,
+  so they label a profile's idle device time.
   Export with :func:`repro.telemetry.write_trace` (``--trace-out``).
 * :class:`GenealogyLog` / :func:`replay_genealogy` — the tournament
   genealogy: one JSONL record per match / round / rescale / failure /
@@ -40,6 +42,7 @@ from typing import Any, Dict, List, Optional
 
 from repro.telemetry import (
     SCHED_TID,
+    Span,
     Tracer,
     log_event,
     prom_counter,
@@ -49,6 +52,7 @@ from repro.telemetry import (
 
 __all__ = [
     "TrainTelemetry",
+    "span",
     "GenealogyLog",
     "replay_genealogy",
     "train_prometheus",
@@ -89,7 +93,9 @@ class TrainTelemetry:
 
     def trainer_span(self, name: str, trainer: int, t0: float, t1: float,
                      phase: Optional[str] = None, **args: Any) -> None:
-        """Emit a complete span on a trainer's trace row (thread-safe).
+        """Record a span timed elsewhere on a trainer's trace row
+        (thread-safe; the tournament's executor threads use it, so
+        such spans stay in the ring only).
 
         ``phase`` additionally accumulates the duration into
         :attr:`phase_seconds` under that name.
@@ -101,15 +107,25 @@ class TrainTelemetry:
         with self._lock:
             self.tracer.req_span(name, trainer, t0, t1, **args)
 
-    def span(self, name: str, t0: float, t1: float,
-             phase: Optional[str] = None, **args: Any) -> None:
-        """Emit a complete span on the orchestrator row (thread-safe)."""
-        if phase is not None:
-            self.add_phase(phase, t1 - t0)
-        if not self.enabled:
-            return
-        with self._lock:
-            self.tracer.complete(name, SCHED_TID, t0, t1, **args)
+    def span(self, name: str, trainer: Optional[int] = None,
+             phase: Optional[str] = None, **args: Any) -> Span:
+        """A :class:`~repro.telemetry.Span` on ``trainer``'s row (the
+        orchestrator row when None): a profiler annotation while open,
+        recorded in the ring (thread-safe) when it closes; ``phase``
+        adds its duration to :attr:`phase_seconds`."""
+        def done(sp: Span) -> None:
+            if phase is not None:
+                self.add_phase(phase, sp.seconds)
+            if not self.enabled:
+                return
+            with self._lock:
+                if trainer is None:
+                    self.tracer.complete(name, SCHED_TID, sp.t0, sp.t1,
+                                         **sp.args)
+                else:
+                    self.tracer.req_span(name, trainer, sp.t0, sp.t1,
+                                         **sp.args)
+        return Span(name, done=done, **args)
 
     def event(self, name: str, **args: Any) -> None:
         """Emit an instant event on the orchestrator row (rescale,
@@ -118,6 +134,16 @@ class TrainTelemetry:
             return
         with self._lock:
             self.tracer.instant(name, SCHED_TID, **args)
+
+
+def span(telemetry: Optional[TrainTelemetry], name: str,
+         trainer: Optional[int] = None, **args: Any) -> Span:
+    """``telemetry.span(...)``, or with no telemetry attached a span the
+    profiler alone sees (the training loop's spans label a profile's
+    idle device time whether or not it keeps a ring)."""
+    if telemetry is None:
+        return Span(name, **args)
+    return telemetry.span(name, trainer, **args)
 
 
 # ---- tournament genealogy -------------------------------------------------

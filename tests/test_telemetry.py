@@ -155,7 +155,19 @@ def test_telemetry_disabled_emits_no_events(served):
     assert set(results) == {"x", "y"}
     assert len(sched.telemetry.tracer.events) == 0
     # phase wall-time attribution still accumulates (it feeds /metrics)
-    assert sched.telemetry.phase_seconds.get("decode", 0.0) > 0.0
+    tel = sched.telemetry
+    assert tel.phase_seconds.get("decode", 0.0) > 0.0
+    # through the span API: a phase span adds its time, a span that is
+    # not a phase adds none, and neither reaches the disabled ring
+    before, calls = tel.phase_seconds["decode"], tel.phase_calls["decode"]
+    with tel.span("decode") as sp:
+        with tel.span("sample"):
+            pass
+    assert tel.phase_seconds["decode"] == pytest.approx(
+        before + sp.seconds)
+    assert tel.phase_calls["decode"] == calls + 1
+    assert "sample" not in tel.phase_seconds
+    assert len(tel.tracer.events) == 0
 
 
 # -- prometheus exposition --------------------------------------------------
@@ -193,9 +205,14 @@ def test_prometheus_exposition(mixed_run):
     cum = [int(v) for k, v in samples.items()
            if k.startswith("repro_serve_ttft_seconds_bucket")]
     assert cum == sorted(cum), "buckets must be cumulative"
-    # per-shard pool occupancy + phase attribution ride along
+    # per-shard pool occupancy + phase attribution ride along: every
+    # phase span the scheduler opened, at the seconds it accumulated
     assert 'repro_serve_pool_high_water_blocks{shard="0"}' in samples
-    assert 'repro_serve_phase_seconds_total{phase="decode"}' in samples
+    phases = sched.telemetry.phase_seconds
+    assert {"admit", "prefill", "decode"} <= set(phases)
+    for ph, secs in phases.items():
+        key = f'repro_serve_phase_seconds_total{{phase="{ph}"}}'
+        assert float(samples[key]) == pytest.approx(secs)
 
 
 def test_prometheus_text_handles_empty_stats():
