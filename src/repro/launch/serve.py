@@ -483,9 +483,9 @@ def build_parser() -> argparse.ArgumentParser:
                          "identical to target-only decoding")
     ap.add_argument("--no-spec-fused", action="store_true",
                     help="disable the fused draft step (K proposals in "
-                         "ONE dispatch via on-device greedy feed + host "
-                         "resample; off = K+1 sequential draft "
-                         "dispatches per round)")
+                         "ONE dispatch via on-device greedy feed + a "
+                         "redraw by the device sampler; off = K+1 "
+                         "sequential draft dispatches per round)")
     ap.add_argument("--spec-adapt", action="store_true",
                     help="adapt the speculative depth PER ROW from its "
                          "accept-rate history (within [1, spec-tokens]); "

@@ -5,8 +5,9 @@ append-only JSONL file, flushed every scheduler step and fsync'd at a
 bounded interval, so a new
 scheduler/gateway generation can requeue unfinished work after a crash
 (SIGKILL, dead mesh peer, OOM) or a graceful SIGTERM restart — and,
-because host sampling is deterministic in ``(seed, ntok)``, resume
-emission **token-identically** from the last journaled token.
+because sampling is deterministic in ``(seed, ntok_base + ntok)``
+(``repro.serve.sampling``), resume emission **token-identically** from
+the last journaled token.
 
 Record types (one JSON object per line):
 
@@ -52,9 +53,9 @@ Resume model — *a resumed request is just a longer prompt*.  For an
 unfinished journal entry with ``k`` emitted tokens, :func:`resume_request`
 rebuilds the request as ``prompt = original_prompt + emitted``,
 ``max_new = original_max_new - k`` and ``ntok_base = k``.  The
-scheduler's sampler seeds ``rng([seed, ntok_base + ntok])``, so decode
-step ``j`` of the resumed run conditions on exactly the tokens and rng
-stream the uninterrupted run used at step ``k + j`` — pool budget,
+scheduler's sampler keys each draw by ``(seed, ntok_base + ntok)``, so
+decode step ``j`` of the resumed run conditions on exactly the tokens and
+draw the uninterrupted run used at step ``k + j`` — pool budget,
 write positions, EOS and speculative decoding all hold automatically.
 The new generation's ``results[rid]`` holds only the NEW tokens;
 :func:`stitched_results` prepends the journaled prefix to recover the

@@ -173,6 +173,7 @@ class ServeStats:
     decode_steps: int = 0
     decode_tokens: int = 0         # useful generated tokens
     decode_slot_steps: int = 0     # slots * steps actually computed
+    sample_fetch_bytes: int = 0    # bytes copied to the host for sampling
     # speculative decoding (population drafter)
     spec_rounds: int = 0           # target verify steps
     spec_draft_steps: int = 0      # drafter decode dispatches
@@ -252,6 +253,7 @@ class ServeStats:
             "decode_steps": self.decode_steps,
             "decode_tokens": self.decode_tokens,
             "decode_slot_steps": self.decode_slot_steps,
+            "sample_fetch_bytes": self.sample_fetch_bytes,
             "spec_rounds": self.spec_rounds,
             "spec_draft_steps": self.spec_draft_steps,
             "spec_draft_proposed": self.spec_draft_proposed,

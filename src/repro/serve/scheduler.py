@@ -42,8 +42,10 @@ Per scheduler step:
      rejected rows roll recurrent state back via
      ``session.restore`` + a ``valid``-masked replay.  At any
      temperature the output is token-identical to target-only decoding
-     (sampling is a deterministic function of (seed, ntok) and the
-     target logits).  On CPU the jnp gather oracle pays the full
+     (every token is drawn on the device by
+     :func:`repro.serve.sampling.sample_rows`, a deterministic function
+     of the target logits, the request's seed and the token's
+     position).  On CPU the jnp gather oracle pays the full
      bucketed table width per row, so when one row's pow2 width is
      >= 4x everyone else's (the one-long-request pathology) the round
      splits into (narrow, wide) groups stepped separately — the long
@@ -63,16 +65,16 @@ from __future__ import annotations
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import lm
 from repro.serve.kv_cache import PagedLayout, SlotLayout, blocks_for
 from repro.serve.metrics import ServeStats
+from repro.serve.sampling import sample_rows, seed_words
 from repro.serve.session import DecodeSession
 from repro.serve.telemetry import ServeTelemetry, log_event
 
@@ -88,18 +90,19 @@ class Request:
     """One generation request.
 
     ``prompt`` is a (P,) int32 token-id array; ``max_new`` bounds the
-    generated tokens; ``temperature > 0`` requires ``seed`` (sampling
-    is host-side and deterministic in ``(seed, ntok)``).  The optional
-    deadlines are SLO declarations in milliseconds: a queued request
-    whose ``ttft_deadline_ms`` has already expired is shed by
+    generated tokens; ``temperature > 0`` requires ``seed``, an int in
+    ``[0, 2**64)`` (sampling runs on the device and is deterministic in
+    ``(seed, ntok_base + ntok)``, see :mod:`repro.serve.sampling`).  The
+    optional deadlines are SLO declarations in milliseconds: a queued
+    request whose ``ttft_deadline_ms`` has already expired is shed by
     :meth:`Scheduler.shed_expired` instead of admitted late, and a
     completed request that missed its TTFT/TPOT deadline increments
     the corresponding ``[serve]`` miss counter.
 
-    ``ntok_base`` offsets the sampler's rng stream: a journal resume
-    re-submits a request with ``k`` already-emitted tokens folded into
-    the prompt and ``ntok_base=k``, so its first new sample draws
-    ``rng([seed, k])`` — exactly the draw the uninterrupted run would
+    ``ntok_base`` offsets the sampler's stream position: a journal
+    resume re-submits a request with ``k`` already-emitted tokens folded
+    into the prompt and ``ntok_base=k``, so its first new sample draws
+    at position ``k`` — exactly the draw the uninterrupted run would
     have made (see ``repro.serve.journal``).  ``idem_key`` carries the
     gateway's ``Idempotency-Key`` header into the journal so client
     retries after a restart don't double-admit.
@@ -350,6 +353,11 @@ class Scheduler:
             self._reject(
                 f"request {req.rid!r}: temperature > 0 requires a seed "
                 "(refusing to silently fall back to greedy)")
+        if req.seed is not None:
+            try:
+                seed_words(req.seed)
+            except (TypeError, ValueError) as e:
+                self._reject(f"request {req.rid!r}: {e}")
         self.stats.submitted += 1
         if self.journal is not None:
             self.journal.record_submit(req)
@@ -456,7 +464,6 @@ class Scheduler:
         t0 = time.perf_counter()
         with self.telemetry.span("prefill.dispatch"):
             last = self.session.prefill(req.rid, req.prompt, bucket=bucket)
-        last = self._fetch_rows(last)
         self.telemetry.req_span(req.rid, "prefill", t0, time.perf_counter(),
                                 tokens=P, bucket=bucket)
         self.stats.prefills += 1
@@ -472,7 +479,6 @@ class Scheduler:
         t0 = time.perf_counter()
         with self.telemetry.span("prefill.dispatch"):
             last = self.session.prefill(req.rid, req.prompt, bucket=None)
-        last = self._fetch_rows(last)
         self.telemetry.req_span(req.rid, "prefill", t0, time.perf_counter(),
                                 tokens=P)
         self.stats.prefills += 1
@@ -511,8 +517,6 @@ class Scheduler:
                 req.rid, req.prompt[act.pf_pos:act.pf_pos + n],
                 hist_len=act.pf_pos, prompt_len=P, chunk_bucket=Cb,
                 width=W)
-        if final:
-            last = self._fetch_rows(last)
         tel.req_span(req.rid, "prefill_chunk", t0, time.perf_counter(),
                      tokens=n, pos=act.pf_pos, prompt_len=P)
         act.pf_pos += n
@@ -530,13 +534,13 @@ class Scheduler:
             del self.prefilling[req.rid]
             self._start_decoding(act, last)
 
-    def _start_decoding(self, act: _Active, last_logits: np.ndarray) -> None:
-        """Sample the first token off the prefill logits and move the
-        request into the decode batch."""
+    def _start_decoding(self, act: _Active, last_logits: jax.Array) -> None:
+        """Draw the first token off the prefill's device logits (V,) and
+        move the request into the decode batch."""
         req = act.req
         self.active[req.rid] = act
         self._by_slot[act.slot] = act
-        tok = self._sample(last_logits, req, 0)
+        tok = int(self._draw(last_logits, [(0, act)], (1, 1))[0, 0])
         act.first_token_t = time.perf_counter()
         self.stats.ttft.append(act.first_token_t - act.submit_t)
         self.telemetry.req_instant(
@@ -544,33 +548,35 @@ class Scheduler:
             ttft_s=act.first_token_t - act.submit_t)
         self._accept_token(act, tok)
 
-    def _fetch_rows(self, logits) -> np.ndarray:
-        """Copy device logits to the host as float32, in two spans:
-        ``device_wait`` while the device finishes them (the host waits,
-        the device works) and ``logits_fetch`` for the copy itself (the
-        device has nothing queued).  ``np.asarray`` would block once
-        for both; the split adds no synchronisation."""
-        tel = self.telemetry
-        with tel.span("device_wait"):
-            logits = logits.astype(jnp.float32).block_until_ready()
-        with tel.span("logits_fetch"):
-            return np.asarray(logits)
+    def _draw(self, logits: jax.Array, picks: List[tuple],
+              shape: Tuple[int, int], first: int = 0) -> np.ndarray:
+        """Draw tokens on the device and copy back only their ids.
 
-    def _sample(self, logits_row, req: Request, ntok: int) -> int:
-        """logits_row: (V,) host array.  Sampling stays on host (Gumbel
-        trick for temperature > 0) so the only device dispatch per step
-        is the batched decode itself.  Deterministic in (seed, ntok) —
-        which is what makes speculative decoding output-identical to
-        target-only decoding at ANY temperature, not just greedy.
-        ``ntok_base`` shifts the stream for journal-resumed requests,
-        so sample k of the resumed run draws the same rng the
-        uninterrupted run drew at position ntok_base + k."""
-        if req.temperature <= 0.0:
-            return int(np.argmax(logits_row))
-        rng = np.random.default_rng([req.seed, req.ntok_base + ntok])
-        g = rng.gumbel(size=logits_row.shape[-1])
-        return int(np.argmax(
-            np.asarray(logits_row, np.float64) / req.temperature + g))
+        ``picks`` holds ``(row, active request)`` pairs: row ``b`` of
+        ``logits`` draws at the request's stream positions
+        ``ntok_base + ntok + first + t`` for ``t < K``, with ``shape``
+        ``(B, K)`` the rows and tokens per row the logits hold.  Rows not
+        picked draw greedily and are ignored.  ``sample`` spans building
+        the per-row inputs and dispatching the sampler, ``device_wait``
+        the wait for the ids (and their copy).  Returns (B, K) int32.
+        """
+        tel = self.telemetry
+        B, K = shape
+        with tel.span("sample", rows=len(picks)):
+            temp = np.zeros((B,), np.float32)
+            seed = [0] * B
+            base = np.zeros((B, 1), np.int64)
+            for b, act in picks:
+                req = act.req
+                if req.temperature > 0.0:
+                    temp[b] = req.temperature
+                    seed[b] = req.seed
+                base[b] = req.ntok_base + act.ntok + first
+            ids = sample_rows(logits, temp, seed, base + np.arange(K))
+        with tel.span("device_wait"):
+            ids = np.asarray(ids)
+        self.stats.sample_fetch_bytes += ids.nbytes
+        return ids
 
     def _accept_token(self, act: _Active, tok: int) -> None:
         act.tokens.append(tok)
@@ -959,17 +965,15 @@ class Scheduler:
 
     _SPLIT_RATIO = 4
 
-    def _sample_and_accept(self, picks: List[tuple]) -> None:
-        """One token for each ``(active request, its logits row)``:
-        ``sample`` them all, then ``accept`` them (finishing frees the
-        slot in place)."""
-        tel = self.telemetry
-        with tel.span("sample", rows=len(picks)):
-            toks = [self._sample(row, act.req, act.ntok)
-                    for act, row in picks]
-        with tel.span("accept"):
-            for (act, _), tok in zip(picks, toks):
-                self._accept_token(act, tok)
+    def _sample_and_accept(self, logits: jax.Array,
+                           picks: List[tuple]) -> None:
+        """One token for each ``(row, active request)`` of the (B, 1, V)
+        device logits: draw them all, then ``accept`` them (finishing
+        frees the slot in place)."""
+        ids = self._draw(logits, picks, (logits.shape[0], 1))
+        with self.telemetry.span("accept"):
+            for b, act in picks:
+                self._accept_token(act, int(ids[b, 0]))
 
     def _decode_round(self) -> None:
         tel = self.telemetry
@@ -988,10 +992,9 @@ class Scheduler:
             with tel.span("decode.dispatch"):
                 logits = self.session.step(self._next_token[:, None],
                                            self._index, width=width)
-            rows = self._fetch_rows(logits)
             self.stats.decode_slot_steps += self.pool.num_slots
-            self._sample_and_accept([(a, rows[a.slot, 0])
-                                     for a in self.active.values()])
+            self._sample_and_accept(logits, [(a.slot, a) for a in
+                                             self.active.values()])
             return
         # ragged split: one subset dispatch per width group (row counts
         # pow2-bucketed so the compile count stays logarithmic)
@@ -1007,12 +1010,11 @@ class Scheduler:
                     index[i] = self._index[s]
                     tables[i] = self.pool.tables[s, :W]
                 logits = self.session.step(tokens, index, tables=tables)
-            rows = self._fetch_rows(logits)
             self.stats.decode_slot_steps += n
             self.stats.ragged_splits += 1
-            self._sample_and_accept([(self._by_slot[s], rows[i, 0])
-                                     for i, s in enumerate(slots)
-                                     if s in self._by_slot])
+            self._sample_and_accept(logits, [(i, self._by_slot[s])
+                                             for i, s in enumerate(slots)
+                                             if s in self._by_slot])
 
     # -- speculative decode --------------------------------------------------
     def _spec_round(self) -> None:
@@ -1029,11 +1031,12 @@ class Scheduler:
         **Fused drafting** (``spec_fused``, the default): the whole
         draft block is ONE dispatch — ``session.draft_block`` unrolls
         K+1 single-token decodes on device, feeding each greedy argmax
-        into the next — and the host then RESAMPLES the proposals from
-        the returned logits with the request's real sampling function.
-        A round is 2 dispatches (draft + verify) instead of K+2.  At
-        temperature 0 host resample == device greedy, so the drafter's
-        cache is exactly right; at temperature > 0 a resample that
+        into the next — and the proposals are then REDRAWN from the
+        returned logits with the request's real sampling function (the
+        device sampler, keyed like the target's draws).  A round is 2
+        model dispatches (draft + verify) instead of K+2.  At
+        temperature 0 the redraw == the greedy feed, so the drafter's
+        cache is exactly right; at temperature > 0 a redraw that
         diverges from the device feed leaves wrong tokens in the
         drafter's history, which the rollback below repairs (token
         identity is untouched either way — emitted tokens only ever
@@ -1043,7 +1046,7 @@ class Scheduler:
         the accepted prefix when it kept fewer tokens than it fed
         (attention KV needs none: stale tail positions are causally
         masked and overwritten).  The DRAFTER additionally repairs
-        rows whose device-fed block diverged from the host-resampled
+        rows whose device-fed block diverged from the redrawn
         block — a replay write for attention KV, restore + replay for
         recurrent state.
         """
@@ -1073,7 +1076,7 @@ class Scheduler:
             W = None
         block = np.zeros((B, Kv), np.int32)
         block[:, 0] = self._next_token
-        ntok0 = {act.slot: act.ntok for act in acts}
+        picks = [(act.slot, act) for act in acts]
 
         d_snap = self.draft.snapshot() if d_rec else ()
         with tel.span("draft", k=Kv - 1):
@@ -1083,15 +1086,12 @@ class Scheduler:
                     dlogits, dev = self.draft.draft_block(
                         self._next_token[:, None], base, Kv, valid=cap,
                         width=W)
-                drows = self._fetch_rows(dlogits)           # (B, Kv, V)
-                dev = np.asarray(dev)                       # (B, Kv)
                 self.stats.spec_draft_steps += 1
-                with tel.span("sample", rows=len(acts)):
-                    for act in acts:
-                        s = act.slot
-                        for t in range(int(cap[s]) - 1):
-                            block[s, t + 1] = self._sample(
-                                drows[s, t], act.req, ntok0[s] + t)
+                drawn = self._draw(dlogits, picks, (B, Kv))
+                dev = np.asarray(dev)                       # (B, Kv)
+                for act in acts:
+                    s = act.slot
+                    block[s, 1:cap[s]] = drawn[s, :cap[s] - 1]
             else:
                 # -- sequential draft: Kv single-token steps (the last
                 # feeds the final proposal so drafter and target caches
@@ -1106,48 +1106,41 @@ class Scheduler:
                     self.stats.spec_draft_steps += 1
                     if t + 1 >= Kv:
                         break
-                    rows = self._fetch_rows(logits)
-                    with tel.span("sample", rows=len(acts)):
-                        for act in acts:
-                            s = act.slot
-                            if t + 1 < cap[s]:
-                                block[s, t + 1] = self._sample(
-                                    rows[s, 0], act.req, ntok0[s] + t)
-                dev = block          # the drafter was fed the host block
+                    drawn = self._draw(logits, picks, (B, 1), first=t)
+                    for act in acts:
+                        s = act.slot
+                        if t + 1 < cap[s]:
+                            block[s, t + 1] = drawn[s, 0]
+                dev = block          # the drafter was fed the drawn block
 
-        # -- target: verify the whole block in one K-token step
+        # -- target: verify the whole block in one K-token step, and draw
+        # the target's token at every position of it
         with tel.span("verify", k=Kv):
             t_snap = self.session.snapshot() if t_rec else ()
             with tel.span("decode.dispatch"):
                 vlogits = self.session.step(block, base, valid=cap, width=W)
-            rows = self._fetch_rows(vlogits)                # (B, Kv, V)
+            target = self._draw(vlogits, picks, (B, Kv))
         self.stats.decode_steps += 1
         self.stats.spec_rounds += 1
         self.stats.decode_slot_steps += B
 
-        # -- acceptance: longest matching prefix + one target token.
-        # The target's tokens per row are drawn first (up to the first
-        # that differs from the proposal, an EOS or the row's budget),
-        # then accepted
-        with tel.span("sample", rows=len(acts)):
-            drawn: Dict[int, List[int]] = {}
-            for act in acts:
-                s = act.slot
-                c = min(int(cap[s]), act.req.max_new - act.ntok)
-                toks = drawn[s] = []
-                for t in range(c):
-                    g = self._sample(rows[s, t], act.req, ntok0[s] + t)
-                    toks.append(g)
-                    if g == act.req.eos_id or t + 1 >= c \
-                            or g != int(block[s, t + 1]):
-                        break
+        # -- acceptance: longest matching prefix + one target token — the
+        # target's draws up to the first that differs from the proposal,
+        # an EOS or the row's budget
         fed_valid = np.zeros((B,), np.int32)
         with tel.span("accept"):
             for act in acts:
                 s = act.slot
-                for g in drawn[s]:
+                c = min(int(cap[s]), act.req.max_new - act.ntok)
+                appended = 0
+                for t in range(c):
+                    g = int(target[s, t])
                     self._accept_token(act, g)           # may finish
-                fed_valid[s] = appended = len(drawn[s])
+                    appended += 1
+                    if g == act.req.eos_id or t + 1 >= c \
+                            or g != int(block[s, t + 1]):
+                        break
+                fed_valid[s] = appended
                 offered = max(0, int(cap[s]) - 1)
                 accepted = max(0, appended - 1)
                 self.stats.spec_draft_proposed += offered

@@ -87,7 +87,7 @@ def _draft_unroll(params, cfg, tok0, cache, index, valid, steps, tables):
                                          tables=tables, valid=valid_t)
         fed.append(tok[:, 0])
         logits_all.append(logits[:, 0])
-        # greedy device feed; the host resamples from the returned
+        # greedy device feed; the scheduler redraws from the returned
         # logits with the request's true sampling function afterwards
         tok = jnp.argmax(logits[:, 0].astype(jnp.float32),
                          axis=-1).astype(jnp.int32)[:, None]
@@ -256,7 +256,7 @@ class DecodeSession:
         steps per row (rows freeze beyond, like a masked multi-token
         step); width: block-table columns (paged).  Returns (logits
         (B, steps, V), fed tokens (B, steps)) still on device — the
-        caller resamples proposals from the logits with the request's
+        caller redraws proposals from the logits with the request's
         real sampling function and repairs the cache where its samples
         diverge from the greedy feed.
         """

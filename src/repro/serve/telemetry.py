@@ -200,6 +200,7 @@ _SNAPSHOT_COUNTERS = (
     "decode_steps",
     "decode_tokens",
     "decode_slot_steps",
+    "sample_fetch_bytes",
     "ragged_splits",
     "spec_rounds",
     "spec_draft_steps",
@@ -272,6 +273,8 @@ _COUNTER_HELP = {
     "decode_steps": "batched decode steps",
     "decode_tokens": "tokens decoded",
     "decode_slot_steps": "per-slot decode steps",
+    "sample_fetch_bytes": "bytes copied to the host for sampling "
+                          "(the drawn token ids)",
     "ragged_splits": "ragged gather-width split dispatches",
     "spec_rounds": "speculative verify rounds",
     "spec_draft_steps": "drafter decode dispatches",

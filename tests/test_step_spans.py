@@ -28,9 +28,8 @@ PARENTS = {
     "prefill.dispatch": {"prefill"},
     "kv.register_prefix": {"prefill"},
     "device_wait": {"prefill", "decode", "draft", "verify"},
-    "logits_fetch": {"prefill", "decode", "draft", "verify"},
     "decode.dispatch": {"decode", "draft", "verify"},
-    "sample": {"decode", "draft"},
+    "sample": {"prefill", "decode", "draft", "verify"},
     "accept": {"decode"},
     "draft": {"decode"},
     "verify": {"decode"},
@@ -116,6 +115,8 @@ def test_every_span_lands_in_its_phase_on_the_callers_line(profiled):
     _, events, _, _ = profiled
     names = {n for _, _, n in events}
     assert SPANS - {"draft", "verify"} <= names
+    # tokens are drawn on the device: no logits cross to the host
+    assert "logits_fetch" not in names
     for ev in events:
         name = ev[2]
         if name in PHASES:
@@ -143,7 +144,8 @@ def test_telemetry_off_keeps_the_profiler_spans(profiled):
 
 def test_spans_per_step_do_not_grow_with_rows(served, tmp_path):
     """A decode step with one active row and one with four open the
-    same spans."""
+    same spans: one ``sample`` (the device sampler's dispatch) and no
+    ``logits_fetch``."""
     one, four = (_scheduler(served, n, max_new=12) for n in (1, 4))
     for sched, n in ((one, 1), (four, 4)):
         while len(sched.active) < n:       # admit + prefill, unprofiled
@@ -154,24 +156,26 @@ def test_spans_per_step_do_not_grow_with_rows(served, tmp_path):
     for s, e, _ in marks:
         inside = sorted(n for a, b, n in events
                         if s <= a and b <= e and n in SPANS)
-        assert "sample" in inside and "accept" in inside
+        assert inside.count("sample") == 1 and "accept" in inside
+        assert not any(n == "logits_fetch" for a, b, n in events
+                       if s <= a and b <= e)
         counts.append(inside)
     assert len(one.active) == 1 and len(four.active) == 4
     assert counts[0] == counts[1]
 
 
 def test_speculative_round_spans(served):
-    """A speculative round puts dispatch, wait, copy and sampling inside
-    ``draft`` and ``verify``, and the target's sampling and acceptance
-    directly in ``decode`` (read from the ring, which holds the same
-    spans)."""
+    """A speculative round puts dispatch, sampling and the wait for the
+    drawn ids inside ``draft`` and ``verify``, and acceptance directly
+    in ``decode`` (read from the ring, which holds the same spans)."""
     cfg, params = served
     sched = _scheduler(served, 2, draft_params=params, spec_tokens=2)
     sched.run()
     spans = _ring_spans(sched)
     names = {n for _, _, n in spans}
     assert {"draft", "verify", "decode.dispatch", "device_wait",
-            "logits_fetch", "sample", "accept"} <= names
+            "sample", "accept"} <= names
+    assert "logits_fetch" not in names
     for ev in spans:
         if ev[2] in PARENTS:
             assert _parent(ev, spans) in PARENTS[ev[2]], ev
@@ -179,10 +183,21 @@ def test_speculative_round_spans(served):
         _parent(ev, spans) == "draft" for ev in spans if ev[2] == n)}
     in_verify = {n for n in names if any(
         _parent(ev, spans) == "verify" for ev in spans if ev[2] == n)}
-    assert {"decode.dispatch", "device_wait", "logits_fetch",
-            "sample"} <= in_draft
-    assert {"decode.dispatch", "device_wait",
-            "logits_fetch"} <= in_verify
+    assert {"decode.dispatch", "device_wait", "sample"} <= in_draft
+    assert {"decode.dispatch", "device_wait", "sample"} <= in_verify
+
+
+def test_only_token_ids_are_fetched_for_sampling(served):
+    """Each full-batch decode step copies ``num_slots`` int32 ids to the
+    host for sampling, and each first token one more: no logits."""
+    sched = _scheduler(served, 3)
+    sched.run()
+    st = sched.stats
+    assert st.completed == 3 and st.decode_steps > 0
+    assert st.ragged_splits == 0
+    assert st.sample_fetch_bytes == 4 * (sched.pool.num_slots
+                                         * st.decode_steps + st.completed)
+    assert st.as_dict()["sample_fetch_bytes"] == st.sample_fetch_bytes
 
 
 def test_training_spans_reach_the_profiler_without_telemetry(tmp_path):

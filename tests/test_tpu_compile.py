@@ -19,6 +19,7 @@ from repro.configs.icf_cyclegan import FULL as ICF_FULL
 from repro.configs.qwen3_06b import FULL as QWEN3
 from repro.kernels import ops
 from repro.kernels import paged_attention as pa
+from repro.serve import sampling
 from repro.train.steps import make_gan_steps
 
 V5E_HBM_BYTES = 16 * 1024 ** 3
@@ -70,6 +71,24 @@ def test_paged_kernel_compiles_at_qwen3_shapes(one_chip, k_tokens):
             _spec((B,), jnp.int32, one_chip)]
     compiled = jax.jit(pa.paged_attention).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows,k_tokens,dtype", [
+    (64, 1, jnp.bfloat16), (64, 4, jnp.bfloat16), (1, 1, jnp.float32)],
+    ids=["decode", "verify", "first-token"])
+def test_device_sampler_compiles_at_qwen3_vocab(one_chip, rows, k_tokens,
+                                                dtype):
+    """The scheduler's sampler over the full 151,936-token vocabulary, at
+    the serving cell's 64 slots (and one prefill row)."""
+    V = QWEN3.vocab_size
+    logits = (V,) if rows == 1 else (rows, k_tokens, V)
+    compiled = sampling._draw.lower(
+        _spec(logits, dtype, one_chip),
+        _spec((rows,), jnp.float32, one_chip),
+        _spec((rows, 2), jnp.uint32, one_chip),
+        _spec((rows, k_tokens), jnp.int32, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < V5E_HBM_BYTES // 16
 
 
 def test_paged_attention_sharded_compiles_on_2x2_mesh(topo):
