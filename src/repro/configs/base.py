@@ -37,6 +37,54 @@ class MoEConfig:
     # 'scatter' (beyond-paper: indexed scatter/gather — no O(T*E*C)
     # dispatch tensors, no dispatch matmul flops)
     dispatch: str = "einsum"
+    # renormalise the top-k gate values to sum to 1 (Mixtral, Qwen-MoE);
+    # DeepSeek's gate keeps the softmax scores as they are
+    norm_topk_prob: bool = True
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434).
+
+    Keys and values are expanded per head from one compressed latent
+    ``c_kv`` of ``kv_lora_rank`` values (normalised), plus one RoPE key
+    head of ``qk_rope_head_dim`` shared by every head; the cache holds
+    only ``[c_kv; k_pe]``.  Queries are not compressed (no q-LoRA).
+    """
+
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        """Cached values per token per layer: ``[c_kv; k_pe]``."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def pool_dim(self) -> int:
+        """Width of a row of the paged latent pool: ``latent_dim``
+        zero-padded to a multiple of 128 lanes (576 -> 640), the width a
+        TPU's tiled layout gives the row in HBM anyway and the width a
+        kernel's DMA of a whole page must have."""
+        return -(-self.latent_dim // 128) * 128
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (arXiv:2309.00071), DeepSeek-V2's variant."""
+
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclass(frozen=True)
@@ -107,6 +155,10 @@ class ModelConfig:
     block_pattern: str = "a"
 
     moe: Optional[MoEConfig] = None
+    # latent attention replaces every attention layer's projections and
+    # cache; YaRN scales its rotary frequencies
+    mla: Optional[MLAConfig] = None
+    yarn: Optional[YarnConfig] = None
     mamba: Optional[MambaConfig] = None
     xlstm: Optional[XLSTMConfig] = None
     frontend: FrontendConfig = field(default_factory=FrontendConfig)
@@ -143,6 +195,14 @@ class ModelConfig:
 
     # --- parameter accounting (used for MODEL_FLOPS = 6*N*D) ---------------
     def _attn_params(self) -> int:
+        if self.mla is not None:
+            m, H = self.mla, self.num_heads
+            return (self.d_model * H * m.qk_head_dim             # wq
+                    + self.d_model * m.latent_dim                 # wkv_a
+                    + m.kv_lora_rank                              # kv norm
+                    + m.kv_lora_rank * H * (m.qk_nope_head_dim
+                                            + m.v_head_dim)       # wkv_b
+                    + H * m.v_head_dim * self.d_model)            # wo
         hd = self.resolved_head_dim
         p = self.d_model * (self.q_dim + 2 * self.kv_dim)      # wq wk wv
         p += self.q_dim * self.d_model                          # wo

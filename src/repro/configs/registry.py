@@ -10,6 +10,7 @@ from typing import Dict, List, Tuple
 from repro.configs import (
     codeqwen15,
     deepseek_moe,
+    deepseek_v2_lite,
     granite_8b,
     icf_cyclegan,
     jamba_15_large,
@@ -37,6 +38,9 @@ _MODULES = (
 
 ARCHS: Dict[str, object] = {m.ARCH_ID: m for m in _MODULES}
 ARCHS[icf_cyclegan.ARCH_ID] = icf_cyclegan
+# served through the paged path only (latent attention has no dense-row
+# cache), so it stays out of the dry-run grid below
+ARCHS[deepseek_v2_lite.ARCH_ID] = deepseek_v2_lite
 
 # LM architectures participating in the arch x shape dry-run grid.
 LM_ARCH_IDS: Tuple[str, ...] = tuple(m.ARCH_ID for m in _MODULES)

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import flash_attention as fa
+from repro.kernels import latent_attention as la
 from repro.kernels import paged_attention as pa
 from repro.kernels import ref
 from repro.kernels import rmsnorm as rn
@@ -129,6 +130,26 @@ def paged_attention(q, k_pages, v_pages, tables, lengths,
                                        lengths, interpret=interpret)
     return _paged_attention_local(q, k_pages, v_pages, tables, lengths,
                                   interpret=interpret)
+
+
+def latent_paged_attention(q, pool, layer, tables, lengths, scale: float,
+                           value_dim: int, interpret: bool = None):
+    """Absorbed latent-attention decode over a stacked paged latent pool.
+
+    q: (B, H, D) or (B, K, H, D); pool: (layers, P+1, bs, D); layer:
+    int32 scalar; tables: (B, W); lengths: (B,) (0: idle row) -> float32
+    (.., H, value_dim).  The compiled Pallas kernel on TPU (or in
+    interpret mode when asked), the jnp oracle everywhere else.
+    Single-device only: no serving mesh holds a latent pool.
+    """
+    if interpret is None:
+        if not _on_tpu():
+            return ref.latent_paged_attention_ref(q, pool, layer, tables,
+                                                  lengths, scale, value_dim)
+        interpret = False
+    return la.latent_paged_attention(q, pool, layer, tables, lengths,
+                                     scale=scale, value_dim=value_dim,
+                                     interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_rows",

@@ -67,6 +67,40 @@ def paged_attention_ref(q: jax.Array, k_pages: jax.Array,
     return out[:, 0] if squeeze else out
 
 
+def latent_paged_attention_ref(q: jax.Array, pool: jax.Array, layer,
+                               tables: jax.Array, lengths: jax.Array,
+                               scale: float, value_dim: int) -> jax.Array:
+    """Oracle of the latent paged decode kernel (the jnp twin the models
+    use off-TPU).
+
+    q: (B, H, D) or (B, K, H, D) absorbed queries; pool: (layers, P+1,
+    bs, D) stacked latent pool; layer: int32 scalar; tables: (B, W);
+    lengths: (B,) tokens the first query of each row sees (query t sees
+    ``lengths[b] + t``; a row of length 0 returns zeros).  Scores over
+    all D columns of the gathered rows, values their first
+    ``value_dim``; float32 out.
+    """
+    squeeze = q.ndim == 3
+    if squeeze:
+        q = q[:, None]
+    B, K, H, D = q.shape
+    bs = pool.shape[2]
+    W = tables.shape[1]
+    rows = pool[layer][tables].reshape(B, W * bs, D).astype(jnp.float32)
+    s = jnp.einsum("bthd,bkd->bthk", q.astype(jnp.float32), rows) * scale
+    pos = jnp.arange(W * bs, dtype=jnp.int32)
+    lens = lengths[:, None] + jnp.arange(K, dtype=jnp.int32)[None, :]
+    valid = pos[None, None, :] < lens[..., None]          # (B, K, W*bs)
+    s = jnp.where(valid[:, :, None, :], s, -jnp.inf)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.where(valid[:, :, None, :],
+                  jnp.exp(s - jnp.where(jnp.isfinite(m), m, 0.0)), 0.0)
+    w = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+    out = jnp.einsum("bthk,bkl->bthl", w, rows[..., :value_dim])
+    out = jnp.where((lengths > 0)[:, None, None, None], out, 0.0)
+    return out[:, 0] if squeeze else out
+
+
 def rmsnorm_ref(x: jax.Array, scale: jax.Array,
                 eps: float = 1e-6) -> jax.Array:
     xf = x.astype(jnp.float32)

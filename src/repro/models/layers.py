@@ -592,6 +592,318 @@ def attention_chunk_paged(params: Params, cfg: ModelConfig, x: jax.Array,
     return constrain(out, "batch", "seq", "act_embed"), {"k": k, "v": v}
 
 
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434)
+#
+# Per token: q = W_q x, split per head into q_nope and q_pe;
+# [c_kv; k_pe] = W_kva x; c_kv is RMS-normalised (kv_a_layernorm) and
+# expanded per head to [k_nope; v] = W_kvb c_kv; k_pe is ONE rotary key
+# head shared by every head.  Scores are q_nope.k_nope + q_pe.k_pe over
+# sqrt(qk_head_dim), times YaRN's mscale^2.  The serving cache holds only
+# [c_kv; k_pe] (latent_dim values a token a layer), so decode runs the
+# absorbed form: q_nope is mapped into the latent space through W_UK (the
+# k_nope half of W_kvb), scores are taken against the cached rows, the
+# softmax-weighted c_kv is mapped back through W_UV, then o_proj.
+# ---------------------------------------------------------------------------
+
+
+def _yarn_mscale(scale: float, mscale: float) -> float:
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def mla_rope_freqs(cfg: ModelConfig) -> jax.Array:
+    """Inverse frequencies of the rotary dims: YaRN's blend of the
+    original and the ``factor``-interpolated frequencies, ramped between
+    the correction dims of ``beta_fast`` and ``beta_slow`` rotations."""
+    dim = cfg.mla.qk_rope_head_dim
+    base = cfg.rope_theta
+    extra = 1.0 / base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    y = cfg.yarn
+    if y is None:
+        return extra
+
+    def corr_dim(rot):
+        return dim * math.log(y.original_max_position
+                              / (rot * 2 * math.pi)) / (2 * math.log(base))
+
+    low = max(math.floor(corr_dim(y.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(y.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp           # 1: the original frequency, 0: interpolated
+    return extra / y.factor * (1.0 - keep) + extra * keep
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """``qk_head_dim ** -0.5``, times YaRN's ``mscale(factor,
+    mscale_all_dim) ** 2`` when the rope is scaled."""
+    scale = cfg.mla.qk_head_dim ** -0.5
+    y = cfg.yarn
+    if y is not None and y.mscale_all_dim:
+        m = _yarn_mscale(y.factor, y.mscale_all_dim)
+        scale *= m * m
+    return scale
+
+
+def apply_mla_rope(x: jax.Array, positions: jax.Array,
+                   cfg: ModelConfig) -> jax.Array:
+    """DeepSeek's rotary convention: the rope dims hold interleaved pairs
+    (x[2i], x[2i+1]); they are de-interleaved to [evens; odds] and then
+    rotated half against half (``rotate_half``), so pair i turns by
+    ``position * inv_freq[i]``.  x: (B, S, ..., r); positions: (B, S)."""
+    B, S = x.shape[:2]
+    r = x.shape[-1]
+    y = cfg.yarn
+    ms = 1.0 if y is None else (_yarn_mscale(y.factor, y.mscale)
+                                / _yarn_mscale(y.factor, y.mscale_all_dim))
+    ang = positions.astype(jnp.float32)[..., None] * mla_rope_freqs(cfg)
+    extra = (1,) * (x.ndim - 3)
+    cos = (jnp.cos(ang) * ms).reshape(B, S, *extra, r // 2)
+    sin = (jnp.sin(ang) * ms).reshape(B, S, *extra, r // 2)
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., 0::2], xf[..., 1::2]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return out.astype(x.dtype)
+
+
+def init_mla(keys: KeyGen, cfg: ModelConfig) -> Tuple[Params, Params]:
+    m, d, H = cfg.mla, cfg.d_model, cfg.num_heads
+    dt = jnp.dtype(cfg.dtype)
+    p: Params = {
+        "wq": dense_init(keys(), d, H * m.qk_head_dim, dt),
+        "wkv_a": dense_init(keys(), d, m.latent_dim, dt),
+        "kv_norm": jnp.ones((m.kv_lora_rank,), dt),
+        "wkv_b": dense_init(keys(), m.kv_lora_rank,
+                            H * (m.qk_nope_head_dim + m.v_head_dim), dt),
+        "wo": dense_init(keys(), H * m.v_head_dim, d, dt),
+    }
+    a: Params = {"wq": ("embed", "heads_w"), "wkv_a": ("embed", None),
+                 "kv_norm": (None,), "wkv_b": (None, "heads_w"),
+                 "wo": ("heads_w", "embed")}
+    return p, a
+
+
+def _mla_project(params: Params, cfg: ModelConfig, x: jax.Array,
+                 positions: jax.Array):
+    """x (B, S, d) -> q_nope (B, S, H, n), roped q_pe (B, S, H, r) and
+    the cache rows (B, S, latent_dim) = [rms(c_kv); roped k_pe]."""
+    m, H = cfg.mla, cfg.num_heads
+    B, S, _ = x.shape
+    if positions.ndim == 3:       # (3, B, S) given but plain rope
+        positions = positions[0]
+    q = (x @ params["wq"]).reshape(B, S, H, m.qk_head_dim)
+    q_nope = q[..., :m.qk_nope_head_dim]
+    q_pe = apply_mla_rope(q[..., m.qk_nope_head_dim:], positions, cfg)
+    kv = x @ params["wkv_a"]
+    c = rmsnorm({"scale": params["kv_norm"]}, kv[..., :m.kv_lora_rank],
+                cfg.norm_eps)
+    k_pe = apply_mla_rope(kv[..., m.kv_lora_rank:], positions, cfg)
+    return q_nope, q_pe, jnp.concatenate([c, k_pe], -1)
+
+
+def _mla_kv_b(params: Params, cfg: ModelConfig):
+    """W_kvb as (rank, H, n + v): W_UK = [..., :n], W_UV = [..., n:]."""
+    m = cfg.mla
+    return params["wkv_b"].reshape(m.kv_lora_rank, cfg.num_heads,
+                                   m.qk_nope_head_dim + m.v_head_dim)
+
+
+def mla_block(params: Params, cfg: ModelConfig, x: jax.Array,
+              positions: jax.Array, return_cache: bool = False):
+    """Full (non-absorbed) causal latent attention over the whole
+    sequence, as published (training / full prefill).  With
+    ``return_cache`` also returns the cache rows {"c": (B, S, pool_dim)}
+    (zero-padded as the pool holds them)."""
+    m = cfg.mla
+    B, S, _ = x.shape
+    with jax.named_scope("mla"):
+        q_nope, q_pe, lat = _mla_project(params, cfg, x, positions)
+        c, k_pe = lat[..., :m.kv_lora_rank], lat[..., m.kv_lora_rank:]
+        kv = jnp.einsum("bsl,lhe->bshe", c, _mla_kv_b(params, cfg))
+        k_nope, v = kv[..., :m.qk_nope_head_dim], kv[..., m.qk_nope_head_dim:]
+        s = (jnp.einsum("bqhn,bkhn->bhqk", q_nope, k_nope,
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("bqhr,bkr->bhqk", q_pe, k_pe,
+                          preferred_element_type=jnp.float32))
+        s = s * mla_softmax_scale(cfg)
+        mask = jnp.tril(jnp.ones((S, S), jnp.bool_))
+        s = jnp.where(mask[None, None], s, -jnp.inf)
+        w = jax.nn.softmax(s, axis=-1)
+        out = jnp.einsum("bhqk,bkhv->bqhv", w.astype(v.dtype), v)
+        out = out.reshape(B, S, -1) @ params["wo"]
+    out = constrain(out, "batch", "seq", "act_embed")
+    if return_cache:
+        return out, {"c": _pool_rows(lat, cfg)}
+    return out
+
+
+def _mla_absorb_query(params: Params, cfg: ModelConfig, q_nope, q_pe):
+    """[q_nope . W_UK; q_pe] (.., H, pool_dim) in float32: the query in
+    the pool rows' space (zero over their padding)."""
+    m = cfg.mla
+    w_uk = _mla_kv_b(params, cfg)[..., :m.qk_nope_head_dim]
+    q_lat = jnp.einsum("...hn,lhn->...hl", q_nope, w_uk,
+                       preferred_element_type=jnp.float32)
+    return _pool_rows(jnp.concatenate([q_lat, q_pe.astype(jnp.float32)],
+                                      -1), cfg)
+
+
+def _mla_out(params: Params, cfg: ModelConfig, o_lat, dtype):
+    """Softmax-weighted c_kv (.., H, rank) -> W_UV -> o_proj (.., d)."""
+    m = cfg.mla
+    w_uv = _mla_kv_b(params, cfg)[..., m.qk_nope_head_dim:]
+    o = jnp.einsum("...hl,lhv->...hv", o_lat.astype(dtype), w_uv)
+    return o.reshape(*o.shape[:-2], -1) @ params["wo"]
+
+
+def _pool_rows(x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """Rows (.., latent_dim) zero-padded to the pool's ``pool_dim``."""
+    pad = cfg.mla.pool_dim - x.shape[-1]
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
+def init_latent_pool(cfg: ModelConfig, layers: int, num_pages: int,
+                     block_size: int):
+    """``layers`` latent pools stacked from the start: (layers, num_pages
+    + 1, block_size, pool_dim), page ``num_pages`` the null page."""
+    shape = (layers, num_pages + 1, block_size, cfg.mla.pool_dim)
+    return ({"c": jnp.zeros(shape, jnp.dtype(cfg.dtype))},
+            {"c": ("layers", "pages", None, None)})
+
+
+def mla_decode_paged(params: Params, cfg: ModelConfig, x: jax.Array,
+                     pool: Params, layer: jax.Array, index: jax.Array,
+                     positions: jax.Array, tables: jax.Array,
+                     valid: Optional[jax.Array] = None):
+    """K-token decode/verify in the absorbed form against layer ``layer``
+    of the stacked latent pool {"c": (layers, P+1, bs, pool_dim)}.
+
+    Rows, indices, tables and ``valid`` follow
+    :func:`attention_decode_paged` (index -1: an idle row, its write goes
+    to the null page).  The row's new cache entries are written in place
+    into the stacked pool (carried, never sliced out), then
+    :func:`repro.kernels.ops.latent_paged_attention` reads scores over
+    the whole row and values over its kv_lora_rank columns, from the
+    same pages.
+    Returns (out, new pool).
+    """
+    from repro.kernels import ops
+
+    B, K, _ = x.shape
+    c = pool["c"]
+    with jax.named_scope("mla"):
+        q_nope, q_pe, lat = _mla_project(params, cfg, x, positions)
+        bs = c.shape[2]
+        null_page = c.shape[1] - 1
+        W = tables.shape[1]
+        index = jnp.asarray(index, jnp.int32)
+        active = (index >= 0)[:, None]
+        if valid is not None:
+            active = active & (jnp.arange(K, dtype=jnp.int32)[None, :]
+                               < jnp.asarray(valid, jnp.int32)[:, None])
+        widx = jnp.maximum(index, 0)
+        wpos = widx[:, None] + jnp.arange(K, dtype=jnp.int32)[None, :]
+        page = jnp.take_along_axis(tables, jnp.minimum(wpos // bs, W - 1),
+                                   axis=1)
+        page = jnp.where(active, page, null_page)
+        c = c.at[layer, page.reshape(-1), (wpos % bs).reshape(-1)].set(
+            _pool_rows(lat, cfg).reshape(B * K, -1).astype(c.dtype))
+        q = _mla_absorb_query(params, cfg, q_nope, q_pe)
+        # idle rows read nothing (length 0)
+        lengths = jnp.where(index >= 0, widx + 1, 0)
+        o_lat = ops.latent_paged_attention(
+            q, c, layer, tables, lengths, scale=mla_softmax_scale(cfg),
+            value_dim=cfg.mla.kv_lora_rank)
+        out = _mla_out(params, cfg, o_lat, x.dtype)
+    return constrain(out, "batch", "seq", "act_embed"), {"c": c}
+
+
+# pages of keys a chunk's queries read per step of the online softmax
+# (1,024 tokens: a (heads, chunk, 1024) float32 score block)
+CHUNK_KEY_PAGES = 64
+
+
+def latent_chunk_attention(q: jax.Array, pages: jax.Array, layer,
+                           table: jax.Array, q_pos: jax.Array, scale: float,
+                           value_dim: int) -> jax.Array:
+    """Causal attention of C chunk queries over one request's pages.
+
+    q: (C, H, D) float32 absorbed queries; pages: the stacked pool
+    (layers, P+1, bs, D); table: (W,) the request's page ids; q_pos: (C,)
+    absolute query positions (query i sees keys at positions <=
+    q_pos[i]).  Keys are read ``CHUNK_KEY_PAGES`` pages at a time with
+    an online softmax, and only the blocks some query reaches, so no (C,
+    W * bs) score matrix is ever held.  Returns (C, H, value_dim) f32:
+    the softmax-weighted first ``value_dim`` columns of the rows.
+    """
+    C, H, D = q.shape
+    bs = pages.shape[2]
+    W = table.shape[0]
+    block_pages = CHUNK_KEY_PAGES
+    nb = -(-W // block_pages)
+    null_page = pages.shape[1] - 1
+    table = jnp.pad(table, (0, nb * block_pages - W),
+                    constant_values=null_page)
+    tk = block_pages * bs
+    n_live = jnp.minimum((jnp.max(q_pos) + tk) // tk, nb)
+
+    def body(j, carry):
+        acc, m_run, l_run = carry
+        ids = jax.lax.dynamic_slice(table, (j * block_pages,),
+                                    (block_pages,))
+        kv = pages[layer, ids].reshape(tk, D).astype(jnp.float32)
+        s = jnp.einsum("chd,td->hct", q, kv) * scale
+        kpos = j * tk + jnp.arange(tk, dtype=jnp.int32)
+        s = jnp.where((kpos[None, :] <= q_pos[:, None])[None], s, -jnp.inf)
+        m_new = jnp.maximum(m_run, jnp.max(s, axis=-1))
+        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+        p = jnp.where(jnp.isfinite(s), jnp.exp(s - m_safe[..., None]), 0.0)
+        corr = jnp.where(jnp.isfinite(m_run), jnp.exp(m_run - m_safe), 0.0)
+        acc = acc * corr[..., None] + jnp.einsum("hct,tl->hcl", p,
+                                                 kv[:, :value_dim])
+        return acc, m_new, l_run * corr + jnp.sum(p, axis=-1)
+
+    init = (jnp.zeros((H, C, value_dim), jnp.float32),
+            jnp.full((H, C), -jnp.inf, jnp.float32),
+            jnp.zeros((H, C), jnp.float32))
+    acc, _, l_run = jax.lax.fori_loop(0, n_live, body, init)
+    return (acc / jnp.maximum(l_run, 1e-30)[..., None]).transpose(1, 0, 2)
+
+
+def mla_chunk_paged(params: Params, cfg: ModelConfig, x: jax.Array,
+                    pool: Params, layer: jax.Array, tables: jax.Array,
+                    hist_len: jax.Array, prompt_len: jax.Array,
+                    positions: jax.Array):
+    """One chunked-prefill slice of a single request against layer
+    ``layer`` of the stacked latent pool, in the absorbed form: the
+    slice's cache rows are written first (padded positions to the null
+    page), then its queries attend over the request's pages, history
+    and slice under one causal mask (:func:`latent_chunk_attention`).
+    Returns (out, new pool)."""
+    B, C, _ = x.shape
+    assert B == 1
+    c = pool["c"]
+    with jax.named_scope("mla"):
+        q_nope, q_pe, lat = _mla_project(params, cfg, x, positions)
+        bs = c.shape[2]
+        null_page = c.shape[1] - 1
+        W = tables.shape[1]
+        abs_pos = jnp.asarray(hist_len, jnp.int32) \
+            + jnp.arange(C, dtype=jnp.int32)
+        page = jnp.take(tables[0], jnp.minimum(abs_pos // bs, W - 1))
+        page = jnp.where(abs_pos < prompt_len, page, null_page)
+        c = c.at[layer, page, abs_pos % bs].set(
+            _pool_rows(lat[0], cfg).astype(c.dtype))
+        q = _mla_absorb_query(params, cfg, q_nope[0], q_pe[0])
+        o_lat = latent_chunk_attention(q, c, layer, tables[0], abs_pos,
+                                       mla_softmax_scale(cfg),
+                                       cfg.mla.kv_lora_rank)
+        out = _mla_out(params, cfg, o_lat, x.dtype)[None]
+    return constrain(out, "batch", "seq", "act_embed"), {"c": c}
+
+
 def decode_scan(step_fn, x: jax.Array, state,
                 valid: Optional[jax.Array] = None):
     """Drive a single-token recurrent decode step over K tokens.
@@ -702,7 +1014,7 @@ MOE_GROUP_TOKENS = int(_os.environ.get("REPRO_MOE_GROUP", "1024"))
 
 
 def moe_block(params: Params, cfg: ModelConfig, x: jax.Array,
-              dropless: bool = False
+              dropless: bool = False, mask: Optional[jax.Array] = None
               ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Capacity-based top-k MoE with GShard group dispatch.
 
@@ -714,7 +1026,10 @@ def moe_block(params: Params, cfg: ModelConfig, x: jax.Array,
 
     ``dropless=True`` (decode/eval) sizes the buffers so no token is ever
     dropped, making prefill/decode bit-consistent with full forward.
-    Returns (out, aux) with load-balancing and z losses.
+    Returns (out, aux) with load-balancing and z losses.  Given ``mask``
+    ((B, S), true on real tokens), aux also counts the real tokens
+    routed to each expert (``expert_tokens``, (E,) int32) and the
+    routed choices dropped for capacity (``dropped_tokens``).
     """
     m = cfg.moe
     B, S, d = x.shape
@@ -734,8 +1049,9 @@ def moe_block(params: Params, cfg: ModelConfig, x: jax.Array,
                         preferred_element_type=jnp.float32)    # (G,Tg,E)
     probs = jax.nn.softmax(logits, axis=-1)
     gate_vals, gate_idx = jax.lax.top_k(probs, k)              # (G,Tg,k)
-    gate_vals = gate_vals / jnp.clip(
-        jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+    if m.norm_topk_prob:
+        gate_vals = gate_vals / jnp.clip(
+            jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
 
     if dropless:
         capacity = Tg   # per-expert worst case (choices per token distinct)
@@ -807,4 +1123,9 @@ def moe_block(params: Params, cfg: ModelConfig, x: jax.Array,
         "moe_load_balance": jnp.sum(density * density_proxy) * E,
         "moe_z": jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2),
     }
+    if mask is not None:
+        real = mask.reshape(G, Tg, 1).astype(jnp.int32)
+        aux["expert_tokens"] = jnp.sum(onehot * real[..., None],
+                                       axis=(0, 1, 2))
+        aux["dropped_tokens"] = jnp.sum((~keep).astype(jnp.int32) * real)
     return out.reshape(B, S, d), aux

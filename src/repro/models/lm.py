@@ -44,9 +44,15 @@ Params = Dict[str, Any]
 
 
 class LayerSpec(NamedTuple):
-    kind: str      # 'a' attention | 'M' mamba | 'm' mLSTM | 's' sLSTM
+    kind: str      # 'a' attention | 'l' latent attention (MLA)
+    #                | 'M' mamba | 'm' mLSTM | 's' sLSTM
     ffn: str       # 'dense' | 'moe' | 'none'
     d_ff: int
+
+
+# mixers whose cache is pages of a shared pool (prompts may be padded and
+# prefilled in chunks)
+ATTENTION_KINDS = ("a", "l")
 
 
 def layer_specs(cfg: ModelConfig) -> Tuple[LayerSpec, ...]:
@@ -58,6 +64,8 @@ def layer_specs(cfg: ModelConfig) -> Tuple[LayerSpec, ...]:
     pat = cfg.block_pattern
     for i in range(cfg.num_layers):
         kind = pat[i % len(pat)]
+        if kind == "a" and cfg.mla is not None:
+            kind = "l"
         if cfg.moe is not None and cfg.is_moe_layer(i):
             ffn = "moe"
             d_ff = 0
@@ -100,6 +108,8 @@ def _init_block(keys: L.KeyGen, cfg: ModelConfig, spec: LayerSpec):
     p["ln1"], a["ln1"] = L.init_rmsnorm(d, dt)
     if spec.kind == "a":
         p["mixer"], a["mixer"] = L.init_attention(keys, cfg)
+    elif spec.kind == "l":
+        p["mixer"], a["mixer"] = L.init_mla(keys, cfg)
     elif spec.kind == "M":
         p["mixer"], a["mixer"] = S.init_mamba(keys, cfg)
     elif spec.kind == "m":
@@ -171,7 +181,8 @@ def _zero_aux():
 
 def _apply_block(bp: Params, cfg: ModelConfig, spec: LayerSpec, x, positions,
                  mode: str, cache=None, index=None, tables=None,
-                 hist_len=None, prompt_len=None, valid=None):
+                 hist_len=None, prompt_len=None, valid=None, layer=None,
+                 token_mask=None):
     """Returns (x, new_cache, aux).
 
     ``tables`` switches attention layers onto the paged-KV path:
@@ -182,8 +193,12 @@ def _apply_block(bp: Params, cfg: ModelConfig, spec: LayerSpec, x, positions,
     marks how many of the K are real per row — attention routes the
     rest to the null page (paged) and recurrent mixers freeze their
     state past it (the speculative verify / rollback-replay contract).
+    Latent attention ('l') gets the WHOLE stacked latent pool as
+    ``cache`` and its own index in the stack as ``layer``, and returns
+    the whole pool.  ``token_mask`` ((B, S), true on real tokens) makes
+    an MoE layer count its routing into ``aux``.
     """
-    if mode == "chunk" and spec.kind != "a":
+    if mode == "chunk" and spec.kind not in ATTENTION_KINDS:
         raise ValueError(
             "chunked prefill requires an attention-only stack "
             f"(got mixer kind {spec.kind!r})")
@@ -211,6 +226,23 @@ def _apply_block(bp: Params, cfg: ModelConfig, spec: LayerSpec, x, positions,
             # overwritten, so no routing is needed
             mix, new_cache = L.attention_decode(bp["mixer"], cfg, h, cache,
                                                 index, positions)
+    elif spec.kind == "l":
+        if mode == "train":
+            mix = L.mla_block(bp["mixer"], cfg, h, positions)
+        elif mode == "prefill":
+            mix, new_cache = L.mla_block(bp["mixer"], cfg, h, positions,
+                                         return_cache=True)
+        elif mode == "chunk":
+            mix, new_cache = L.mla_chunk_paged(
+                bp["mixer"], cfg, h, cache, layer, tables, hist_len,
+                prompt_len, positions)
+        elif tables is not None:
+            mix, new_cache = L.mla_decode_paged(
+                bp["mixer"], cfg, h, cache, layer, index, positions, tables,
+                valid=valid)
+        else:
+            raise ValueError("latent attention decodes through the paged "
+                             "pool only (pass block tables)")
     elif spec.kind == "M":
         if mode == "train":
             mix = S.mamba_block(bp["mixer"], cfg, h)
@@ -246,9 +278,11 @@ def _apply_block(bp: Params, cfg: ModelConfig, spec: LayerSpec, x, positions,
     if spec.ffn != "none":
         h2 = L.rmsnorm(bp["ln2"], x, cfg.norm_eps)
         if spec.ffn == "moe":
-            f, moe_aux = L.moe_block(bp["ffn"], cfg, h2,
-                                     dropless=(mode != "train"))
-            aux = {k: aux[k] + moe_aux.get(k, 0.0) for k in aux}
+            with jax.named_scope("moe"):
+                f, moe_aux = L.moe_block(bp["ffn"], cfg, h2,
+                                         dropless=(mode != "train"),
+                                         mask=token_mask)
+            aux = {**moe_aux, **{k: aux[k] + moe_aux[k] for k in aux}}
         else:
             f = L.mlp_block(bp["ffn"], h2)
         x = x + f
@@ -398,8 +432,15 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int = 0,
     through per-request block tables, so a request's KV can be
     scattered anywhere in the pool.  Recurrent layers (mamba / xLSTM)
     carry O(1) state per request and keep ``batch`` dense rows in both
-    layouts.  Returns (cache, axes); axes leaves containing ``"pages"``
-    / ``"kv_seq"`` identify attention KV, everything else is the
+    layouts.  Latent-attention layers (paged only) get one latent pool
+    per stack, allocated already stacked
+    (:func:`repro.models.layers.init_latent_pool`), and an MoE model
+    with them also gets ``moe_stats``: the real tokens each layer routed
+    to each expert (``expert_tokens``, (num_layers, E) int32) and the
+    routed choices dropped for capacity (``dropped_tokens``), counted on
+    the device by every decode step and prefill chunk.  Returns (cache,
+    axes); axes leaves containing ``"pages"`` / ``"kv_seq"`` identify
+    attention KV, ``"counters"`` the counters, everything else is the
     recurrent state that snapshot/restore (speculative rollback)
     copies.
     """
@@ -411,18 +452,105 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int = 0,
     def make(spec):
         return _init_block_cache(cfg, spec, batch, max_len, pages)
 
-    if k0:
+    def latent(n):
+        if pages is None:
+            raise ValueError("latent attention keeps its cache in a paged "
+                             "pool only (pass pages=)")
+        return L.init_latent_pool(cfg, n, pages[0], pages[1])
+
+    if k0 and specs[0].kind == "l":
+        cache["prefix"], axes["prefix"] = latent(k0)
+    elif k0:
         per = [make(specs[i]) for i in range(k0)]
         cache["prefix"] = _stack([c for c, _ in per])
         axes["prefix"] = _push_axis(per[0][1], "layers")
     body_c, body_a = [], []
     for j in range(R):
+        if specs[k0 + j].kind == "l":
+            c, a = latent(P)
+            body_c.append(c)
+            body_a.append(a)
+            continue
         per = [make(specs[k0 + pi * R + j]) for pi in range(P)]
         body_c.append(_stack([c for c, _ in per]))
         body_a.append(_push_axis(per[0][1], "period"))
     cache["body"] = tuple(body_c)
     axes["body"] = tuple(body_a)
+    if _carries_pools(cfg) and cfg.moe is not None:
+        E = cfg.moe.num_experts
+        cache["moe_stats"] = {
+            "expert_tokens": jnp.zeros((cfg.num_layers, E), jnp.int32),
+            "dropped_tokens": jnp.zeros((cfg.num_layers,), jnp.int32)}
+        axes["moe_stats"] = {"expert_tokens": ("counters", None),
+                             "dropped_tokens": ("counters",)}
     return cache, axes
+
+
+def _carries_pools(cfg: ModelConfig) -> bool:
+    """Latent stacks carry their stacked pools through the layer scan
+    (each layer writes and reads its own slice in place) instead of
+    scanning over them, which would copy every layer's pool out and
+    back each call."""
+    return any(s.kind == "l" for s in layer_specs(cfg))
+
+
+def _run_carried(cfg: ModelConfig, x, params: Params, cache: Params,
+                 apply):
+    """Drive a latent stack: ``apply(spec, layer_params, pool, layer, x)
+    -> (x, pool, aux)`` per layer, the prefix and body pools in the scan
+    carry and the layer's index in the stack as the scanned input.
+    Adds each layer's routing counts into ``cache["moe_stats"]`` when it
+    is there.  Returns (x, new_cache)."""
+    specs = layer_specs(cfg)
+    k0, R, _ = _grouping(cfg)
+    counting = "moe_stats" in cache
+    E = cfg.moe.num_experts if counting else 0
+
+    def run(x, stacked, pools, spec_list):
+        single = not isinstance(stacked, tuple)
+        n = jax.tree.leaves(stacked)[0].shape[0]
+
+        def body(carry, inp):
+            xc, cs = carry
+            lps, li = inp
+            lps = (lps,) if single else lps
+            cs = (cs,) if single else cs
+            new, tok, drop = [], [], []
+            for sp, lp, lc in zip(spec_list, lps, cs):
+                xc, nc, aux = apply(sp, lp, lc, li, xc)
+                new.append(nc)
+                tok.append(aux.get("expert_tokens",
+                                   jnp.zeros((E,), jnp.int32)))
+                drop.append(aux.get("dropped_tokens",
+                                    jnp.zeros((), jnp.int32)))
+            cs = new[0] if single else tuple(new)
+            ys = (jnp.stack(tok), jnp.stack(drop)) if counting else None
+            return (xc, cs), ys
+
+        (x, pools), ys = jax.lax.scan(
+            body, (x, pools), (stacked, jnp.arange(n, dtype=jnp.int32)))
+        if counting:
+            ys = (ys[0].reshape(-1, E), ys[1].reshape(-1))
+        return x, pools, ys
+
+    new_cache: Params = {}
+    counts = []
+    if "prefix" in params:
+        x, new_cache["prefix"], ys = run(x, params["prefix"],
+                                         cache["prefix"], (specs[0],))
+        counts.append(ys)
+    x, new_cache["body"], ys = run(x, tuple(params["body"]),
+                                   tuple(cache["body"]),
+                                   tuple(specs[k0:k0 + R]))
+    counts.append(ys)
+    if counting:
+        st = cache["moe_stats"]
+        new_cache["moe_stats"] = {
+            "expert_tokens": st["expert_tokens"]
+            + jnp.concatenate([c[0] for c in counts]),
+            "dropped_tokens": st["dropped_tokens"]
+            + jnp.concatenate([c[1] for c in counts])}
+    return x, new_cache
 
 
 def _prefill_chunk(params: Params, cfg: ModelConfig, tokens: jax.Array,
@@ -468,12 +596,25 @@ def _prefill_chunk(params: Params, cfg: ModelConfig, tokens: jax.Array,
 
         return jax.lax.scan(body, x, (stacked, cache_stacked))
 
-    new_cache: Params = {}
-    if "prefix" in params:
-        x, pc = run_stack(x, params["prefix"], cache["prefix"], (specs[0],))
-        new_cache["prefix"] = pc[0]
-    x, bc = run_stack(x, tuple(params["body"]), tuple(cache["body"]), pspecs)
-    new_cache["body"] = bc
+    if _carries_pools(cfg):
+        real = pos < prompt_len
+
+        def apply(sp, lp, lc, li, xc):
+            return _apply_block(lp, cfg, sp, xc, positions, "chunk",
+                                cache=lc, tables=tables, hist_len=hist_len,
+                                prompt_len=prompt_len, layer=li,
+                                token_mask=real)
+
+        x, new_cache = _run_carried(cfg, x, params, cache, apply)
+    else:
+        new_cache = {}
+        if "prefix" in params:
+            x, pc = run_stack(x, params["prefix"], cache["prefix"],
+                              (specs[0],))
+            new_cache["prefix"] = pc[0]
+        x, bc = run_stack(x, tuple(params["body"]), tuple(cache["body"]),
+                          pspecs)
+        new_cache["body"] = bc
     idx = jnp.broadcast_to(
         jnp.asarray(last_pos, jnp.int32)[:, None, None],
         (x.shape[0], 1, x.shape[2]))
@@ -596,6 +737,21 @@ def lm_decode(params: Params, cfg: ModelConfig, tokens: jax.Array,
             return xc, tuple(new_caches)
 
         return jax.lax.scan(body, x, (stacked, cache_stacked))
+
+    if _carries_pools(cfg):
+        real = jnp.broadcast_to(index >= 0, (B,))[:, None] \
+            & jnp.ones((1, K), bool)
+        if valid is not None:
+            real = real & (jnp.arange(K, dtype=jnp.int32)[None, :]
+                           < valid[:, None])
+
+        def apply(sp, lp, lc, li, xc):
+            return _apply_block(lp, cfg, sp, xc, positions, "decode",
+                                cache=lc, index=index, tables=tables,
+                                valid=valid, layer=li, token_mask=real)
+
+        x, new_cache = _run_carried(cfg, x, params, cache, apply)
+        return _logits(params, cfg, x), new_cache
 
     new_cache: Params = {}
     if "prefix" in params:

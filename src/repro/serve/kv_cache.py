@@ -64,6 +64,7 @@ Two implementations, both driven through
 """
 from __future__ import annotations
 
+import hashlib
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -74,6 +75,18 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import lm
+
+
+def _chain_key(prev: Optional[bytes], chunk: np.ndarray) -> bytes:
+    """Prefix-cache key of a fully-filled page: a 16-byte BLAKE2b digest
+    of the previous page's key and this page's token ids, so equal keys
+    mean equal prefixes (a false match needs a 128-bit collision) and a
+    key costs one page of hashing, whatever the prefix's length (a
+    nested tuple of every earlier page hashed and compared in time
+    growing with the prefix: quadratic over a 32k-token prompt)."""
+    h = hashlib.blake2b(prev or b"", digest_size=16)
+    h.update(np.asarray(chunk, np.int64).tobytes())
+    return h.digest()
 
 
 def blocks_for(n_tokens: int, block_size: int) -> int:
@@ -322,10 +335,16 @@ def _leaf_is_paged(axes_leaf) -> bool:
 
 
 def _leaf_is_kv(axes_leaf) -> bool:
-    """Attention KV leaves (either layout); everything else is the
-    recurrent state snapshot/restore copies."""
+    """Attention KV leaves (either layout); everything else but the
+    counters is the recurrent state snapshot/restore copies."""
     return isinstance(axes_leaf, tuple) and \
         ("pages" in axes_leaf or "kv_seq" in axes_leaf)
+
+
+def _leaf_is_counter(axes_leaf) -> bool:
+    """Device-side counters (``moe_stats``): neither KV nor per-slot
+    state; steps add to them, prefills and rollbacks leave them be."""
+    return isinstance(axes_leaf, tuple) and "counters" in axes_leaf
 
 
 def _axes_leaves(axes):
@@ -480,9 +499,8 @@ class PageShard:
         the chain key of page ``start - 1`` when resuming."""
         keys = []
         for i in range(start, len(prompt) // block_size):
-            chunk = tuple(int(t) for t in
-                          prompt[i * block_size:(i + 1) * block_size])
-            prev = (prev, chunk)
+            prev = _chain_key(prev,
+                              prompt[i * block_size:(i + 1) * block_size])
             keys.append(prev)
         return keys
 
@@ -605,15 +623,14 @@ def _prefix_key_memo(prompt: np.ndarray, block_size: int):
     """Lazy chain-key supplier for ``prompt``: ``key_at(i)`` hashes
     chunks only up to page i, memoized — a page-0 miss still costs one
     hash, and multiple shard probes share one derivation."""
-    keys: List[Any] = []
+    keys: List[bytes] = []
 
     def key_at(i: int):
         while len(keys) <= i:
             j = len(keys)
-            prev = keys[-1] if keys else None
-            chunk = tuple(int(t) for t in
-                          prompt[j * block_size:(j + 1) * block_size])
-            keys.append((prev, chunk))
+            keys.append(_chain_key(keys[-1] if keys else None,
+                                   prompt[j * block_size:(j + 1)
+                                          * block_size]))
         return keys[i]
 
     return key_at
@@ -633,12 +650,19 @@ def _insert_leaf_slot(dst, src, slot):
 
 @partial(jax.jit, static_argnums=(1,), donate_argnums=(0,))
 def _insert_tree_paged(pool, paged_mask, src, page_ids, offsets, slot):
+    """``paged_mask`` per pool leaf: True a page pool, False a slot row,
+    None a counter the prefill cache does not hold (kept as it is)."""
     flat_pool, tree = jax.tree.flatten(pool)
-    flat_src = jax.tree.leaves(src)
-    out = [
-        _insert_leaf_paged(d, s, page_ids, offsets) if paged
-        else _insert_leaf_slot(d, s, slot)
-        for d, s, paged in zip(flat_pool, flat_src, paged_mask)]
+    flat_src = iter(jax.tree.leaves(src))
+    out = []
+    for d, paged in zip(flat_pool, paged_mask):
+        if paged is None:
+            out.append(d)
+        elif paged:
+            out.append(_insert_leaf_paged(d, next(flat_src), page_ids,
+                                          offsets))
+        else:
+            out.append(_insert_leaf_slot(d, next(flat_src), slot))
     return jax.tree.unflatten(tree, out)
 
 
@@ -698,7 +722,9 @@ class PagedLayout(CacheLayout):
                                          pages=(total - 1, block_size))
         self.paged_mask = tuple(_leaf_is_paged(a)
                                 for a in _axes_leaves(axes))
-        self.rec_mask = tuple(not _leaf_is_kv(a)
+        self.counter_mask = tuple(_leaf_is_counter(a)
+                                  for a in _axes_leaves(axes))
+        self.rec_mask = tuple(not (_leaf_is_kv(a) or _leaf_is_counter(a))
                               for a in _axes_leaves(axes))
         if placer is not None:
             self.cache = placer(self.cache, axes)
@@ -908,7 +934,8 @@ class PagedLayout(CacheLayout):
         table = [shard.offset + p for p in shard.blocks.table(rid)]
         # per-token page targets; positions past prompt_len (padding)
         # are dropped onto the row's shard's null page
-        kv_len = _first_kv_len(prefill_cache, self.paged_mask)
+        kv_len = _first_kv_len(prefill_cache, [
+            p for p, c in zip(self.paged_mask, self.counter_mask) if not c])
         if kv_len is None:          # pure-recurrent stack: no KV pages
             kv_len = prompt_len
         pos = np.arange(kv_len)
@@ -917,8 +944,10 @@ class PagedLayout(CacheLayout):
         pids[valid] = np.asarray(table, np.int32)[pos[valid]
                                                   // self.block_size]
         offs = (pos % self.block_size).astype(np.int32)
+        mask = tuple(None if c else p
+                     for p, c in zip(self.paged_mask, self.counter_mask))
         self.cache = _insert_tree_paged(
-            self.cache, self.paged_mask, prefill_cache,
+            self.cache, mask, prefill_cache,
             jnp.asarray(pids), jnp.asarray(offs), jnp.int32(slot))
 
     def release(self, rid) -> int:
@@ -933,6 +962,30 @@ class PagedLayout(CacheLayout):
         """Flush every shard's prefix cache + pins (hot swap)."""
         for shard in self.shards:
             shard.invalidate_prefix()
+
+    @property
+    def bytes_per_token(self) -> int:
+        """Device bytes the pools hold for one token, over every layer
+        (page pools' bytes over their capacity in tokens)."""
+        leaves = [x for x, p in zip(jax.tree.leaves(self.cache),
+                                    self.paged_mask) if p]
+        tokens = self.data_shards * (self.shards[0].blocks.num_blocks + 1) \
+            * self.block_size
+        return sum(x.nbytes for x in leaves) // max(tokens, 1)
+
+    def moe_counts(self) -> Optional[Dict[str, np.ndarray]]:
+        """The device's routing counters copied to the host (one copy;
+        None when the stack keeps none): ``expert_tokens`` (layers, E)
+        and ``dropped_tokens`` (layers,)."""
+        if not (isinstance(self.cache, dict) and "moe_stats" in self.cache):
+            return None
+        return {k: np.asarray(v) for k, v in self.cache["moe_stats"].items()}
+
+    def reset_moe_counts(self) -> None:
+        """Zero the routing counters (device-side; nothing is copied)."""
+        if isinstance(self.cache, dict) and "moe_stats" in self.cache:
+            self.cache = {**self.cache, "moe_stats": jax.tree.map(
+                jnp.zeros_like, self.cache["moe_stats"])}
 
     def table_width_for(self, max_tokens: int) -> int:
         """Block-table columns needed to cover `max_tokens` (the

@@ -698,6 +698,9 @@ class MeshScheduler(Scheduler):
                  local_mesh: bool = False,
                  step_timeout_s: float = 60.0,
                  stats_every: int = 1, **kwargs):
+        if cfg.mla is not None:
+            raise ValueError("the serving mesh holds no latent (MLA) pool "
+                             "yet: serve latent attention on one device")
         if mesh is None:
             if mesh_shape is None:
                 mesh_shape = (jax.device_count(), 1)

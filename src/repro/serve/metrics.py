@@ -174,6 +174,13 @@ class ServeStats:
     decode_tokens: int = 0         # useful generated tokens
     decode_slot_steps: int = 0     # slots * steps actually computed
     sample_fetch_bytes: int = 0    # bytes copied to the host for sampling
+    # latent attention (MLA) + MoE: the pool's bytes a token over every
+    # layer, and the device's routing counters as last read
+    # (Scheduler.read_moe_counters): routed tokens per expert, summed
+    # over layers, and routed choices dropped for capacity
+    latent_cache_bytes_per_token: int = 0
+    moe_routed_tokens: List[int] = field(default_factory=list)
+    moe_dropped_tokens: int = 0
     # speculative decoding (population drafter)
     spec_rounds: int = 0           # target verify steps
     spec_draft_steps: int = 0      # drafter decode dispatches
@@ -254,6 +261,9 @@ class ServeStats:
             "decode_tokens": self.decode_tokens,
             "decode_slot_steps": self.decode_slot_steps,
             "sample_fetch_bytes": self.sample_fetch_bytes,
+            "latent_cache_bytes_per_token":
+                self.latent_cache_bytes_per_token,
+            "moe_dropped_tokens": self.moe_dropped_tokens,
             "spec_rounds": self.spec_rounds,
             "spec_draft_steps": self.spec_draft_steps,
             "spec_draft_proposed": self.spec_draft_proposed,

@@ -231,9 +231,11 @@ class Scheduler:
         # (one compile per distinct length instead of per bucket) —
         # and one-shot: chunked prefill needs mid-prompt resume, which
         # only the paged attention path supports.
-        self._can_pad = all(s.kind == "a" for s in lm.layer_specs(cfg))
+        self._can_pad = all(s.kind in lm.ATTENTION_KINDS
+                            for s in lm.layer_specs(cfg))
         self._draft_can_pad = all(
-            s.kind == "a" for s in lm.layer_specs(self.draft_cfg))
+            s.kind in lm.ATTENTION_KINDS
+            for s in lm.layer_specs(self.draft_cfg))
         self._chunked = self.paged and self._can_pad
         self.prefix_sharing = bool(prefix_sharing) and self._chunked
         # ragged gather-width grouping only pays on the CPU oracle (the
@@ -264,6 +266,9 @@ class Scheduler:
         self.spec_k_by_rid: Dict[Any, int] = {}
         self.results: Dict[Any, np.ndarray] = {}
         self.stats = ServeStats(slots=num_slots)
+        if self.paged and cfg.mla is not None:
+            self.stats.latent_cache_bytes_per_token = \
+                self.pool.bytes_per_token
         # request tracing + phase attribution + profiler window;
         # telemetry=False keeps the counters but drops the spans
         self.telemetry = ServeTelemetry(enabled=telemetry,
@@ -942,6 +947,20 @@ class Scheduler:
             last = last_token_pos[act.slot]
             if first // bs != (first - 1) // bs or last // bs != first // bs:
                 pool.ensure(act.req.rid, last + 1)
+
+    def read_moe_counters(self) -> Optional[Dict[str, np.ndarray]]:
+        """Copy the device's MoE routing counters into ``stats`` (one
+        host copy, made when asked, never by a step) and return them:
+        ``expert_tokens`` (layers, E), the real tokens each layer routed
+        to each expert since the counters were last zeroed, and
+        ``dropped_tokens`` (layers,).  None for stacks that keep none."""
+        counts = self.pool.moe_counts() if self.paged else None
+        if counts is not None:
+            self.stats.moe_routed_tokens = [
+                int(n) for n in counts["expert_tokens"].sum(axis=0)]
+            self.stats.moe_dropped_tokens = int(
+                counts["dropped_tokens"].sum())
+        return counts
 
     def _width_split(self) -> List[tuple]:
         """Partition active rows for the ragged-gather fix: when one

@@ -201,6 +201,7 @@ _SNAPSHOT_COUNTERS = (
     "decode_tokens",
     "decode_slot_steps",
     "sample_fetch_bytes",
+    "moe_dropped_tokens",
     "ragged_splits",
     "spec_rounds",
     "spec_draft_steps",
@@ -275,6 +276,8 @@ _COUNTER_HELP = {
     "decode_slot_steps": "per-slot decode steps",
     "sample_fetch_bytes": "bytes copied to the host for sampling "
                           "(the drawn token ids)",
+    "moe_dropped_tokens": "routed expert choices dropped for capacity "
+                          "(as last read from the device)",
     "ragged_splits": "ragged gather-width split dispatches",
     "spec_rounds": "speculative verify rounds",
     "spec_draft_steps": "drafter decode dispatches",
@@ -406,6 +409,10 @@ def prometheus_text(
     if slots_busy is not None:
         gauges.append(("slots_busy", "slots prefilling or decoding",
                        slots_busy))
+    if getattr(stats, "latent_cache_bytes_per_token", 0):
+        gauges.append(("latent_cache_bytes_per_token",
+                       "latent pool bytes held per token over every layer",
+                       stats.latent_cache_bytes_per_token))
     d = stats.as_dict()
     for k in ("tokens_per_s", "requests_per_s", "spec_accept_rate",
               "spec_k_mean", "queue_depth_mean", "slot_occupancy"):
@@ -417,6 +424,15 @@ def prometheus_text(
         out.append(f"# HELP {name} {help_}")
         out.append(f"# TYPE {name} gauge")
         out.append(f"{name} {_fmt(v)}")
+
+    routed = getattr(stats, "moe_routed_tokens", None)
+    if routed:
+        name = f"{_PREFIX}moe_routed_tokens_total"
+        out.append(f"# HELP {name} real tokens routed to each expert, "
+                   "summed over layers (as last read from the device)")
+        out.append(f"# TYPE {name} counter")
+        for e, n in enumerate(routed):
+            out.append(f'{name}{{expert="{e}"}} {int(n)}')
 
     _hist_lines(out, f"{_PREFIX}ttft_seconds", "time to first token",
                 stats.ttft)

@@ -15,8 +15,10 @@ from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import OptimizerConfig
+from repro.configs.deepseek_v2_lite import FULL as DSV2
 from repro.configs.icf_cyclegan import FULL as ICF_FULL
 from repro.configs.qwen3_06b import FULL as QWEN3
+from repro.kernels import latent_attention as la
 from repro.kernels import ops
 from repro.kernels import paged_attention as pa
 from repro.serve import sampling
@@ -71,6 +73,30 @@ def test_paged_kernel_compiles_at_qwen3_shapes(one_chip, k_tokens):
             _spec((B,), jnp.int32, one_chip)]
     compiled = jax.jit(pa.paged_attention).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("k_tokens", [1, 4], ids=["decode", "verify"])
+def test_latent_kernel_compiles_at_deepseek_v2_widths(one_chip, k_tokens):
+    """16 query heads over one latent row of 576 values (512 of them the
+    values), padded to 640 lanes, 16-token pages, 2,048 table columns
+    (32k tokens), 64 rows, a two-layer stacked pool."""
+    m, H, rows = DSV2.mla, DSV2.num_heads, 64
+    q = (rows, H, m.pool_dim) if k_tokens == 1 \
+        else (rows, k_tokens, H, m.pool_dim)
+
+    def gather(q, pool, layer, tables, lengths):
+        return la.latent_paged_attention(q, pool, layer, tables, lengths,
+                                         scale=0.1,
+                                         value_dim=m.kv_lora_rank)
+
+    compiled = jax.jit(gather).lower(
+        _spec(q, jnp.float32, one_chip),
+        _spec((2, 4097, BS, m.pool_dim), jnp.bfloat16, one_chip),
+        _spec((), jnp.int32, one_chip),
+        _spec((rows, 2048), jnp.int32, one_chip),
+        _spec((rows,), jnp.int32, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024 ** 2
 
 
 @pytest.mark.parametrize("rows,k_tokens,dtype", [
