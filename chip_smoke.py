@@ -233,7 +233,8 @@ def decode_hlo(jnp, sched) -> str:
 
 def kernel_vs_oracle(jax, jnp, np, sched, seed: int) -> float:
     """The compiled Pallas kernel against the jnp oracle at the run's
-    pool, batch and table shapes (decode K=1 and verify K=4)."""
+    pool, batch and table shapes (decode K=1 and verify K=4), reading
+    the second layer of a two-layer stacked pool."""
     from repro.kernels import paged_attention as pa
     from repro.kernels import ref
 
@@ -246,16 +247,18 @@ def kernel_vs_oracle(jax, jnp, np, sched, seed: int) -> float:
     worst = 0.0
     for K in (1, 4):
         q = rng.normal(size=(B, K, H, D) if K > 1 else (B, H, D))
-        k = rng.normal(size=(P1, bs, Hkv, D))
-        v = rng.normal(size=(P1, bs, Hkv, D))
+        k = rng.normal(size=(2, P1, bs, Hkv, D))
+        v = rng.normal(size=(2, P1, bs, Hkv, D))
         args = [jnp.asarray(a, jnp.bfloat16) for a in (q, k, v)]
+        layer = jnp.int32(1)
         tables = jnp.asarray(rng.integers(0, P1 - 1, (B, W)), jnp.int32)
         lengths = jnp.asarray(rng.integers(1, W * bs - K + 2, (B,)),
                               jnp.int32)
-        got = kernel(*args, tables, lengths).astype(jnp.float32)
+        got = kernel(*args, layer, tables, lengths).astype(jnp.float32)
         with jax.default_matmul_precision("highest"):
             want = ref.paged_attention_ref(
-                *[a.astype(jnp.float32) for a in args], tables, lengths)
+                *[a.astype(jnp.float32) for a in args], layer, tables,
+                lengths)
         err = float(jnp.max(jnp.abs(got - want)))
         log(f"serve: paged kernel vs oracle K={K} B={B} H={H} Hkv={Hkv} "
             f"D={D} pages={P1} block={bs} W={W} max_abs_err={err:.3e}")

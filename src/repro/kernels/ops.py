@@ -45,7 +45,7 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
                               block_k=block_k, interpret=interpret)
 
 
-def _paged_attention_local(q, k_pages, v_pages, tables, lengths,
+def _paged_attention_local(q, k_pages, v_pages, layer, tables, lengths,
                            interpret=None):
     """Single-shard gather: Pallas kernel on TPU (or when interpret
     mode is explicitly requested), jnp oracle everywhere else —
@@ -53,18 +53,21 @@ def _paged_attention_local(q, k_pages, v_pages, tables, lengths,
     a decode loop."""
     if interpret is None:
         if not _on_tpu():
-            return ref.paged_attention_ref(q, k_pages, v_pages, tables,
-                                           lengths)
+            return ref.paged_attention_ref(q, k_pages, v_pages, layer,
+                                           tables, lengths)
         interpret = False
-    return pa.paged_attention(q, k_pages, v_pages, tables, lengths,
+    return pa.paged_attention(q, k_pages, v_pages, layer, tables, lengths,
                               interpret=interpret)
 
 
-def paged_attention_sharded(mesh, q, k_pages, v_pages, tables, lengths,
-                            interpret: bool = None):
-    """Gather-decode over a page pool sharded on the mesh ``data`` axis.
+def paged_attention_sharded(mesh, q, k_pages, v_pages, layer, tables,
+                            lengths, interpret: bool = None):
+    """Gather-decode over a stacked page pool sharded on the mesh
+    ``data`` axis.
 
-    The pool's page dim is split into ``data``-many private sub-pools
+    The pool's page dim (dim 1; dim 0 is the layer, replicated, and
+    ``layer`` passes through to every shard) is split into
+    ``data``-many private sub-pools
     (each with its own trailing null page); ``tables`` holds GLOBAL
     page ids, and every row's pages live in that row's shard — the
     invariant :class:`repro.serve.mesh.MeshPagedLayout` maintains.
@@ -78,39 +81,43 @@ def paged_attention_sharded(mesh, q, k_pages, v_pages, tables, lengths,
     data = axes.get("data", 1)
     model = axes.get("model", 1)
     B = q.shape[0]
-    H, Hkv = q.shape[-2], k_pages.shape[2]
-    if B % data != 0 or k_pages.shape[0] % data != 0:
+    H, Hkv = q.shape[-2], k_pages.shape[3]
+    if B % data != 0 or k_pages.shape[1] % data != 0:
         raise ValueError(
             f"paged_attention_sharded: batch {B} and pool pages "
-            f"{k_pages.shape[0]} must be divisible by the data axis "
+            f"{k_pages.shape[1]} must be divisible by the data axis "
             f"({data})")
     shard_heads = model > 1 and H % model == 0 and Hkv % model == 0
     mspec = "model" if shard_heads else None
     q_spec = P("data", None, mspec, None) if q.ndim == 4 \
         else P("data", mspec, None)
-    kv_spec = P("data", None, mspec, None)
-    pages_per_shard = k_pages.shape[0] // data
+    kv_spec = P(None, "data", None, mspec, None)
+    pages_per_shard = k_pages.shape[1] // data
 
-    def local(qs, ks, vs, ts, ls):
+    def local(qs, ks, vs, ly, ts, ls):
         shard = jax.lax.axis_index("data")
         local_t = jnp.clip(ts - shard * pages_per_shard, 0,
                            pages_per_shard - 1).astype(jnp.int32)
-        return _paged_attention_local(qs, ks, vs, local_t, ls,
+        return _paged_attention_local(qs, ks, vs, ly, local_t, ls,
                                       interpret=interpret)
 
     return jax.shard_map(
         local, mesh=mesh,
-        in_specs=(q_spec, kv_spec, kv_spec, P("data", None), P("data")),
-        out_specs=q_spec, check_vma=False)(q, k_pages, v_pages, tables,
-                                           lengths)
+        in_specs=(q_spec, kv_spec, kv_spec, P(), P("data", None),
+                  P("data")),
+        out_specs=q_spec, check_vma=False)(
+            q, k_pages, v_pages, jnp.asarray(layer, jnp.int32), tables,
+            lengths)
 
 
-def paged_attention(q, k_pages, v_pages, tables, lengths,
+def paged_attention(q, k_pages, v_pages, layer, tables, lengths,
                     interpret: bool = None):
-    """Gather-decode/verify attention over scattered KV pages.
+    """Gather-decode/verify attention over scattered KV pages of one
+    layer of a stacked pool.
 
     q: (B, H, D), or (B, K, H, D) for a K-token speculative-verify
-    step; k_pages/v_pages: (P, bs, Hkv, D); tables: (B, W); lengths:
+    step; k_pages/v_pages: (layers, P, bs, Hkv, D); layer: int32
+    scalar; tables: (B, W); lengths:
     (B,) valid KV tokens for the FIRST query of each row (query t sees
     ``lengths + t``) -> same rank as q.
 
@@ -126,10 +133,10 @@ def paged_attention(q, k_pages, v_pages, tables, lengths,
     mesh = _sharding.current_mesh()
     if mesh is not None and "data" in mesh.axis_names \
             and dict(zip(mesh.axis_names, mesh.devices.shape))["data"] > 1:
-        return paged_attention_sharded(mesh, q, k_pages, v_pages, tables,
-                                       lengths, interpret=interpret)
-    return _paged_attention_local(q, k_pages, v_pages, tables, lengths,
-                                  interpret=interpret)
+        return paged_attention_sharded(mesh, q, k_pages, v_pages, layer,
+                                       tables, lengths, interpret=interpret)
+    return _paged_attention_local(q, k_pages, v_pages, layer, tables,
+                                  lengths, interpret=interpret)
 
 
 def latent_paged_attention(q, pool, layer, tables, lengths, scale: float,

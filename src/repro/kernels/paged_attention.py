@@ -1,11 +1,13 @@
 """Pallas TPU paged-attention decode/verify kernel (gather over
 scattered KV pages).
 
-The serving pool stores each layer's KV cache as one
-``(num_pages + 1, block_size, n_kv_heads, head_dim)`` array; a request's
-tokens live in whatever pages its block table names, in logical order
-but physically scattered (the last page, index ``num_pages``, is the
-null page that inactive batch rows point at).  This kernel computes
+The serving pool stores a stack's KV cache as one ``(layers, num_pages +
+1, block_size, n_kv_heads, head_dim)`` array for K and one for V; a
+request's tokens live in whatever pages its block table names, in
+logical order but physically scattered (the last page, index
+``num_pages``, is the null page that inactive batch rows point at).  The
+kernel reads one layer of the stacked pool in place, so the layer scan
+that carries the pool never slices a layer's pool out.  It computes
 decode attention for a batch of requests directly against that layout,
 for ``K >= 1`` query tokens per row — K = 1 is the classic decode step,
 K > 1 is the speculative-decoding verify step where the K queries of a
@@ -19,11 +21,11 @@ row are consecutive positions of the same request:
     block's last two dims to be (8, 128)-aligned or whole, so a block of
     one kv head out of the (kv_heads, head_dim) minor dims is refused,
     and so is a dynamic kv-head index into the page;
-  * the block table and per-row sequence lengths ride in as
-    **scalar-prefetch** operands (``pltpu.PrefetchScalarGridSpec``): the
-    K/V BlockSpec index_map reads ``tables[b, p]`` to DMA the right
-    physical page HBM->VMEM — the gather never materializes a
-    contiguous copy of the request's KV;
+  * the block table, per-row sequence lengths and the layer index ride
+    in as **scalar-prefetch** operands (``pltpu.PrefetchScalarGridSpec``):
+    the K/V BlockSpec index_map reads ``(layer, tables[b, p])`` to DMA
+    the right physical page HBM->VMEM — the gather never materializes a
+    contiguous copy of the request's KV, nor of the layer's pool;
   * per-query causality: query t of row b attends over
     ``lengths[b] + t`` tokens (the intra-block staircase a K-token
     verify needs), expressed as a per-accumulator-row position bound;
@@ -50,7 +52,7 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
+def _kernel(tables_ref, lengths_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
             acc_ref, m_ref, l_ref, *, scale: float, block_size: int,
             group: int, k_tokens: int):
     b = pl.program_id(0)
@@ -100,15 +102,17 @@ def _kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                    tables: jax.Array, lengths: jax.Array,
+                    layer, tables: jax.Array, lengths: jax.Array,
                     interpret: bool = False) -> jax.Array:
-    """Decode/verify attention over a paged KV pool.
+    """Decode/verify attention over layer ``layer`` of a stacked paged
+    KV pool.
 
     q: (B, H, D) single-token queries, or (B, K, H, D) for K
     consecutive query tokens per row (speculative verify);
-    k_pages/v_pages: (num_pages [+1], block_size, Hkv, D) physical
-    pools; tables: (B, W) int32 physical page ids (logical page j of
-    row b at ``tables[b,j]``, null-page entries past the used length);
+    k_pages/v_pages: (layers, num_pages [+1], block_size, Hkv, D)
+    stacked physical pools; layer: int32 scalar, the layer read;
+    tables: (B, W) int32 physical page ids (logical page j of row b at
+    ``tables[b,j]``, null-page entries past the used length);
     lengths: (B,) int32 valid KV tokens for the FIRST query of each row
     (query t sees ``lengths[b] + t``).  Returns the same rank as ``q``.
     """
@@ -116,7 +120,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     if squeeze:
         q = q[:, None]
     B, K, H, D = q.shape
-    _, bs, Hkv, _ = k_pages.shape
+    _, _, bs, Hkv, _ = k_pages.shape
     W = tables.shape[1]
     assert H % Hkv == 0
     g = H // Hkv
@@ -128,11 +132,12 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
 
     kernel = functools.partial(_kernel, scale=scale, block_size=bs,
                                group=g, k_tokens=K)
-    page = pl.BlockSpec((1, bs, Hkv, D),
-                        lambda b, p, tbl, ln: (tbl[b, p], 0, 0, 0))
-    rows = pl.BlockSpec((1, Hkv, K * g, D), lambda b, p, tbl, ln: (b, 0, 0, 0))
+    page = pl.BlockSpec((pl.squeezed, 1, bs, Hkv, D),
+                        lambda b, p, tbl, ln, ly: (ly[0], tbl[b, p], 0, 0, 0))
+    rows = pl.BlockSpec((1, Hkv, K * g, D),
+                        lambda b, p, tbl, ln, ly: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, W),
         in_specs=[rows, page, page],
         out_specs=rows,
@@ -148,7 +153,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, K * g, D), q.dtype),
         interpret=interpret,
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      qg, k_pages, v_pages)
+      jnp.asarray(layer, jnp.int32).reshape(1), qg, k_pages, v_pages)
     out = out.reshape(B, Hkv, K, g, D).transpose(0, 2, 1, 3, 4) \
              .reshape(B, K, H, D)
     return out[:, 0] if squeeze else out

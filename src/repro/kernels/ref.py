@@ -27,15 +27,16 @@ def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def paged_attention_ref(q: jax.Array, k_pages: jax.Array,
-                        v_pages: jax.Array, tables: jax.Array,
+                        v_pages: jax.Array, layer, tables: jax.Array,
                         lengths: jax.Array) -> jax.Array:
-    """Gather-decode/verify oracle over a paged KV pool (the jnp twin
-    the models use off-TPU).
+    """Gather-decode/verify oracle over layer ``layer`` of a stacked
+    paged KV pool (the jnp twin the models use off-TPU).
 
     q: (B, H, D) single-token decode, or (B, K, H, D) for a K-token
     verify step (speculative decoding: the K queries of one row are
     consecutive positions of the same request); k_pages/v_pages:
-    (P, bs, Hkv, D); tables: (B, W) int32 physical page ids; lengths:
+    (layers, P, bs, Hkv, D); layer: int32 scalar; tables: (B, W) int32
+    physical page ids; lengths:
     (B,) valid KV tokens for the FIRST query of each row — query t of a
     row sees ``lengths[b] + t`` tokens, the intra-block causal
     staircase.  Returns the same rank as ``q``.  Gathers each row's
@@ -50,11 +51,13 @@ def paged_attention_ref(q: jax.Array, k_pages: jax.Array,
     if squeeze:
         q = q[:, None]
     B, K, H, D = q.shape
-    _, bs, Hkv, _ = k_pages.shape
+    _, _, bs, Hkv, _ = k_pages.shape
     W = tables.shape[1]
     g = H // Hkv
-    kg = k_pages[tables].reshape(B, W * bs, Hkv, D).astype(jnp.float32)
-    vg = v_pages[tables].reshape(B, W * bs, Hkv, D).astype(jnp.float32)
+    kg = k_pages[layer, tables].reshape(B, W * bs, Hkv, D) \
+        .astype(jnp.float32)
+    vg = v_pages[layer, tables].reshape(B, W * bs, Hkv, D) \
+        .astype(jnp.float32)
     qg = q.reshape(B, K, Hkv, g, D).astype(jnp.float32)
     s = jnp.einsum("bthgd,bkhd->bthgk", qg, kg) / math.sqrt(D)
     pos = jnp.arange(W * bs, dtype=jnp.int32)

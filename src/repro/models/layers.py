@@ -473,44 +473,45 @@ def attention_decode(params: Params, cfg: ModelConfig, x: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def _paged_attention_dispatch(q, k_pages, v_pages, tables, lengths):
-    # single dispatch site: ops.paged_attention picks the compiled
-    # Pallas kernel on TPU and the jnp oracle everywhere else
-    from repro.kernels import ops
-    return ops.paged_attention(q, k_pages, v_pages, tables, lengths)
-
-
-def init_paged_attention_cache(cfg: ModelConfig, num_pages: int,
-                               block_size: int, dtype=None):
-    """One layer's paged KV pool: (num_pages + 1, block_size, Hkv, D).
+def init_paged_attention_pool(cfg: ModelConfig, layers: int,
+                              num_pages: int, block_size: int):
+    """``layers`` paged KV pools stacked from the start: K and V each
+    (layers, num_pages + 1, block_size, Hkv, D), allocated at that shape
+    (no per-layer pools to stack).  Returns (pool, the axes of ONE
+    layer's pool); the caller names the stacking axis.
 
     The extra page (index ``num_pages``) is the NULL page: inactive
     batch rows and dropped (padded) prefill positions write there, so a
     row that owns no pages can never corrupt another request's cache.
     """
-    dt = dtype or jnp.dtype(cfg.dtype)
-    hd = cfg.resolved_head_dim
-    shape = (num_pages + 1, block_size, cfg.num_kv_heads, hd)
+    dt = jnp.dtype(cfg.dtype)
+    shape = (layers, num_pages + 1, block_size, cfg.num_kv_heads,
+             cfg.resolved_head_dim)
     cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
     axes = {"k": ("pages", None, "kv_heads", "head_dim"),
             "v": ("pages", None, "kv_heads", "head_dim")}
     return cache, axes
 
 
-def _scatter_pages(pages: jax.Array, vals: jax.Array, page_ids: jax.Array,
-                   offsets: jax.Array) -> jax.Array:
-    """Write vals[n] -> pages[page_ids[n], offsets[n]] (rows of (Hkv, D))."""
-    return pages.at[page_ids, offsets].set(vals.astype(pages.dtype))
+def _scatter_pages(pool: jax.Array, layer, vals: jax.Array,
+                   page_ids: jax.Array, offsets: jax.Array) -> jax.Array:
+    """Write vals[n] -> pool[layer, page_ids[n], offsets[n]] (rows of
+    (Hkv, D)), in place in the stacked pool."""
+    return pool.at[layer, page_ids, offsets].set(vals.astype(pool.dtype))
 
 
 def attention_decode_paged(params: Params, cfg: ModelConfig, x: jax.Array,
-                           cache: Params, index: jax.Array,
-                           positions: jax.Array, tables: jax.Array,
+                           cache: Params, layer: jax.Array,
+                           index: jax.Array, positions: jax.Array,
+                           tables: jax.Array,
                            valid: Optional[jax.Array] = None):
-    """K-token decode/verify against the paged pool.
+    """K-token decode/verify against layer ``layer`` of the stacked
+    paged pool.
 
     x: (B, K, d) — K >= 1 consecutive tokens per row; cache['k'/'v']:
-    (P+1, bs, Hkv, D) shared pools; index: int32 (B,) per-row write
+    (layers, P+1, bs, Hkv, D) stacked shared pools, written in place
+    and read by the kernel at ``layer`` (carried through the layer
+    scan, never sliced out); index: int32 (B,) per-row write
     position of the FIRST token, with -1 marking inactive rows (their
     KV is routed to the null page and their output is garbage the
     caller discards); tables: (B, W) int32 physical page ids; valid:
@@ -520,10 +521,12 @@ def attention_decode_paged(params: Params, cfg: ModelConfig, x: jax.Array,
     Token t writes at ``index[b] + t`` and attends causally over
     positions ``<= index[b] + t``.  Returns (out, new_cache).
     """
+    from repro.kernels import ops
+
     B, K, _ = x.shape
     q, k_new, v_new = _project_qkv(params, cfg, x, positions)
-    bs = cache["k"].shape[1]
-    null_page = cache["k"].shape[0] - 1
+    bs = cache["k"].shape[2]
+    null_page = cache["k"].shape[1] - 1
     W = tables.shape[1]
     index = jnp.asarray(index, jnp.int32)
     active = (index >= 0)[:, None]                        # (B, 1)
@@ -536,26 +539,31 @@ def attention_decode_paged(params: Params, cfg: ModelConfig, x: jax.Array,
                                axis=1)
     page = jnp.where(active, page, null_page)
     off = wpos % bs
-    k = _scatter_pages(cache["k"], k_new.reshape(B * K, *k_new.shape[2:]),
+    k = _scatter_pages(cache["k"], layer,
+                       k_new.reshape(B * K, *k_new.shape[2:]),
                        page.reshape(-1), off.reshape(-1))
-    v = _scatter_pages(cache["v"], v_new.reshape(B * K, *v_new.shape[2:]),
+    v = _scatter_pages(cache["v"], layer,
+                       v_new.reshape(B * K, *v_new.shape[2:]),
                        page.reshape(-1), off.reshape(-1))
     lengths = widx + 1                       # KV tokens seen by query 0
-    out = _paged_attention_dispatch(q, k, v, tables, lengths)
+    # the compiled Pallas kernel on TPU, the jnp oracle elsewhere
+    out = ops.paged_attention(q, k, v, layer, tables, lengths)
     out = out.reshape(B, K, cfg.q_dim) @ params["wo"]
     return constrain(out, "batch", "seq", "act_embed"), {"k": k, "v": v}
 
 
 def attention_chunk_paged(params: Params, cfg: ModelConfig, x: jax.Array,
-                          cache: Params, tables: jax.Array,
-                          hist_len: jax.Array, prompt_len: jax.Array,
-                          positions: jax.Array):
-    """One chunked-prefill step for a single request over the paged pool.
+                          cache: Params, layer: jax.Array,
+                          tables: jax.Array, hist_len: jax.Array,
+                          prompt_len: jax.Array, positions: jax.Array):
+    """One chunked-prefill step for a single request over layer
+    ``layer`` of the stacked paged pool.
 
     x: (1, C, d) — the prompt slice [hist_len, hist_len + C) (the tail
     chunk may be right-padded past ``prompt_len``; padded positions
     scatter to the null page and are causally invisible to real
-    queries); cache: shared (P+1, bs, Hkv, D) pools; tables: (1, W)
+    queries); cache: stacked shared (layers, P+1, bs, Hkv, D) pools,
+    written and gathered in place; tables: (1, W)
     this request's block-table row; hist_len/prompt_len: int32 scalars.
     Chunk KV is scattered into the pool first, then the chunk queries
     attend over the gathered pages — which covers both the already-
@@ -565,8 +573,8 @@ def attention_chunk_paged(params: Params, cfg: ModelConfig, x: jax.Array,
     B, C, _ = x.shape
     assert B == 1
     q, k_new, v_new = _project_qkv(params, cfg, x, positions)
-    bs = cache["k"].shape[1]
-    null_page = cache["k"].shape[0] - 1
+    bs = cache["k"].shape[2]
+    null_page = cache["k"].shape[1] - 1
     W = tables.shape[1]
     abs_pos = jnp.asarray(hist_len, jnp.int32) \
         + jnp.arange(C, dtype=jnp.int32)
@@ -574,13 +582,13 @@ def attention_chunk_paged(params: Params, cfg: ModelConfig, x: jax.Array,
     page = jnp.take(tables[0], jnp.minimum(abs_pos // bs, W - 1))
     page = jnp.where(valid, page, null_page)
     off = abs_pos % bs
-    k = _scatter_pages(cache["k"], k_new[0], page, off)
-    v = _scatter_pages(cache["v"], v_new[0], page, off)
+    k = _scatter_pages(cache["k"], layer, k_new[0], page, off)
+    v = _scatter_pages(cache["v"], layer, v_new[0], page, off)
 
     Hkv, D = cfg.num_kv_heads, cfg.resolved_head_dim
     g = cfg.num_heads // Hkv
-    kg = k[tables[0]].reshape(W * bs, Hkv, D).astype(jnp.float32)
-    vg = v[tables[0]].reshape(W * bs, Hkv, D).astype(jnp.float32)
+    kg = k[layer, tables[0]].reshape(W * bs, Hkv, D).astype(jnp.float32)
+    vg = v[layer, tables[0]].reshape(W * bs, Hkv, D).astype(jnp.float32)
     qg = q[0].reshape(C, Hkv, g, D).astype(jnp.float32)
     s = jnp.einsum("qhgd,khd->hgqk", qg, kg) / math.sqrt(D)
     kv_pos = jnp.arange(W * bs, dtype=jnp.int32)

@@ -193,9 +193,10 @@ def _apply_block(bp: Params, cfg: ModelConfig, spec: LayerSpec, x, positions,
     marks how many of the K are real per row — attention routes the
     rest to the null page (paged) and recurrent mixers freeze their
     state past it (the speculative verify / rollback-replay contract).
-    Latent attention ('l') gets the WHOLE stacked latent pool as
-    ``cache`` and its own index in the stack as ``layer``, and returns
-    the whole pool.  ``token_mask`` ((B, S), true on real tokens) makes
+    On the paged pool (``tables`` given) attention ('a') and latent
+    attention ('l') get the WHOLE stacked pool as ``cache`` and their
+    own index in the stack as ``layer``, and return the whole pool.
+    ``token_mask`` ((B, S), true on real tokens) makes
     an MoE layer count its routing into ``aux``.
     """
     if mode == "chunk" and spec.kind not in ATTENTION_KINDS:
@@ -214,11 +215,11 @@ def _apply_block(bp: Params, cfg: ModelConfig, spec: LayerSpec, x, positions,
                                                  positions)
         elif mode == "chunk":
             mix, new_cache = L.attention_chunk_paged(
-                bp["mixer"], cfg, h, cache, tables, hist_len, prompt_len,
-                positions)
+                bp["mixer"], cfg, h, cache, layer, tables, hist_len,
+                prompt_len, positions)
         elif tables is not None:
             mix, new_cache = L.attention_decode_paged(
-                bp["mixer"], cfg, h, cache, index, positions, tables,
+                bp["mixer"], cfg, h, cache, layer, index, positions, tables,
                 valid=valid)
         else:
             # dense rows: beyond-``valid`` writes land at future
@@ -406,10 +407,8 @@ def lm_loss(params: Params, cfg: ModelConfig, batch: Dict[str, jax.Array],
 
 
 def _init_block_cache(cfg: ModelConfig, spec: LayerSpec, batch: int,
-                      max_len: int, pages=None):
+                      max_len: int):
     if spec.kind == "a":
-        if pages is not None:
-            return L.init_paged_attention_cache(cfg, pages[0], pages[1])
         return L.init_attention_cache(cfg, batch, max_len)
     if spec.kind == "M":
         return S.init_mamba_state(cfg, batch)
@@ -427,11 +426,12 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int = 0,
     With ``pages=None`` attention layers get dense ``(batch, max_len,
     Hkv, D)`` slot rows.  With ``pages=(num_pages, block_size)`` they
     instead get ONE shared ``(num_pages + 1, block_size, Hkv, D)`` pool
-    each (page ``num_pages`` is the null page; see
-    :func:`repro.models.layers.init_paged_attention_cache`) addressed
-    through per-request block tables, so a request's KV can be
-    scattered anywhere in the pool.  Recurrent layers (mamba / xLSTM)
-    carry O(1) state per request and keep ``batch`` dense rows in both
+    each (page ``num_pages`` is the null page), addressed through
+    per-request block tables, so a request's KV can be scattered
+    anywhere in the pool; a stack's pools are allocated already stacked
+    (:func:`repro.models.layers.init_paged_attention_pool`), as the
+    layer scan carries them.  Recurrent layers (mamba / xLSTM) carry
+    O(1) state per request and keep ``batch`` dense rows in both
     layouts.  Latent-attention layers (paged only) get one latent pool
     per stack, allocated already stacked
     (:func:`repro.models.layers.init_latent_pool`), and an MoE model
@@ -449,34 +449,26 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int = 0,
     cache: Params = {}
     axes: Params = {}
 
-    def make(spec):
-        return _init_block_cache(cfg, spec, batch, max_len, pages)
+    def stack(spec, n, axis):
+        # ``n`` layers of one (homogeneous) stack, stacked on ``axis``
+        if spec.kind == "l":
+            if pages is None:
+                raise ValueError("latent attention keeps its cache in a "
+                                 "paged pool only (pass pages=)")
+            return L.init_latent_pool(cfg, n, pages[0], pages[1])
+        if spec.kind == "a" and pages is not None:
+            c, a = L.init_paged_attention_pool(cfg, n, pages[0], pages[1])
+            return c, _push_axis(a, axis)
+        per = [_init_block_cache(cfg, spec, batch, max_len)
+               for _ in range(n)]
+        return _stack([c for c, _ in per]), _push_axis(per[0][1], axis)
 
-    def latent(n):
-        if pages is None:
-            raise ValueError("latent attention keeps its cache in a paged "
-                             "pool only (pass pages=)")
-        return L.init_latent_pool(cfg, n, pages[0], pages[1])
-
-    if k0 and specs[0].kind == "l":
-        cache["prefix"], axes["prefix"] = latent(k0)
-    elif k0:
-        per = [make(specs[i]) for i in range(k0)]
-        cache["prefix"] = _stack([c for c, _ in per])
-        axes["prefix"] = _push_axis(per[0][1], "layers")
-    body_c, body_a = [], []
-    for j in range(R):
-        if specs[k0 + j].kind == "l":
-            c, a = latent(P)
-            body_c.append(c)
-            body_a.append(a)
-            continue
-        per = [make(specs[k0 + pi * R + j]) for pi in range(P)]
-        body_c.append(_stack([c for c, _ in per]))
-        body_a.append(_push_axis(per[0][1], "period"))
-    cache["body"] = tuple(body_c)
-    axes["body"] = tuple(body_a)
-    if _carries_pools(cfg) and cfg.moe is not None:
+    if k0:
+        cache["prefix"], axes["prefix"] = stack(specs[0], k0, "layers")
+    body = [stack(specs[k0 + j], P, "period") for j in range(R)]
+    cache["body"] = tuple(c for c, _ in body)
+    axes["body"] = tuple(a for _, a in body)
+    if cfg.moe is not None and any(s.kind == "l" for s in specs):
         E = cfg.moe.num_experts
         cache["moe_stats"] = {
             "expert_tokens": jnp.zeros((cfg.num_layers, E), jnp.int32),
@@ -486,21 +478,17 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int = 0,
     return cache, axes
 
 
-def _carries_pools(cfg: ModelConfig) -> bool:
-    """Latent stacks carry their stacked pools through the layer scan
-    (each layer writes and reads its own slice in place) instead of
-    scanning over them, which would copy every layer's pool out and
-    back each call."""
-    return any(s.kind == "l" for s in layer_specs(cfg))
-
-
 def _run_carried(cfg: ModelConfig, x, params: Params, cache: Params,
                  apply):
-    """Drive a latent stack: ``apply(spec, layer_params, pool, layer, x)
-    -> (x, pool, aux)`` per layer, the prefix and body pools in the scan
-    carry and the layer's index in the stack as the scanned input.
-    Adds each layer's routing counts into ``cache["moe_stats"]`` when it
-    is there.  Returns (x, new_cache)."""
+    """Drive a stack on the paged pool: ``apply(spec, layer_params,
+    cache, layer, x) -> (x, cache, aux)`` per layer, the prefix and body
+    caches in the scan carry and the layer's index in the stack as the
+    scanned input.  Page pools (K/V or latent) stay whole: each layer
+    writes and reads its own slice in place, where scanning over them
+    would copy every layer's pool out and back each call.  A recurrent
+    layer's state rows (small) are sliced out for ``apply`` and written
+    back.  Adds each layer's routing counts into ``cache["moe_stats"]``
+    when it is there.  Returns (x, new_cache)."""
     specs = layer_specs(cfg)
     k0, R, _ = _grouping(cfg)
     counting = "moe_stats" in cache
@@ -517,7 +505,16 @@ def _run_carried(cfg: ModelConfig, x, params: Params, cache: Params,
             cs = (cs,) if single else cs
             new, tok, drop = [], [], []
             for sp, lp, lc in zip(spec_list, lps, cs):
-                xc, nc, aux = apply(sp, lp, lc, li, xc)
+                if sp.kind in ATTENTION_KINDS:
+                    xc, nc, aux = apply(sp, lp, lc, li, xc)
+                else:
+                    st = jax.tree.map(
+                        lambda s: jax.lax.dynamic_index_in_dim(
+                            s, li, keepdims=False), lc)
+                    xc, ns, aux = apply(sp, lp, st, li, xc)
+                    nc = jax.tree.map(
+                        lambda s, n: jax.lax.dynamic_update_index_in_dim(
+                            s, n, li, 0), lc, ns)
                 new.append(nc)
                 tok.append(aux.get("expert_tokens",
                                    jnp.zeros((E,), jnp.int32)))
@@ -576,45 +573,15 @@ def _prefill_chunk(params: Params, cfg: ModelConfig, tokens: jax.Array,
         positions = jnp.broadcast_to(pos[None], (3, B, C))
     else:
         positions = pos
-    pspecs = _period_specs(cfg)
-    specs = layer_specs(cfg)
+    real = pos < prompt_len
 
-    def run_stack(x, stacked, cache_stacked, spec_list):
-        def body(xc, inp):
-            layer_p, layer_c = inp
-            if not isinstance(layer_p, tuple):
-                layer_p = (layer_p,)
-                layer_c = (layer_c,)
-            new_caches = []
-            for sp, lp, lc in zip(spec_list, layer_p, layer_c):
-                xc, nc, _ = _apply_block(
-                    lp, cfg, sp, xc, positions, "chunk", cache=lc,
-                    tables=tables, hist_len=hist_len,
-                    prompt_len=prompt_len)
-                new_caches.append(nc)
-            return xc, tuple(new_caches)
+    def apply(sp, lp, lc, li, xc):
+        return _apply_block(lp, cfg, sp, xc, positions, "chunk",
+                            cache=lc, tables=tables, hist_len=hist_len,
+                            prompt_len=prompt_len, layer=li,
+                            token_mask=real)
 
-        return jax.lax.scan(body, x, (stacked, cache_stacked))
-
-    if _carries_pools(cfg):
-        real = pos < prompt_len
-
-        def apply(sp, lp, lc, li, xc):
-            return _apply_block(lp, cfg, sp, xc, positions, "chunk",
-                                cache=lc, tables=tables, hist_len=hist_len,
-                                prompt_len=prompt_len, layer=li,
-                                token_mask=real)
-
-        x, new_cache = _run_carried(cfg, x, params, cache, apply)
-    else:
-        new_cache = {}
-        if "prefix" in params:
-            x, pc = run_stack(x, params["prefix"], cache["prefix"],
-                              (specs[0],))
-            new_cache["prefix"] = pc[0]
-        x, bc = run_stack(x, tuple(params["body"]), tuple(cache["body"]),
-                          pspecs)
-        new_cache["body"] = bc
+    x, new_cache = _run_carried(cfg, x, params, cache, apply)
     idx = jnp.broadcast_to(
         jnp.asarray(last_pos, jnp.int32)[:, None, None],
         (x.shape[0], 1, x.shape[2]))
@@ -719,26 +686,8 @@ def lm_decode(params: Params, cfg: ModelConfig, tokens: jax.Array,
             positions = jnp.broadcast_to(pos[None], (3, B, K))
         else:
             positions = pos
-    pspecs = _period_specs(cfg)
-    specs = layer_specs(cfg)
 
-    def run_stack(x, stacked, cache_stacked, spec_list):
-        def body(xc, inp):
-            layer_p, layer_c = inp
-            if not isinstance(layer_p, tuple):
-                layer_p = (layer_p,)
-                layer_c = (layer_c,)
-            new_caches = []
-            for sp, lp, lc in zip(spec_list, layer_p, layer_c):
-                xc, nc, _ = _apply_block(lp, cfg, sp, xc, positions,
-                                         "decode", cache=lc, index=index,
-                                         tables=tables, valid=valid)
-                new_caches.append(nc)
-            return xc, tuple(new_caches)
-
-        return jax.lax.scan(body, x, (stacked, cache_stacked))
-
-    if _carries_pools(cfg):
+    if tables is not None:      # the paged pool rides in the scan carry
         real = jnp.broadcast_to(index >= 0, (B,))[:, None] \
             & jnp.ones((1, K), bool)
         if valid is not None:
@@ -753,11 +702,30 @@ def lm_decode(params: Params, cfg: ModelConfig, tokens: jax.Array,
         x, new_cache = _run_carried(cfg, x, params, cache, apply)
         return _logits(params, cfg, x), new_cache
 
+    # dense slot rows: scan over the per-layer caches
+    def run_stack(x, stacked, cache_stacked, spec_list):
+        def body(xc, inp):
+            layer_p, layer_c = inp
+            if not isinstance(layer_p, tuple):
+                layer_p = (layer_p,)
+                layer_c = (layer_c,)
+            new_caches = []
+            for sp, lp, lc in zip(spec_list, layer_p, layer_c):
+                xc, nc, _ = _apply_block(lp, cfg, sp, xc, positions,
+                                         "decode", cache=lc, index=index,
+                                         valid=valid)
+                new_caches.append(nc)
+            return xc, tuple(new_caches)
+
+        return jax.lax.scan(body, x, (stacked, cache_stacked))
+
     new_cache: Params = {}
     if "prefix" in params:
-        x, pc = run_stack(x, params["prefix"], cache["prefix"], (specs[0],))
+        x, pc = run_stack(x, params["prefix"], cache["prefix"],
+                          (layer_specs(cfg)[0],))
         new_cache["prefix"] = pc[0]
-    x, bc = run_stack(x, tuple(params["body"]), tuple(cache["body"]), pspecs)
+    x, bc = run_stack(x, tuple(params["body"]), tuple(cache["body"]),
+                      _period_specs(cfg))
     new_cache["body"] = bc
     logits = _logits(params, cfg, x)
     return logits, new_cache

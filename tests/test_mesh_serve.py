@@ -262,8 +262,9 @@ print("OK")
 def test_sharded_paged_gather_matches_oracle():
     """ops.paged_attention under a (data, model) sharding context ==
     ref.paged_attention_ref on the unsharded global pool, for K = 1 and
-    a K = 3 verify staircase, GQA heads, inside scan-under-jit — and
-    no page moves across `data` (each row's tables stay in its shard)."""
+    a K = 3 verify staircase, GQA heads, inside scan-under-jit with the
+    scanned layer index picking a layer of the stacked pool — and no
+    page moves across `data` (each row's tables stay in its shard)."""
     _run_mesh_script(r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -276,8 +277,9 @@ pps = 3
 P_tot = D_ * (pps + 1)
 B, W = 8, 3
 rng = np.random.default_rng(0)
-k_pages = rng.normal(size=(P_tot, bs, Hkv, hd)).astype(np.float32)
-v_pages = rng.normal(size=(P_tot, bs, Hkv, hd)).astype(np.float32)
+layers, layer = 2, 1                  # a stacked pool, its second layer
+k_pages = rng.normal(size=(layers, P_tot, bs, Hkv, hd)).astype(np.float32)
+v_pages = rng.normal(size=(layers, P_tot, bs, Hkv, hd)).astype(np.float32)
 tables = np.zeros((B, W), np.int32)
 lengths = np.zeros((B,), np.int32)
 for b in range(B):
@@ -287,9 +289,9 @@ for b in range(B):
                  base + pps]                      # 2 real pages + null
     lengths[b] = 1 + b % (2 * bs - 3)
 kp = jax.device_put(jnp.asarray(k_pages),
-                    NamedSharding(mesh, P("data", None, "model", None)))
+                    NamedSharding(mesh, P(None, "data", None, "model", None)))
 vp = jax.device_put(jnp.asarray(v_pages),
-                    NamedSharding(mesh, P("data", None, "model", None)))
+                    NamedSharding(mesh, P(None, "data", None, "model", None)))
 for K in (1, 3):
     q = rng.normal(size=(B, K, H, hd)).astype(np.float32)
     if K == 1:
@@ -297,15 +299,15 @@ for K in (1, 3):
     else:
         qq = q
     want = ref.paged_attention_ref(
-        jnp.asarray(qq), jnp.asarray(k_pages), jnp.asarray(v_pages),
+        jnp.asarray(qq), jnp.asarray(k_pages), jnp.asarray(v_pages), layer,
         jnp.asarray(tables), jnp.asarray(lengths))
 
     def f(q, kp, vp, t, l):
-        def body(c, _):
-            o = ops.paged_attention(q, kp, vp, t, l)
+        def body(c, li):
+            o = ops.paged_attention(q, kp, vp, li, t, l)
             return c, o
         _, os_ = jax.lax.scan(body, 0.0, jnp.arange(2))
-        return os_[0]
+        return os_[layer]
 
     with use_sharding(mesh):
         got = jax.jit(f)(jnp.asarray(qq), kp, vp, jnp.asarray(tables),

@@ -43,20 +43,24 @@ def _prompts(cfg, n, max_len, seed=1):
 ])
 @pytest.mark.parametrize("K", [1, 3])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_attention_kernel_matches_ref(B, H, Hkv, D, bs, W, K, dtype):
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_attention_kernel_matches_ref(B, H, Hkv, D, bs, W, K, dtype,
+                                            layer):
     """Pallas gather-decode/verify kernel (interpret) == jnp oracle
-    over scattered pages, null-page rows included; K > 1 exercises the
-    speculative-verify staircase (query t reaches lengths + t)."""
+    over scattered pages of one layer of a stacked pool, null-page rows
+    included; K > 1 exercises the speculative-verify staircase (query t
+    reaches lengths + t).  Every layer of the pool holds other values,
+    so a wrong or ignored layer index reads the wrong pages."""
     from repro.kernels.ops import paged_attention
     from repro.kernels.ref import paged_attention_ref
 
-    P = 9                      # pool pages (+1 null)
+    P, layers = 9, 3           # pool pages (+1 null), stacked layers
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (B, K, H, D), dtype)
     if K == 1:                 # exercise the 3D single-token surface
         q = q[:, 0]
-    kp = jax.random.normal(ks[1], (P + 1, bs, Hkv, D), dtype)
-    vp = jax.random.normal(ks[2], (P + 1, bs, Hkv, D), dtype)
+    kp = jax.random.normal(ks[1], (layers, P + 1, bs, Hkv, D), dtype)
+    vp = jax.random.normal(ks[2], (layers, P + 1, bs, Hkv, D), dtype)
     rng = np.random.default_rng(0)
     # scattered, non-contiguous tables; trailing entries null
     tables = rng.permutation(P)[:B * W].reshape(B, W).astype(np.int32)
@@ -64,15 +68,71 @@ def test_paged_attention_kernel_matches_ref(B, H, Hkv, D, bs, W, K, dtype):
     for b in range(B):
         used = blocks_for(int(lengths[b]) + K - 1, bs)
         tables[b, used:] = P    # null page
-    out = paged_attention(q, kp, vp, jnp.asarray(tables),
-                          jnp.asarray(lengths), interpret=True)
-    ref = paged_attention_ref(q, kp, vp, jnp.asarray(tables),
-                              jnp.asarray(lengths))
+    args = (jnp.asarray(tables), jnp.asarray(lengths))
+    out = paged_attention(q, kp, vp, jnp.int32(layer), *args,
+                          interpret=True)
+    ref = paged_attention_ref(q, kp, vp, jnp.int32(layer), *args)
     assert out.shape == q.shape
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                atol=tol, rtol=tol)
+    # the oracle on the layer alone (a one-layer stack) agrees, and
+    # another layer's pages give another answer
+    alone = paged_attention_ref(q, kp[layer:layer + 1], vp[layer:layer + 1],
+                                0, *args)
+    np.testing.assert_array_equal(np.asarray(alone, np.float32),
+                                  np.asarray(ref, np.float32))
+    other = paged_attention(q, kp, vp, jnp.int32(1), *args, interpret=True)
+    assert not np.allclose(np.asarray(other, np.float32),
+                           np.asarray(out, np.float32), atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("mode", ["decode", "chunk"])
+def test_paged_write_touches_only_its_layer(mode):
+    """A paged decode step and a prefill chunk write their rows into
+    layer ``l`` of the stacked pool in place: every other layer's pages
+    stay bitwise equal, and so does every page of layer ``l`` they do
+    not write."""
+    from repro.models import layers as Lyr
+
+    cfg = _f32_cfg("qwen3-0.6b")
+    params, _ = init_lm(cfg, KEY)
+    mixer = jax.tree.map(lambda a: a[0], params["body"][0]["mixer"])
+    P, bs, n_layers, layer = 8, 4, 3, 1
+    pool, _ = Lyr.init_paged_attention_pool(cfg, n_layers, P, bs)
+    ks = jax.random.split(jax.random.PRNGKey(3), 2)
+    pool = {n: jax.random.normal(k, pool[n].shape, pool[n].dtype)
+            for n, k in zip(("k", "v"), ks)}
+    if mode == "decode":
+        # rows at positions 5 and 2 (pages 3 and 6), row 2 idle
+        tables = jnp.asarray([[0, 3, 8], [6, 8, 8], [8, 8, 8]], jnp.int32)
+        index = jnp.asarray([5, 2, -1], jnp.int32)
+        x = jax.random.normal(KEY, (3, 1, cfg.d_model), jnp.float32)
+        _, new = Lyr.attention_decode_paged(
+            mixer, cfg, x, pool, jnp.int32(layer), index,
+            jnp.maximum(index, 0)[:, None], tables)
+        written = {(3, 1), (6, 2), (P, 0)}       # the idle row: null page
+    else:
+        # prompt positions 4..9 of a 7-token prompt: 7..9 are padding
+        tables = jnp.asarray([[2, 5, 7]], jnp.int32)
+        x = jax.random.normal(KEY, (1, 6, cfg.d_model), jnp.float32)
+        pos = 4 + jnp.arange(6, dtype=jnp.int32)[None]
+        _, new = Lyr.attention_chunk_paged(
+            mixer, cfg, x, pool, jnp.int32(layer), tables, jnp.int32(4),
+            jnp.int32(7), pos)
+        written = {(5, 0), (5, 1), (5, 2), (P, 3), (P, 0), (P, 1)}
+    for n in ("k", "v"):
+        old, got = np.asarray(pool[n]), np.asarray(new[n])
+        assert got.shape == old.shape
+        for li in range(n_layers):
+            if li != layer:
+                np.testing.assert_array_equal(got[li], old[li])
+        same = np.ones((P + 1, bs), bool)
+        for page, off in written:
+            same[page, off] = False
+        np.testing.assert_array_equal(got[layer][same], old[layer][same])
+        assert not np.array_equal(got[layer], old[layer])
 
 
 # ---------------------------------------------------------------------------

@@ -7,6 +7,8 @@ programs that do not fit the chip's 16 GB, which interpret-mode tests
 cannot show.  The topology is described inside a module-scoped fixture,
 so only the worker that runs these tests loads the TPU library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,13 +23,15 @@ from repro.configs.qwen3_06b import FULL as QWEN3
 from repro.kernels import latent_attention as la
 from repro.kernels import ops
 from repro.kernels import paged_attention as pa
-from repro.serve import sampling
+from repro.models import lm
+from repro.serve import sampling, session
 from repro.train.steps import make_gan_steps
 
 V5E_HBM_BYTES = 16 * 1024 ** 3
 # qwen3-0.6b decode at serving shapes: 8 rows, 16-token pages, a
-# 512-page pool (+ the null page) and 32 table columns (512 tokens)
-B, BS, PAGES, W = 8, 16, 512, 32
+# 512-page pool (+ the null page) and 32 table columns (512 tokens), two
+# layers stacked
+B, BS, PAGES, W, LAYERS = 8, 16, 512, 32, 2
 
 
 @pytest.fixture(scope="module")
@@ -65,10 +69,11 @@ def _spec(shape, dtype, sharding):
 def test_paged_kernel_compiles_at_qwen3_shapes(one_chip, k_tokens):
     H, Hkv, D = QWEN3.num_heads, QWEN3.num_kv_heads, QWEN3.head_dim
     q = (B, H, D) if k_tokens == 1 else (B, k_tokens, H, D)
-    pool = (PAGES + 1, BS, Hkv, D)
+    pool = (LAYERS, PAGES + 1, BS, Hkv, D)
     args = [_spec(q, jnp.bfloat16, one_chip),
             _spec(pool, jnp.bfloat16, one_chip),
             _spec(pool, jnp.bfloat16, one_chip),
+            _spec((), jnp.int32, one_chip),
             _spec((B, W), jnp.int32, one_chip),
             _spec((B,), jnp.int32, one_chip)]
     compiled = jax.jit(pa.paged_attention).lower(*args).compile()
@@ -125,20 +130,101 @@ def test_paged_attention_sharded_compiles_on_2x2_mesh(topo):
     def on(*spec):
         return NamedSharding(mesh, P(*spec))
 
+    pool = (LAYERS, pages, BS, Hkv, D)
     args = [_spec((B, H, D), jnp.bfloat16, on("data", "model", None)),
-            _spec((pages, BS, Hkv, D), jnp.bfloat16,
-                  on("data", None, "model", None)),
-            _spec((pages, BS, Hkv, D), jnp.bfloat16,
-                  on("data", None, "model", None)),
+            _spec(pool, jnp.bfloat16, on(None, "data", None, "model", None)),
+            _spec(pool, jnp.bfloat16, on(None, "data", None, "model", None)),
+            _spec((), jnp.int32, on()),
             _spec((B, W), jnp.int32, on("data", None)),
             _spec((B,), jnp.int32, on("data"))]
 
-    def gather(q, k, v, tables, lengths):
-        return ops.paged_attention_sharded(mesh, q, k, v, tables, lengths,
-                                           interpret=False)
+    def gather(q, k, v, layer, tables, lengths):
+        return ops.paged_attention_sharded(mesh, q, k, v, layer, tables,
+                                           lengths, interpret=False)
 
     compiled = jax.jit(gather).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# an HLO instruction: ``%name = type[dims]{layout} opcode(``
+_HLO_OP = re.compile(r"^\s*(?:ROOT\s+)?%(\S+)\s*=\s*\w+\[([\d,]*)\]\S*\s+"
+                     r"([\w-]+)\(")
+
+
+def _pool_copies(hlo: str, shapes):
+    """Instructions of ``hlo`` that copy, slice or update-slice an
+    array of one of ``shapes`` (dims of size 1 ignored), as themselves
+    or as a fusion XLA named after them."""
+    want = {tuple(d for d in s if d != 1) for s in shapes}
+    found = []
+    for line in hlo.splitlines():
+        m = _HLO_OP.match(line)
+        if not m:
+            continue
+        name, dims, opcode = m.groups()
+        dims = tuple(int(d) for d in dims.split(",") if d and d != "1")
+        moves = any(w in f"{name} {opcode}" for w in
+                    ("copy", "dynamic-slice", "dynamic-update-slice"))
+        if moves and dims in want:
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_qwen3_paged_programs_leave_the_pool_in_place(one_chip, monkeypatch,
+                                                      program):
+    """qwen3 FULL's decode step (64 rows, 2,560 pages, 192 table
+    columns) and prefill chunk (512 tokens, 64 columns), with the Pallas
+    kernel selected: the stacked K/V pool rides in the layer scan's
+    carry, so no copy, slice or update-slice of the whole pool or of one
+    layer's pool is compiled, and the temporaries stay far under a
+    second pool."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    rows, pages = 64, 2560
+    params = jax.eval_shape(lambda: lm.init_lm(QWEN3,
+                                               jax.random.PRNGKey(0))[0])
+    cache = jax.eval_shape(
+        lambda: lm.init_cache(QWEN3, rows, pages=(pages, BS))[0])
+    params, cache = jax.tree.map(
+        lambda s: _spec(s.shape, s.dtype, one_chip), (params, cache))
+
+    def i32(*shape):
+        return _spec(shape, jnp.int32, one_chip)
+
+    if program == "decode":
+        lowered = session._step_tables_fn.lower(
+            params, QWEN3, i32(rows, 1), cache, i32(rows), i32(rows, 192),
+            None)
+    else:
+        lowered = session._chunk_fn.lower(
+            params, QWEN3, i32(1, 512), cache, i32(1, 64), i32(), i32(),
+            i32(1))
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    k = cache["body"][0]["k"]
+    assert k.shape == (QWEN3.num_layers, pages + 1, BS, QWEN3.num_kv_heads,
+                       QWEN3.head_dim)
+    assert _pool_copies(hlo, [k.shape, k.shape[1:]]) == []
+    if program == "decode":
+        assert "tpu_custom_call" in hlo
+    pool_bytes = 2 * k.size * k.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 0.05 * pool_bytes
+
+
+def test_pool_copies_finds_a_layer_slice():
+    """The finder above sees the ops a scanned pool compiles to."""
+    hlo = "\n".join([
+        "  %dynamic-slice_bitcast_fusion.4 = bf16[2561,16,8,128]{3,2,1,0:"
+        "T(8,128)(2,1)S(1)} fusion(%a, %b), kind=kLoop",
+        "  %copy.81 = bf16[28,2561,16,8,128]{4,3,2,1,0} copy(%c)",
+        "  %dynamic-slice.3 = bf16[1,2561,16,8,128]{4,3,2,1,0} "
+        "dynamic-slice(%d, %e)",
+        "  ROOT %scatter.19 = bf16[28,2561,16,8,128]{4,3,2,1,0} "
+        "scatter(%p, %i, %u)",
+        "  %copy.2 = bf16[64,8,128]{2,1,0} copy(%f)"])
+    pool = (28, 2561, 16, 8, 128)
+    assert len(_pool_copies(hlo, [pool, pool[1:]])) == 3
 
 
 def test_icf_cyclegan_train_step_fits_one_chip(one_chip):
