@@ -1,24 +1,15 @@
-"""Fig. 14 (beyond-paper) — serving schedule + KV-layout benchmark.
+"""Fig. 14 (beyond-paper) — serving scheduler benchmark (CPU).
 
-The ROADMAP north star is a production system answering surrogate /
-LM queries at scale; this benchmark measures the decode hot path that
-gets there.  One mixed-length request trace — short chats and long
-documents behind a common system-prompt prefix, plus one request whose
-total length exceeds the dense layout's per-slot ceiling — is served
-through three configurations at EQUAL KV-cache memory:
+One mixed-length request trace — short chats and long documents behind
+a common system-prompt prefix, plus one request longer than the pool's
+sizing unit ``max_len`` — is served through these configurations, all
+over the same page pool:
 
-  * ``static``   — dense slot rows, classic batch inference: fill the
-    pool, run until every request in the batch finishes.
-  * ``dense``    — the PR-2 continuous-batching baseline: token-budget
-    admission + per-request completion over dense ``num_slots x
-    max_len`` rows.  Admission is gated by the per-slot ``max_len``
-    ceiling: the long request is REJECTED and a short request wastes a
-    full row.
   * ``paged``    — the paged KV pool (scattered pages + gather-decode
-    kernel) with chunked prefill and copy-on-admit prefix sharing.
-    The same memory holds 2x the decode slots because pages are shared;
-    the long request is admitted; the shared system prompt prefills
-    once and is then mapped, not recomputed.
+    kernel) with continuous batching, chunked prefill and
+    copy-on-admit prefix sharing: the long request is admitted (its
+    pages may lie anywhere in the pool); the shared system prompt
+    prefills once and is then mapped, not recomputed.
   * ``paged_notel`` — the paged configuration with ``telemetry=False``:
     the control arm that bounds the cost of per-request tracing (token
     identity asserted; overhead must stay <= 5% tokens/s).
@@ -69,22 +60,20 @@ from repro.models.lm import init_lm
 from repro.serve import telemetry as telemetry_mod
 from repro.serve.scheduler import Request, Scheduler
 
-# mixed-length trace: short chats + long documents, interleaved so a
-# static batch always contains at least one straggler; every prompt
-# starts with the same SYS_LEN-token system prefix (the prefix-sharing
-# capacity win the paged layout banks)
+# mixed-length trace: short chats + long documents, interleaved; every
+# prompt starts with the same SYS_LEN-token system prefix (the
+# prefix-sharing capacity win the paged pool banks)
 SYS_LEN = 32
 TAIL_LENS = (4, 16, 4, 40, 8, 4)
 MAX_NEW = (12, 24, 12, 24, 24, 12)
-# the dense per-slot ceiling: largest regular request, prompt + max_new
-DENSE_MAX_LEN = max(SYS_LEN + t + m for t, m in zip(TAIL_LENS, MAX_NEW))
-DENSE_SLOTS = 4
+# the largest regular request, prompt + max_new: the pool sizing unit
+MAX_LEN = max(SYS_LEN + t + m for t, m in zip(TAIL_LENS, MAX_NEW))
 BLOCK_SIZE = 16
-# equal memory: the paged pool gets exactly the dense pool's tokens
-POOL_TOKENS = DENSE_SLOTS * DENSE_MAX_LEN
+# the page pool: four of the largest regular requests' tokens
+POOL_TOKENS = 4 * MAX_LEN
 NUM_BLOCKS = POOL_TOKENS // BLOCK_SIZE
 PAGED_SLOTS = 8
-# the beyond-ceiling request: admissible only under the paged layout
+# the long request, beyond MAX_LEN (the pool admits it up to max_seq)
 LONG_PROMPT, LONG_NEW = 96, 24
 # draft tokens per speculative round (the spec arm)
 SPEC_TOKENS = 3
@@ -120,16 +109,11 @@ def build_trace(cfg, n_requests: int, seed: int = 0, with_long: bool = True):
 
 
 def make_scheduler(cfg, params, mode: str) -> Scheduler:
-    if mode in ("static", "dense"):
-        return Scheduler(
-            cfg, params, num_slots=DENSE_SLOTS, max_len=DENSE_MAX_LEN,
-            block_size=BLOCK_SIZE, num_blocks=NUM_BLOCKS, layout="dense",
-            policy="static" if mode == "static" else "continuous")
     paged_kw = dict(
-        num_slots=PAGED_SLOTS, max_len=DENSE_MAX_LEN,
-        block_size=BLOCK_SIZE, num_blocks=NUM_BLOCKS, layout="paged",
+        num_slots=PAGED_SLOTS, max_len=MAX_LEN,
+        block_size=BLOCK_SIZE, num_blocks=NUM_BLOCKS,
         max_seq=LONG_PROMPT + LONG_NEW, prefill_chunk=2 * BLOCK_SIZE,
-        max_prefills_per_step=3, policy="continuous",
+        max_prefills_per_step=3,
         # self-draft: the accept-rate upper bound (a deployment drafts
         # with an earlier/smaller LTFB population checkpoint instead)
         draft_params=params if mode == "spec" else None,
@@ -201,8 +185,7 @@ def run(report: CsvReport, quick: bool = False, json_path: str = None,
     # misses chunk/table-width shape buckets and the measured run pays
     # the compile), then run the configs round-robin and report each
     # one's median of 5, so slow-machine drift hits all configs alike
-    modes = ("static", "dense", "paged", "paged_notel", "paged_journal",
-             "spec")
+    modes = ("paged", "paged_notel", "paged_journal", "spec")
     if jax.device_count() >= MESH_DEVICES:
         modes = modes + ("mesh",)
     else:
@@ -235,13 +218,12 @@ def run(report: CsvReport, quick: bool = False, json_path: str = None,
                    d["ttft_mean_s"] * 1e6,
                    f"p95={d['ttft_p95_s'] * 1e6:.0f}us")
 
-    # the dense ceiling rejects the long request; paged admits it
-    assert out["dense"]["rejected"] >= 1, "long request should not fit dense"
+    # the long request is served from the shared pool
     assert out["paged"]["rejected"] == 0 and \
         out["paged"]["completed"] == len(reqs), \
-        "paged pool must admit the beyond-ceiling request"
-    print(f"# fig14 long request ({LONG_PROMPT}+{LONG_NEW} tokens > dense "
-          f"ceiling {DENSE_MAX_LEN}): dense rejected, paged served")
+        "paged pool must admit the long request"
+    print(f"# fig14 long request ({LONG_PROMPT}+{LONG_NEW} tokens > "
+          f"max_len {MAX_LEN}): served")
     print(f"# fig14 paged prefix cache: "
           f"hits={out['paged']['pool_prefix_hits']} "
           f"shared_tokens={out['paged']['pool_prefix_shared_tokens']} "
@@ -347,19 +329,10 @@ def run(report: CsvReport, quick: bool = False, json_path: str = None,
               f"bf16 arm: {out['mesh']['tokens_per_s']:.1f} tok/s on "
               f"emulated devices (mechanics check, not a speedup claim)")
 
-    cont = out["dense"]["tokens_per_s"] / \
-        max(out["static"]["tokens_per_s"], 1e-9)
-    paged = out["paged"]["tokens_per_s"] / \
-        max(out["dense"]["tokens_per_s"], 1e-9)
     spec = out["spec"]["tokens_per_s"] / \
         max(out["paged"]["tokens_per_s"], 1e-9)
-    print(f"# fig14 continuous/static tokens/s speedup: {cont:.2f}x")
-    print(f"# fig14 paged+chunked/dense-continuous tokens/s speedup "
-          f"(equal memory): {paged:.2f}x")
     print(f"# fig14 spec/paged tokens/s ratio (self-draft upper bound, "
           f"CPU oracle): {spec:.2f}x")
-    report.add("fig14_continuous_speedup", cont * 100, f"{cont:.2f}x")
-    report.add("fig14_paged_speedup", paged * 100, f"{paged:.2f}x")
     report.add("fig14_spec_speedup", spec * 100, f"{spec:.2f}x")
     report.add("fig14_spec_accept_rate",
                out["spec"]["spec_accept_rate"] * 100,
@@ -369,13 +342,11 @@ def run(report: CsvReport, quick: bool = False, json_path: str = None,
         summary = {
             "trace": {"requests": len(reqs), "sys_prefix": SYS_LEN,
                       "pool_tokens": POOL_TOKENS,
-                      "dense_max_len": DENSE_MAX_LEN,
+                      "max_len": MAX_LEN,
                       "long_request": LONG_PROMPT + LONG_NEW,
                       "spec_tokens": SPEC_TOKENS,
                       "mesh_shape": list(MESH_SHAPE)
                       if "mesh" in out else None},
-            "speedup_paged_vs_dense": paged,
-            "speedup_continuous_vs_static": cont,
             "speedup_spec_vs_paged": spec,
             "telemetry_overhead": overhead,
             "journal_overhead": journal_overhead,

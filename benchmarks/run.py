@@ -22,7 +22,7 @@ def main(argv=None) -> int:
     from benchmarks.common import CsvReport
     from benchmarks import (fig9_data_parallel, fig10_datastore,
                             fig11_ltfb_scaling, fig12_quality,
-                            fig13_kindependent, fig14_serving, roofline)
+                            fig13_kindependent, fig14_serving)
 
     suites = {
         "fig9": fig9_data_parallel.run,
@@ -31,7 +31,6 @@ def main(argv=None) -> int:
         "fig12": fig12_quality.run,
         "fig13": fig13_kindependent.run,
         "fig14": fig14_serving.run,
-        "roofline": roofline.run,
     }
     if args.only:
         keep = set(args.only.split(","))

@@ -225,7 +225,7 @@ def decode_hlo(jnp, sched) -> str:
 
     s, lay = sched.session, sched.session.layout
     B = lay.num_slots
-    tables = lay.step_kwargs()["tables"]
+    tables = lay.step_tables()
     return session_mod._step_tables_fn.lower(
         s.params, s.cfg, jnp.zeros((B, 1), jnp.int32), lay.cache,
         jnp.full((B,), -1, jnp.int32), tables, None).compile().as_text()

@@ -3,8 +3,7 @@
 A mixed-length batch of prompts flows through the request queue: the
 scheduler admits requests by token budget into a shared preallocated
 KV-cache pool, interleaves prefill of new requests with batched decode
-of in-flight ones, and frees slots per-request on completion — compare
-with the static (pad-to-max) baseline by passing --policy static.
+of in-flight ones, and frees slots per-request on completion.
 
 Every model call runs through ONE DecodeSession (the family-agnostic
 decode API): pass --spec-tokens K to decode speculatively — a drafter
@@ -38,8 +37,6 @@ def main():
     ap.add_argument("--slots", type=int, default=3)
     ap.add_argument("--prompt-lens", default="8,16,24")
     ap.add_argument("--tokens", type=int, default=24)
-    ap.add_argument("--policy", default="continuous",
-                    choices=("continuous", "static"))
     ap.add_argument("--spec-tokens", type=int, default=0,
                     help="speculative decoding: draft tokens per round "
                          "(self-draft demo; 0 = off)")
@@ -47,13 +44,12 @@ def main():
 
     cfg = get_config(args.arch, smoke=True)
     print(f"arch={cfg.name} (reduced config, {cfg.param_count()/1e6:.1f}M "
-          f"params), slots={args.slots} policy={args.policy}")
+          f"params), slots={args.slots}")
     params, _ = init_lm(cfg, jax.random.PRNGKey(0))
 
     lens = parse_lens(args.prompt_lens)
     max_len = max(lens) + args.tokens
     sched = Scheduler(cfg, params, num_slots=args.slots, max_len=max_len,
-                      policy=args.policy,
                       draft_params=params if args.spec_tokens > 0 else None,
                       spec_tokens=args.spec_tokens)
     for r in build_requests(cfg, args.requests, lens, args.tokens, seed=1):

@@ -1,8 +1,8 @@
 """Train an assigned-architecture LM on the synthetic token pipeline.
 
 Any of the 10 architectures is selectable; reduced (smoke) configs keep
-this runnable on CPU, and the identical code path is what the dry-run
-lowers at full scale on the production mesh.  Optional --ltfb K runs the
+this runnable on CPU, and the identical code path runs the full configs
+on a device mesh.  Optional --ltfb K runs the
 tournament algorithm over K trainers (full-model exchange).
 
   PYTHONPATH=src python examples/train_lm.py --arch xlstm-125m --steps 60

@@ -8,9 +8,6 @@ from repro.configs.base import (  # noqa: F401
     MoEConfig,
     OptimizerConfig,
     RunConfig,
-    SHAPE_BY_NAME,
-    SHAPES,
-    ShapeConfig,
     XLSTMConfig,
     replace,
 )

@@ -1,8 +1,8 @@
 """Config dataclasses for the repro framework.
 
 Everything in the framework is driven by these configs: model construction
-(`repro.models`), sharding rules (`repro.parallel`), the launchers
-(`repro.launch`) and the dry-run/roofline tooling.
+(`repro.models`), sharding rules (`repro.parallel`) and the launchers
+(`repro.launch`).
 
 Configs are plain frozen dataclasses (no external deps) so they can be
 constructed in tests, serialized into checkpoints, and diffed in logs.
@@ -274,29 +274,6 @@ class ModelConfig:
                     if d_ff:
                         n += self._dense_ffn_params(d_ff)
         return n
-
-
-# ---------------------------------------------------------------------------
-# Shapes (assigned input-shape set for the LM family)
-# ---------------------------------------------------------------------------
-
-
-@dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str            # 'train' | 'prefill' | 'decode'
-
-
-SHAPES: Tuple[ShapeConfig, ...] = (
-    ShapeConfig("train_4k", 4_096, 256, "train"),
-    ShapeConfig("prefill_32k", 32_768, 32, "prefill"),
-    ShapeConfig("decode_32k", 32_768, 128, "decode"),
-    ShapeConfig("long_500k", 524_288, 1, "decode"),
-)
-
-SHAPE_BY_NAME = {s.name: s for s in SHAPES}
 
 
 # ---------------------------------------------------------------------------

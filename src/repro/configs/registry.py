@@ -5,7 +5,7 @@ literature config) and SMOKE (reduced same-family config for CPU tests).
 """
 from __future__ import annotations
 
-from typing import Dict, List, Tuple
+from typing import Dict, Tuple
 
 from repro.configs import (
     codeqwen15,
@@ -21,7 +21,6 @@ from repro.configs import (
     qwen3_06b,
     xlstm_125m,
 )
-from repro.configs.base import SHAPE_BY_NAME, SHAPES, ModelConfig, ShapeConfig
 
 _MODULES = (
     phi35_moe,
@@ -38,15 +37,10 @@ _MODULES = (
 
 ARCHS: Dict[str, object] = {m.ARCH_ID: m for m in _MODULES}
 ARCHS[icf_cyclegan.ARCH_ID] = icf_cyclegan
-# served through the paged path only (latent attention has no dense-row
-# cache), so it stays out of the dry-run grid below
 ARCHS[deepseek_v2_lite.ARCH_ID] = deepseek_v2_lite
 
-# LM architectures participating in the arch x shape dry-run grid.
+# The LM architecture families the model and serving tests sweep.
 LM_ARCH_IDS: Tuple[str, ...] = tuple(m.ARCH_ID for m in _MODULES)
-
-# Families that support the long_500k sub-quadratic decode shape.
-SUBQUADRATIC_FAMILIES = ("ssm", "hybrid")
 
 
 def get_config(arch_id: str, smoke: bool = False):
@@ -56,27 +50,3 @@ def get_config(arch_id: str, smoke: bool = False):
     mod = ARCHS[arch_id]
     return mod.SMOKE if smoke else mod.FULL
 
-
-def shapes_for(cfg: ModelConfig) -> List[ShapeConfig]:
-    """Applicable input shapes for an architecture (skips documented in
-    DESIGN.md section 4): long_500k only for sub-quadratic families."""
-    out = []
-    for s in SHAPES:
-        if s.name == "long_500k" and cfg.family not in SUBQUADRATIC_FAMILIES:
-            continue
-        out.append(s)
-    return out
-
-
-def dryrun_cells() -> List[Tuple[str, str]]:
-    """All (arch_id, shape_name) dry-run cells."""
-    cells = []
-    for arch_id in LM_ARCH_IDS:
-        cfg = get_config(arch_id)
-        for s in shapes_for(cfg):
-            cells.append((arch_id, s.name))
-    return cells
-
-
-def get_shape(name: str) -> ShapeConfig:
-    return SHAPE_BY_NAME[name]

@@ -18,9 +18,9 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
         n *= s
     devices = jax.devices()[:n]
     assert len(devices) == n, (
-        f"need {n} devices, have {len(devices)} — the dry-run entrypoint "
-        "must set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
-        "before importing jax")
+        f"need {n} devices, have {len(devices)} (to emulate them, set "
+        "XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
+        "importing jax)")
     import numpy as np
     return Mesh(np.asarray(devices).reshape(shape), axes)
 

@@ -182,8 +182,7 @@ def run_lm(args) -> Dict[str, object]:
     sched_kw = dict(
         num_slots=args.slots, max_len=max_len,
         block_size=args.block_size, num_blocks=args.num_blocks,
-        max_seq=args.max_seq, layout=args.layout,
-        policy=args.policy, prefill_chunk=args.prefill_chunk,
+        max_seq=args.max_seq, prefill_chunk=args.prefill_chunk,
         prefix_sharing=not args.no_prefix_sharing,
         pin_prefix=args.pin_prefix,
         max_prefills_per_step=args.prefill_per_step,
@@ -233,8 +232,8 @@ def run_lm(args) -> Dict[str, object]:
     reqs = build_requests(cfg, args.requests, parse_lens(args.prompt_lens),
                           args.max_new, eos_id=args.eos_id,
                           temperature=args.temperature, seed=args.seed)
-    print(f"[serve] arch={cfg.name} workload=lm layout={args.layout} "
-          f"policy={args.policy} slots={args.slots} max_len={max_len} "
+    print(f"[serve] arch={cfg.name} workload=lm "
+          f"slots={args.slots} max_len={max_len} "
           f"max_seq={sched.max_seq} block_size={args.block_size} "
           f"prefill_chunk={args.prefill_chunk} "
           f"swap_mode={args.swap_mode} requests={len(reqs)} "
@@ -256,11 +255,10 @@ def run_lm(args) -> Dict[str, object]:
           f"blocks_used_high_water={pd['high_water_blocks']}/"
           f"{pd['num_blocks']} block_allocs={pd['block_allocs']} "
           f"block_frees={pd['block_frees']}")
-    if args.layout == "paged":
-        print(f"[serve] prefix-cache: hits={pd['prefix_hits']} "
-              f"shared_tokens={pd['prefix_shared_tokens']} "
-              f"pinned={pd['pinned_blocks']} "
-              f"prefill_chunks={sched.stats.prefill_chunks}")
+    print(f"[serve] prefix-cache: hits={pd['prefix_hits']} "
+          f"shared_tokens={pd['prefix_shared_tokens']} "
+          f"pinned={pd['pinned_blocks']} "
+          f"prefill_chunks={sched.stats.prefill_chunks}")
     if args.spec_adapt and sched.spec_k_by_rid:
         ks = sched.spec_k_by_rid
         print(f"[serve] spec-adapt per-row K (final): "
@@ -446,13 +444,9 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--num-blocks", type=int, default=None,
                     help="page-pool size (default: slots*max_len worth)")
     ap.add_argument("--max-seq", type=int, default=None,
-                    help="per-request length cap (paged layout; default "
-                         "max_len — raise it to admit requests longer "
-                         "than the old dense per-slot ceiling)")
-    ap.add_argument("--layout", default="paged",
-                    choices=("paged", "dense"),
-                    help="paged: scattered KV pages + gather-decode "
-                         "kernel; dense: PR-2 slot rows (baseline)")
+                    help="per-request length cap (default max_len — "
+                         "raise it to admit requests longer than the "
+                         "pool sizing unit)")
     ap.add_argument("--prefill-chunk", type=int, default=0,
                     help="prefill long prompts in N-token chunks "
                          "interleaved with decode (0 = one-shot; "
@@ -534,8 +528,6 @@ def build_parser() -> argparse.ArgumentParser:
                     help="hot-swap policy: immediate applies new "
                          "weights to in-flight requests; drain lets "
                          "them finish on the old weights first")
-    ap.add_argument("--policy", default="continuous",
-                    choices=("continuous", "static"))
     ap.add_argument("--prefill-per-step", type=int, default=1)
     # lm trace
     ap.add_argument("--requests", type=int, default=8)

@@ -1,22 +1,18 @@
-"""ShapeDtypeStruct input stand-ins for every (arch x shape) dry-run cell.
+"""ShapeDtypeStruct stand-ins (with logical axes) for params, train
+state and the serving cache.
 
 No device allocation happens here — array stand-ins come from
-``jax.ShapeDtypeStruct`` / ``jax.eval_shape``; the logical-axes trees
-(pure Python) are captured by closure while tracing the init functions,
-so the FULL 398B configs cost nothing to "initialize".
+``jax.eval_shape``; the logical-axes trees (pure Python) are captured
+by closure while tracing the init functions, so the FULL 398B configs
+cost nothing to "initialize".
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Tuple
-
 import jax
-import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig, OptimizerConfig, ShapeConfig
+from repro.configs.base import ModelConfig, OptimizerConfig
 from repro.models import lm
 from repro.train import steps as steps_lib
-
-SDS = jax.ShapeDtypeStruct
 
 
 def _shapes_and_aux(fn):
@@ -48,10 +44,6 @@ def state_specs(cfg: ModelConfig, opt_cfg: OptimizerConfig):
             {"params": p_axes, "opt_state": o_axes})
 
 
-def cache_specs(cfg: ModelConfig, batch: int, max_len: int):
-    return _shapes_and_aux(lambda: lm.init_cache(cfg, batch, max_len))
-
-
 def serve_cache_specs(cfg: ModelConfig, num_slots: int, num_pages: int,
                       block_size: int = 16):
     """PAGED serving-cache specs (ShapeDtypeStructs + logical axes).
@@ -68,41 +60,3 @@ def serve_cache_specs(cfg: ModelConfig, num_slots: int, num_pages: int,
         lambda: lm.init_cache(cfg, num_slots,
                               pages=(num_pages, block_size)))
 
-
-def train_input_specs(cfg: ModelConfig, shape: ShapeConfig) -> Dict[str, Any]:
-    B, S = shape.global_batch, shape.seq_len
-    if cfg.family == "vlm":
-        return {
-            "embeds": SDS((B, S, cfg.d_model), jnp.bfloat16),
-            "positions": SDS((3, B, S), jnp.int32),
-            "labels": SDS((B, S), jnp.int32),
-        }
-    return {"tokens": SDS((B, S), jnp.int32),
-            "labels": SDS((B, S), jnp.int32)}
-
-
-def prefill_input_specs(cfg: ModelConfig, shape: ShapeConfig
-                        ) -> Dict[str, Any]:
-    specs = train_input_specs(cfg, shape)
-    specs.pop("labels")
-    return specs
-
-
-def decode_input_specs(cfg: ModelConfig, shape: ShapeConfig):
-    """(tokens, cache, index) specs + cache axes; cache len = seq_len."""
-    B, S = shape.global_batch, shape.seq_len
-    cache_sh, cache_ax = cache_specs(cfg, B, S)
-    return SDS((B, 1), jnp.int32), cache_sh, cache_ax, SDS((), jnp.int32)
-
-
-# logical axes for input batches
-TRAIN_BATCH_AXES = {
-    "tokens": ("batch", "seq"),
-    "labels": ("batch", "seq"),
-    "embeds": ("batch", "seq", "act_embed"),
-    "positions": (None, "batch", "seq"),
-}
-
-
-def batch_axes(specs: Dict[str, Any]) -> Dict[str, Tuple]:
-    return {k: TRAIN_BATCH_AXES[k] for k in specs}

@@ -3,9 +3,8 @@
 Single-controller JAX: builds the mesh from the runtime topology, the
 datastore from the file manifest, shards the train state per the
 logical rules, and runs the (optionally LTFB-wrapped) training loop with
-checkpoint/restart.  On this CPU container it runs the reduced configs;
-on a TPU pod slice the same script runs the full configs (the dry-run
-proves every cell compiles on the production meshes).
+checkpoint/restart.  On a CPU it runs the reduced configs; on a TPU
+pod slice the same script runs the full configs.
 
   python -m repro.launch.train --arch qwen3-0.6b --smoke --steps 50
   python -m repro.launch.train --arch icf-cyclegan --steps 300

@@ -410,61 +410,6 @@ def attention_prefill(params: Params, cfg: ModelConfig, x: jax.Array,
     return constrain(out, "batch", "seq", "act_embed"), cache
 
 
-def attention_decode(params: Params, cfg: ModelConfig, x: jax.Array,
-                     cache: Params, index: jax.Array,
-                     positions: jax.Array):
-    """K-token decode/verify with a KV cache of static length S_max.
-
-    x: (B, K, d) — K >= 1 consecutive tokens per row (K = 1 is the
-    classic decode step; K > 1 is the speculative-verify write/read);
-    cache['k'/'v']: (B, S_max, Hkv, D); index: scalar int32 write
-    position of the FIRST token (= current KV length), or an int32 (B,)
-    vector of per-row write positions (continuous batching: each cache
-    row belongs to a different request at a different length).  Token t
-    of row b is written at ``index[b] + t`` and attends causally over
-    positions ``<= index[b] + t``.  Writes past S_max are dropped —
-    they can only be speculative padding the caller rolls back.
-    Returns (out, new_cache).
-    """
-    B, K, _ = x.shape
-    q, k_new, v_new = _project_qkv(params, cfg, x, positions)
-    index = jnp.asarray(index, jnp.int32)
-    if index.ndim == 0 and K == 1:
-        k = jax.lax.dynamic_update_slice_in_dim(
-            cache["k"], k_new.astype(cache["k"].dtype), index, axis=1)
-        v = jax.lax.dynamic_update_slice_in_dim(
-            cache["v"], v_new.astype(cache["v"].dtype), index, axis=1)
-    else:
-        # per-(row, token) write: scatter K (Hkv, D) rows per batch
-        # element — O(B*K*Hkv*D) traffic, independent of max_len
-        idx_col = index[:, None] if index.ndim else \
-            jnp.full((B, 1), index, jnp.int32)
-        wpos = idx_col + jnp.arange(K, dtype=jnp.int32)[None, :]  # (B,K)
-        rows = jnp.broadcast_to(
-            jnp.arange(B, dtype=jnp.int32)[:, None], (B, K))
-        k = cache["k"].at[rows, wpos].set(
-            k_new.astype(cache["k"].dtype), mode="drop")
-        v = cache["v"].at[rows, wpos].set(
-            v_new.astype(cache["v"].dtype), mode="drop")
-    k = constrain(k, "batch", "kv_seq", "kv_heads", "head_dim")
-    v = constrain(v, "batch", "kv_seq", "kv_heads", "head_dim")
-    S_max = k.shape[1]
-    H, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
-    g = H // Hkv
-    qh = q.reshape(B, K, Hkv, g, D)
-    scores = jnp.einsum("bthgd,bkhd->bthgk", qh, k,
-                        preferred_element_type=jnp.float32) / math.sqrt(D)
-    pos = jnp.arange(S_max, dtype=jnp.int32)
-    reach = (index if index.ndim else jnp.full((B,), index, jnp.int32))[
-        :, None] + jnp.arange(K, dtype=jnp.int32)[None, :]      # (B, K)
-    valid = pos[None, None, :] <= reach[..., None]              # (B,K,S)
-    scores = jnp.where(valid[:, :, None, None, :], scores, -jnp.inf)
-    w = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bthgk,bkhd->bthgd", w.astype(v.dtype), v)
-    out = out.reshape(B, K, cfg.q_dim) @ params["wo"]
-    return constrain(out, "batch", "seq", "act_embed"), {"k": k, "v": v}
-
-
 # ---------------------------------------------------------------------------
 # Paged attention (serving): KV lives in a shared page pool, requests
 # address it through per-row block tables.  The gather-decode compute is
@@ -942,17 +887,6 @@ def decode_scan(step_fn, x: jax.Array, state,
 
     st, ys = jax.lax.scan(step, state, (x.swapaxes(0, 1), keep))
     return ys.swapaxes(0, 1), st
-
-
-def init_attention_cache(cfg: ModelConfig, batch: int, max_len: int,
-                         dtype=None):
-    dt = dtype or jnp.dtype(cfg.dtype)
-    hd = cfg.resolved_head_dim
-    shape = (batch, max_len, cfg.num_kv_heads, hd)
-    cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
-    axes = {"k": ("batch", "kv_seq", "kv_heads", "head_dim"),
-            "v": ("batch", "kv_seq", "kv_heads", "head_dim")}
-    return cache, axes
 
 
 # ---------------------------------------------------------------------------

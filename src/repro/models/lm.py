@@ -13,18 +13,18 @@ stack is driven by ``jax.lax.scan`` — this keeps HLO size and compile time
 independent of depth, and gives the FSDP all-gather/compute overlap
 pattern on the period boundary.
 
-Three entry points mirror the dry-run shapes:
-  ``lm_loss``      (train_*)    — next-token CE + MoE aux losses
-  ``lm_prefill``   (prefill_*)  — forward + cache construction (full
-                   prompt, or one chunked slice over a paged pool when
-                   ``tables`` is given)
-  ``lm_decode``    (decode_*/long_*) — K >= 1 tokens per row with a
-                   carried cache (dense slot rows or paged block
-                   tables); K > 1 is the speculative-verify step
+Three entry points:
+  ``lm_loss``      — next-token CE + MoE aux losses (training)
+  ``lm_prefill``   — forward + cache construction (full prompt, or one
+                   chunked slice over a paged pool when ``tables`` is
+                   given)
+  ``lm_decode``    — K >= 1 tokens per row over the paged pool,
+                   addressed through block tables; K > 1 is the
+                   speculative-verify step
 
 The serving layer drives these exclusively through
 :class:`repro.serve.session.DecodeSession`, which pairs them with a
-``CacheLayout`` (slot rows or paged pool) and owns the jit boundaries.
+:class:`repro.serve.kv_cache.PagedLayout` and owns the jit boundaries.
 """
 from __future__ import annotations
 
@@ -185,17 +185,17 @@ def _apply_block(bp: Params, cfg: ModelConfig, spec: LayerSpec, x, positions,
                  token_mask=None):
     """Returns (x, new_cache, aux).
 
-    ``tables`` switches attention layers onto the paged-KV path:
-    mode "decode" uses the gather-decode kernel over scattered pages and
-    mode "chunk" runs one chunked-prefill slice (attention-only stacks).
-    Recurrent mixers keep their per-slot state rows in both cases.
+    Modes "decode" and "chunk" run on the paged pool through
+    ``tables``: "decode" uses the gather-decode kernel over scattered
+    pages and "chunk" runs one chunked-prefill slice (attention-only
+    stacks).  Recurrent mixers keep their per-slot state rows in both.
     Decode mode handles K >= 1 tokens per row; ``valid`` (int32 (B,))
     marks how many of the K are real per row — attention routes the
-    rest to the null page (paged) and recurrent mixers freeze their
-    state past it (the speculative verify / rollback-replay contract).
-    On the paged pool (``tables`` given) attention ('a') and latent
-    attention ('l') get the WHOLE stacked pool as ``cache`` and their
-    own index in the stack as ``layer``, and return the whole pool.
+    rest to the null page and recurrent mixers freeze their state past
+    it (the speculative verify / rollback-replay contract).  Attention
+    ('a') and latent attention ('l') get the WHOLE stacked pool as
+    ``cache`` and their own index in the stack as ``layer``, and return
+    the whole pool.
     ``token_mask`` ((B, S), true on real tokens) makes
     an MoE layer count its routing into ``aux``.
     """
@@ -217,16 +217,10 @@ def _apply_block(bp: Params, cfg: ModelConfig, spec: LayerSpec, x, positions,
             mix, new_cache = L.attention_chunk_paged(
                 bp["mixer"], cfg, h, cache, layer, tables, hist_len,
                 prompt_len, positions)
-        elif tables is not None:
+        else:
             mix, new_cache = L.attention_decode_paged(
                 bp["mixer"], cfg, h, cache, layer, index, positions, tables,
                 valid=valid)
-        else:
-            # dense rows: beyond-``valid`` writes land at future
-            # positions the causal mask hides until they are
-            # overwritten, so no routing is needed
-            mix, new_cache = L.attention_decode(bp["mixer"], cfg, h, cache,
-                                                index, positions)
     elif spec.kind == "l":
         if mode == "train":
             mix = L.mla_block(bp["mixer"], cfg, h, positions)
@@ -237,13 +231,10 @@ def _apply_block(bp: Params, cfg: ModelConfig, spec: LayerSpec, x, positions,
             mix, new_cache = L.mla_chunk_paged(
                 bp["mixer"], cfg, h, cache, layer, tables, hist_len,
                 prompt_len, positions)
-        elif tables is not None:
+        else:
             mix, new_cache = L.mla_decode_paged(
                 bp["mixer"], cfg, h, cache, layer, index, positions, tables,
                 valid=valid)
-        else:
-            raise ValueError("latent attention decodes through the paged "
-                             "pool only (pass block tables)")
     elif spec.kind == "M":
         if mode == "train":
             mix = S.mamba_block(bp["mixer"], cfg, h)
@@ -406,10 +397,7 @@ def lm_loss(params: Params, cfg: ModelConfig, batch: Dict[str, jax.Array],
 # ---------------------------------------------------------------------------
 
 
-def _init_block_cache(cfg: ModelConfig, spec: LayerSpec, batch: int,
-                      max_len: int):
-    if spec.kind == "a":
-        return L.init_attention_cache(cfg, batch, max_len)
+def _init_state_rows(cfg: ModelConfig, spec: LayerSpec, batch: int):
     if spec.kind == "M":
         return S.init_mamba_state(cfg, batch)
     if spec.kind == "m":
@@ -419,30 +407,29 @@ def _init_block_cache(cfg: ModelConfig, spec: LayerSpec, batch: int,
     raise ValueError(spec.kind)
 
 
-def init_cache(cfg: ModelConfig, batch: int, max_len: int = 0,
+def init_cache(cfg: ModelConfig, batch: int,
                pages: Optional[Tuple[int, int]] = None):
     """Cache pytree mirroring the stacked param structure.
 
-    With ``pages=None`` attention layers get dense ``(batch, max_len,
-    Hkv, D)`` slot rows.  With ``pages=(num_pages, block_size)`` they
-    instead get ONE shared ``(num_pages + 1, block_size, Hkv, D)`` pool
-    each (page ``num_pages`` is the null page), addressed through
-    per-request block tables, so a request's KV can be scattered
-    anywhere in the pool; a stack's pools are allocated already stacked
+    ``pages=(num_pages, block_size)`` gives attention layers ONE shared
+    ``(num_pages + 1, block_size, Hkv, D)`` pool each (page
+    ``num_pages`` is the null page), addressed through per-request
+    block tables, so a request's KV can be scattered anywhere in the
+    pool; a stack's pools are allocated already stacked
     (:func:`repro.models.layers.init_paged_attention_pool`), as the
-    layer scan carries them.  Recurrent layers (mamba / xLSTM) carry
-    O(1) state per request and keep ``batch`` dense rows in both
-    layouts.  Latent-attention layers (paged only) get one latent pool
-    per stack, allocated already stacked
-    (:func:`repro.models.layers.init_latent_pool`), and an MoE model
-    with them also gets ``moe_stats``: the real tokens each layer routed
-    to each expert (``expert_tokens``, (num_layers, E) int32) and the
-    routed choices dropped for capacity (``dropped_tokens``), counted on
-    the device by every decode step and prefill chunk.  Returns (cache,
-    axes); axes leaves containing ``"pages"`` / ``"kv_seq"`` identify
-    attention KV, ``"counters"`` the counters, everything else is the
-    recurrent state that snapshot/restore (speculative rollback)
-    copies.
+    layer scan carries them.  Latent-attention layers get one latent
+    pool per stack, also allocated stacked
+    (:func:`repro.models.layers.init_latent_pool`).  A stack with
+    attention or latent layers needs ``pages``.  Recurrent layers
+    (mamba / xLSTM) carry O(1) state per request and keep ``batch``
+    rows.  An MoE model with latent layers also gets ``moe_stats``:
+    the real tokens each layer routed to each expert
+    (``expert_tokens``, (num_layers, E) int32) and the routed choices
+    dropped for capacity (``dropped_tokens``), counted on the device by
+    every decode step and prefill chunk.  Returns (cache, axes); axes
+    leaves containing ``"pages"`` identify the pools, ``"counters"``
+    the counters, everything else is the recurrent state that
+    snapshot/restore (speculative rollback) copies.
     """
     specs = layer_specs(cfg)
     k0, R, P = _grouping(cfg)
@@ -451,16 +438,15 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int = 0,
 
     def stack(spec, n, axis):
         # ``n`` layers of one (homogeneous) stack, stacked on ``axis``
+        if spec.kind in ATTENTION_KINDS and pages is None:
+            raise ValueError("attention keeps its cache in a paged pool "
+                             "only (pass pages=)")
         if spec.kind == "l":
-            if pages is None:
-                raise ValueError("latent attention keeps its cache in a "
-                                 "paged pool only (pass pages=)")
             return L.init_latent_pool(cfg, n, pages[0], pages[1])
-        if spec.kind == "a" and pages is not None:
+        if spec.kind == "a":
             c, a = L.init_paged_attention_pool(cfg, n, pages[0], pages[1])
             return c, _push_axis(a, axis)
-        per = [_init_block_cache(cfg, spec, batch, max_len)
-               for _ in range(n)]
+        per = [_init_state_rows(cfg, spec, batch) for _ in range(n)]
         return _stack([c for c, _ in per]), _push_axis(per[0][1], axis)
 
     if k0:
@@ -591,7 +577,7 @@ def _prefill_chunk(params: Params, cfg: ModelConfig, tokens: jax.Array,
 
 
 def lm_prefill(params: Params, cfg: ModelConfig, batch: Dict[str, jax.Array],
-               remat: str = "none", last_pos: Optional[jax.Array] = None,
+               last_pos: Optional[jax.Array] = None,
                cache: Optional[Params] = None,
                tables: Optional[jax.Array] = None,
                hist_len: Optional[jax.Array] = None,
@@ -601,7 +587,10 @@ def lm_prefill(params: Params, cfg: ModelConfig, batch: Dict[str, jax.Array],
     Two modes behind one entry point:
 
     * **full prefill** (``tables=None``, the default): forward the
-      whole prompt and build a fresh dense cache.  ``last_pos`` (int32
+      whole prompt and build a fresh per-layer cache (K/V or latent
+      rows per position, recurrent state), which
+      :meth:`repro.serve.kv_cache.PagedLayout.insert_prefill` scatters
+      into the pool.  ``last_pos`` (int32
       (B,), optional) selects the hidden state each row's logits are
       read from instead of position ``S - 1`` — the serving scheduler
       right-pads prompts to a shape bucket and reads logits at each
@@ -629,7 +618,6 @@ def lm_prefill(params: Params, cfg: ModelConfig, batch: Dict[str, jax.Array],
                 caches.append(c)
             return xc, tuple(caches)
 
-        body = _remat_wrap(body, remat)
         return jax.lax.scan(body, x, stacked)
 
     caches: Params = {}
@@ -650,82 +638,50 @@ def lm_prefill(params: Params, cfg: ModelConfig, batch: Dict[str, jax.Array],
 
 
 def lm_decode(params: Params, cfg: ModelConfig, tokens: jax.Array,
-              cache: Params, index: jax.Array,
-              positions: Optional[jax.Array] = None,
-              tables: Optional[jax.Array] = None,
+              cache: Params, index: jax.Array, *, tables: jax.Array,
               valid: Optional[jax.Array] = None):
-    """One decode step over K >= 1 tokens per row.
+    """One decode step over K >= 1 tokens per row, on the paged pool.
 
     tokens: (B, K) int32 — K = 1 is the classic single-token decode;
     K > 1 is the speculative-verify step (K consecutive tokens per row,
-    logits returned for every position).  index: scalar int32 write
-    position of the first token (= current KV length), or an int32 (B,)
-    vector of per-row positions (continuous batching: each batch row is
-    a different request at a different length); token t of row b lands
-    at ``index[b] + t``.  With ``tables`` ((B, W) int32 block tables)
-    attention layers run the paged gather/scatter path and a per-row
-    index of -1 marks an idle row (writes route to the null page).
-    ``valid`` (int32 (B,), optional) caps the real tokens per row:
-    beyond it attention writes route to the null page and recurrent
-    state freezes — the primitive speculative decoding's rollback
-    replay is built on.  Returns (logits (B, K, V), new_cache)."""
+    logits returned for every position).  index: int32 (B,) per-row
+    write position of each row's first token (= its current KV length;
+    continuous batching: each batch row is a different request at a
+    different length), or a scalar for every row; token t of row b
+    lands at ``index[b] + t``, and an index of -1 marks an idle row
+    (writes route to the null page).  tables: (B, W) int32 block
+    tables into the pool.  ``valid`` (int32 (B,), optional) caps the
+    real tokens per row: beyond it attention writes route to the null
+    page and recurrent state freezes — the primitive speculative
+    decoding's rollback replay is built on.  The stacked pool rides in
+    the layer scan's carry (:func:`_run_carried`).  Returns (logits
+    (B, K, V), new_cache)."""
     x = jnp.take(params["embed"], tokens, axis=0)
     x = constrain(x, "batch", "seq", "act_embed")
     B, K = tokens.shape
     index = jnp.asarray(index, jnp.int32)
     if valid is not None:
         valid = jnp.asarray(valid, jnp.int32)
-    if positions is None:
-        idx_col = index[:, None] if index.ndim else \
-            jnp.full((B, 1), index, jnp.int32)
-        if tables is not None:     # paged: clamp the idle-row sentinel
-            idx_col = jnp.maximum(idx_col, 0)
-        pos = idx_col + jnp.arange(K, dtype=jnp.int32)[None, :]
-        if cfg.use_mrope:
-            # text decode: all three M-RoPE components advance together
-            positions = jnp.broadcast_to(pos[None], (3, B, K))
-        else:
-            positions = pos
+    idx_col = index[:, None] if index.ndim else \
+        jnp.full((B, 1), index, jnp.int32)
+    idx_col = jnp.maximum(idx_col, 0)           # the idle-row sentinel
+    pos = idx_col + jnp.arange(K, dtype=jnp.int32)[None, :]
+    if cfg.use_mrope:
+        # text decode: all three M-RoPE components advance together
+        positions = jnp.broadcast_to(pos[None], (3, B, K))
+    else:
+        positions = pos
 
-    if tables is not None:      # the paged pool rides in the scan carry
-        real = jnp.broadcast_to(index >= 0, (B,))[:, None] \
-            & jnp.ones((1, K), bool)
-        if valid is not None:
-            real = real & (jnp.arange(K, dtype=jnp.int32)[None, :]
-                           < valid[:, None])
+    real = jnp.broadcast_to(index >= 0, (B,))[:, None] \
+        & jnp.ones((1, K), bool)
+    if valid is not None:
+        real = real & (jnp.arange(K, dtype=jnp.int32)[None, :]
+                       < valid[:, None])
 
-        def apply(sp, lp, lc, li, xc):
-            return _apply_block(lp, cfg, sp, xc, positions, "decode",
-                                cache=lc, index=index, tables=tables,
-                                valid=valid, layer=li, token_mask=real)
+    def apply(sp, lp, lc, li, xc):
+        return _apply_block(lp, cfg, sp, xc, positions, "decode",
+                            cache=lc, index=index, tables=tables,
+                            valid=valid, layer=li, token_mask=real)
 
-        x, new_cache = _run_carried(cfg, x, params, cache, apply)
-        return _logits(params, cfg, x), new_cache
-
-    # dense slot rows: scan over the per-layer caches
-    def run_stack(x, stacked, cache_stacked, spec_list):
-        def body(xc, inp):
-            layer_p, layer_c = inp
-            if not isinstance(layer_p, tuple):
-                layer_p = (layer_p,)
-                layer_c = (layer_c,)
-            new_caches = []
-            for sp, lp, lc in zip(spec_list, layer_p, layer_c):
-                xc, nc, _ = _apply_block(lp, cfg, sp, xc, positions,
-                                         "decode", cache=lc, index=index,
-                                         valid=valid)
-                new_caches.append(nc)
-            return xc, tuple(new_caches)
-
-        return jax.lax.scan(body, x, (stacked, cache_stacked))
-
-    new_cache: Params = {}
-    if "prefix" in params:
-        x, pc = run_stack(x, params["prefix"], cache["prefix"],
-                          (layer_specs(cfg)[0],))
-        new_cache["prefix"] = pc[0]
-    x, bc = run_stack(x, tuple(params["body"]), tuple(cache["body"]),
-                      _period_specs(cfg))
-    new_cache["body"] = bc
-    logits = _logits(params, cfg, x)
-    return logits, new_cache
+    x, new_cache = _run_carried(cfg, x, params, cache, apply)
+    return _logits(params, cfg, x), new_cache

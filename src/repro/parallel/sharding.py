@@ -4,7 +4,7 @@ Models annotate params and activations with *logical* axis names; a
 :class:`ShardingRules` object (active via :func:`use_sharding`) maps those
 names onto physical mesh axes.  Outside a sharding context every
 constraint is a no-op, so the same model code runs on 1 CPU device in
-tests and on the 512-chip production mesh in the dry-run.
+tests and sharded on a device mesh.
 """
 from __future__ import annotations
 
@@ -68,11 +68,9 @@ DEFAULT_RULES: Dict[str, Any] = {
 # serve — weights-stationary decode: pure TP over `model` (weights never
 # gathered; per-token collectives are tiny activation all-reduces), the
 # decode batch DP over (pod, data), and every KV-cache leaf over `data`:
-# dense rows / recurrent state on their batch dim, paged pools on the
-# page dim (each data shard owns a private sub-pool its block tables
-# address — see repro.serve.mesh).  Shared by the dry-run "serve" preset
-# (launch/dryrun.py) and the live serving mesh (serve/mesh.py) so the
-# compile-time capacity study and the runtime agree on the layout.
+# recurrent state on its batch dim, paged pools on the page dim (each
+# data shard owns a private sub-pool its block tables address — see
+# repro.serve.mesh).
 SERVE_RULES: Dict[str, Any] = {
     "batch": ("pod", "data"),
     "seq_sp": None,

@@ -1,35 +1,20 @@
-"""Cache layouts + block/paged KV-cache management for serving.
-
-The decode surface is ONE protocol: a :class:`CacheLayout` owns the
-physical cache pytree and answers, per layer,
-
-  * **init**     — build the cache leaves (slot rows or page pools);
-  * **write**    — where a request's prefilled KV/state lands
-    (``insert`` / ``insert_prefill``) and how decode steps address it
-    (``tables`` for paged, per-row indices for slots);
-  * **read**     — the kwargs a decode step needs (``step_kwargs``);
-  * **snapshot / restore** — copy-out / masked copy-back of the
-    RECURRENT leaves (mamba / xLSTM state), the rollback primitive
-    speculative decoding is built on.  Attention KV needs no rollback:
-    stale positions past a row's length are causally masked and
-    overwritten on the next write.
-
-Two implementations, both driven through
-:class:`repro.serve.session.DecodeSession`:
-
-``SlotLayout``
-    Dense rows: ``num_slots x max_len`` attention KV + per-slot
-    recurrent state (the PR-2 layout, kept as the ``layout="dense"``
-    baseline the fig14 benchmark measures the paged path against).
+"""The paged KV cache for serving: page pools, block tables, page
+accounting and the prefix cache.
 
 ``PagedLayout``
-    Per attention layer ONE ``(num_pages + 1, block_size, n_kv_heads,
-    head_dim)`` pool (``repro.models.lm.init_cache(..., pages=...)``;
-    the +1 is the null page), plus the host-side block tables the
-    gather-decode kernel reads.  A request's pages can live anywhere in
-    the pool — there is no per-slot ``max_len`` row, so a single
+    The one cache layout, driven through
+    :class:`repro.serve.session.DecodeSession`.  Per attention stack
+    ONE ``(layers, num_pages + 1, block_size, n_kv_heads, head_dim)``
+    pool (``repro.models.lm.init_cache(..., pages=...)``; the +1 is the
+    null page; latent stacks get a latent pool), plus the host-side
+    block tables the gather-decode kernel reads.  A request's pages can
+    live anywhere in the pool — there is no per-slot row, so a single
     request may use the entire pool.  Recurrent-layer state (O(1) per
-    request) stays in dense per-slot rows.
+    request) stays in per-slot rows.  ``snapshot`` / ``restore`` copy
+    out and masked-copy back the RECURRENT leaves (mamba / xLSTM
+    state), the rollback primitive speculative decoding is built on;
+    attention KV needs no rollback: stale positions past a row's length
+    are causally masked and overwritten on the next write.
 
     **Prefix sharing (copy-on-admit):** after a request prefills, its
     fully-filled prompt pages are registered in a prefix cache keyed by
@@ -230,20 +215,6 @@ class BlockManager:
         self.high_water = max(self.high_water, self.used_blocks)
         return got
 
-    def allocate(self, rid, n_tokens: int) -> List[int]:
-        """Reserve AND materialize pages for `n_tokens` (eager; the
-        dense pool path).  Raises if rid is live or over budget."""
-        if rid in self._tables:
-            raise ValueError(f"request {rid!r} already holds blocks")
-        need = blocks_for(n_tokens, self.block_size)
-        if not self.can_allocate(n_tokens):
-            raise RuntimeError(
-                f"out of cache blocks: need {need}, "
-                f"available {self.available_blocks}")
-        self._tables[rid] = []
-        self._pending[rid] = need
-        return self._claim(rid, need)
-
     def reserve(self, rid, n_tokens: int,
                 shared: Sequence[int] = ()) -> None:
         """Budget `n_tokens` for `rid`, mapping `shared` pages (already
@@ -326,19 +297,14 @@ class BlockManager:
 
 
 # ---------------------------------------------------------------------------
-# CacheLayout protocol
+# cache leaves
 # ---------------------------------------------------------------------------
 
 
 def _leaf_is_paged(axes_leaf) -> bool:
-    return isinstance(axes_leaf, tuple) and "pages" in axes_leaf
-
-
-def _leaf_is_kv(axes_leaf) -> bool:
-    """Attention KV leaves (either layout); everything else but the
+    """Page-pool leaves (K/V or latent); everything else but the
     counters is the recurrent state snapshot/restore copies."""
-    return isinstance(axes_leaf, tuple) and \
-        ("pages" in axes_leaf or "kv_seq" in axes_leaf)
+    return isinstance(axes_leaf, tuple) and "pages" in axes_leaf
 
 
 def _leaf_is_counter(axes_leaf) -> bool:
@@ -368,82 +334,6 @@ def _restore_rec(cache, snap, rec_mask, rows):
         else:
             out.append(leaf)
     return jax.tree.unflatten(tree, out)
-
-
-class CacheLayout:
-    """Family-agnostic cache protocol the serving stack decodes through.
-
-    A layout owns the physical cache pytree and implements, per layer
-    leaf, the five operations :class:`repro.serve.session.
-    DecodeSession` is written against:
-
-    ==========  =========================================================
-    init        build the cache leaves (``lm.init_cache``, dense or
-                ``pages=``)
-    write       land prefilled KV/state (``insert`` / ``insert_prefill``)
-                and route decode-step writes (slot rows / block tables)
-    read        ``step_kwargs()`` — the extra arrays one decode step
-                needs (``tables`` for paged, nothing for slots)
-    snapshot    copy out the recurrent leaves (mamba / xLSTM state)
-    restore     masked copy-back per slot — the speculative-decoding
-                rollback primitive (attention KV never rolls back: stale
-                positions are causally masked and overwritten)
-    ==========  =========================================================
-
-    Slot bookkeeping (`admit` / `release` / `slot_of`) is shared here;
-    page accounting is the paged subclass's :class:`BlockManager`.
-    """
-
-    cfg: ModelConfig
-    num_slots: int
-    cache: Any
-    rec_mask: Tuple[bool, ...]
-
-    def _init_slots(self, num_slots: int) -> None:
-        self.num_slots = num_slots
-        self._free_slots = list(range(num_slots))
-        self._slot_of: Dict[Any, int] = {}
-
-    @property
-    def free_slots(self) -> int:
-        """Decode slots not currently assigned to a request."""
-        return len(self._free_slots)
-
-    def slot_of(self, rid) -> int:
-        """The decode-batch row assigned to ``rid``."""
-        return self._slot_of[rid]
-
-    @property
-    def has_recurrent(self) -> bool:
-        """True when the stack carries per-slot recurrent state (hybrid
-        / ssm families) — the leaves snapshot/restore operates on."""
-        return any(self.rec_mask)
-
-    @property
-    def supports_row_subset(self) -> bool:
-        """True when a decode step may cover any subset of rows (no
-        cache leaf is indexed by slot) — what lets the scheduler group
-        ragged rows by gather width."""
-        return False
-
-    def step_kwargs(self, width: Optional[int] = None,
-                    rows: Optional[np.ndarray] = None) -> Dict[str, Any]:
-        """Extra per-step arrays for :func:`repro.models.lm.lm_decode`."""
-        return {}
-
-    def snapshot(self) -> Tuple[jax.Array, ...]:
-        """Copy of the recurrent leaves (empty for attention-only
-        stacks, where rollback is free)."""
-        flat = jax.tree.leaves(self.cache)
-        return tuple(jnp.array(x, copy=True)
-                     for x, m in zip(flat, self.rec_mask) if m)
-
-    def restore(self, snap: Tuple[jax.Array, ...], rows) -> None:
-        """Roll slots with ``rows[b] == True`` back to ``snap``."""
-        if not snap:
-            return
-        self.cache = _restore_rec(self.cache, snap, self.rec_mask,
-                                  jnp.asarray(np.asarray(rows, bool)))
 
 
 # ---------------------------------------------------------------------------
@@ -666,15 +556,14 @@ def _insert_tree_paged(pool, paged_mask, src, page_ids, offsets, slot):
     return jax.tree.unflatten(tree, out)
 
 
-class PagedLayout(CacheLayout):
+class PagedLayout:
     """Paged decode cache: shared page pools + per-slot block tables.
 
     ``num_slots`` bounds the decode batch width (and the number of
     recurrent-state rows); memory capacity is ``num_pages *
     block_size`` tokens shared by every request.  ``max_seq`` caps a
     single request (it sizes the block-table width) and defaults to the
-    whole pool — the per-slot ``max_len`` ceiling of the dense layout
-    is gone.  With ``pin_prefix=True`` registered prompt pages stay
+    whole pool.  With ``pin_prefix=True`` registered prompt pages stay
     resident after their holders release (reclaimed oldest-first under
     pressure).
 
@@ -724,11 +613,13 @@ class PagedLayout(CacheLayout):
                                 for a in _axes_leaves(axes))
         self.counter_mask = tuple(_leaf_is_counter(a)
                                   for a in _axes_leaves(axes))
-        self.rec_mask = tuple(not (_leaf_is_kv(a) or _leaf_is_counter(a))
-                              for a in _axes_leaves(axes))
+        self.rec_mask = tuple(not (p or c) for p, c in
+                              zip(self.paged_mask, self.counter_mask))
         if placer is not None:
             self.cache = placer(self.cache, axes)
-        self._init_slots(num_slots)
+        self.num_slots = num_slots
+        self._free_slots = list(range(num_slots))
+        self._slot_of: Dict[Any, int] = {}
         self.tables = np.empty((num_slots, self.max_blocks_per_seq),
                                np.int32)
         for s in range(num_slots):
@@ -739,6 +630,36 @@ class PagedLayout(CacheLayout):
         # per-shard high waters would overstate it when shards peak at
         # different times
         self._hw_total = 0
+
+    # -- slots -------------------------------------------------------------
+    @property
+    def free_slots(self) -> int:
+        """Decode slots not currently assigned to a request."""
+        return len(self._free_slots)
+
+    def slot_of(self, rid) -> int:
+        """The decode-batch row assigned to ``rid``."""
+        return self._slot_of[rid]
+
+    @property
+    def has_recurrent(self) -> bool:
+        """True when the stack carries per-slot recurrent state (hybrid
+        / ssm families) — the leaves snapshot/restore operates on."""
+        return any(self.rec_mask)
+
+    def snapshot(self) -> Tuple[jax.Array, ...]:
+        """Copy of the recurrent leaves (empty for attention-only
+        stacks, where rollback is free)."""
+        flat = jax.tree.leaves(self.cache)
+        return tuple(jnp.array(x, copy=True)
+                     for x, m in zip(flat, self.rec_mask) if m)
+
+    def restore(self, snap: Tuple[jax.Array, ...], rows) -> None:
+        """Roll slots with ``rows[b] == True`` back to ``snap``."""
+        if not snap:
+            return
+        self.cache = _restore_rec(self.cache, snap, self.rec_mask,
+                                  jnp.asarray(np.asarray(rows, bool)))
 
     # -- shard routing -----------------------------------------------------
     def shard_of_slot(self, slot: int) -> int:
@@ -836,13 +757,13 @@ class PagedLayout(CacheLayout):
         # shard's batch partition)
         return not self.has_recurrent and self.data_shards == 1
 
-    def step_kwargs(self, width: Optional[int] = None,
-                    rows: Optional[np.ndarray] = None) -> Dict[str, Any]:
-        """Page tables (optionally width-clipped / row-subset) for the
+    def step_tables(self, width: Optional[int] = None,
+                    rows: Optional[np.ndarray] = None) -> jax.Array:
+        """Block tables (optionally width-clipped / row-subset) for the
         decode dispatch."""
         W = width if width is not None else self.max_blocks_per_seq
         tables = self.tables if rows is None else self.tables[rows]
-        return {"tables": jnp.asarray(tables[:, :W])}
+        return jnp.asarray(tables[:, :W])
 
     def can_admit(self, n_tokens: int,
                   shared_pages: Sequence[int] = ()) -> bool:
@@ -922,7 +843,7 @@ class PagedLayout(CacheLayout):
             self._hw_total = max(self._hw_total, used)
 
     def insert_prefill(self, rid, prefill_cache, prompt_len: int) -> None:
-        """Scatter a (batch=1) dense prefill cache into the pool.
+        """Scatter a (batch=1) one-shot prefill cache into the pool.
 
         The one-shot path for recurrent/hybrid families: attention
         leaves scatter token s into (table[s // bs], s % bs); recurrent
@@ -1016,127 +937,12 @@ class PagedLayout(CacheLayout):
 
 
 def _first_kv_len(prefill_cache, paged_mask) -> Optional[int]:
-    """Sequence length of the first attention leaf of a dense (batch=1)
-    prefill cache: leaves are (stack, 1, S, Hkv, D).  None for pure-
-    recurrent stacks (xLSTM), whose cache is all per-slot state rows."""
+    """Sequence length of the first attention leaf of a (batch=1)
+    one-shot prefill cache: leaves are (stack, 1, S, Hkv, D).  None for
+    pure-recurrent stacks (xLSTM), whose cache is all per-slot state
+    rows."""
     for leaf, paged in zip(jax.tree.leaves(prefill_cache),
                            paged_mask):
         if paged:
             return int(leaf.shape[2])
     return None
-
-
-# ---------------------------------------------------------------------------
-# dense slot layout (the PR-2 baseline, kept for layout="dense")
-# ---------------------------------------------------------------------------
-
-
-def _insert_row(dst: jax.Array, src: jax.Array, slot) -> jax.Array:
-    """Write `src` (leading (layers, 1, ...)) into pool row `slot`.
-
-    Every cache leaf is (layers, batch, *state); attention leaves carry
-    a kv_seq axis shorter than the pool's max_len at prefill time — pad
-    with zeros so the whole row is overwritten (slot reuse must not
-    leak the previous occupant's cache).
-    """
-    if src.shape[2:] != dst.shape[2:]:
-        pad = [(0, 0), (0, 0)] + [(0, d - s)
-                                  for d, s in zip(dst.shape[2:], src.shape[2:])]
-        src = jnp.pad(src, pad)
-    start = (0, jnp.asarray(slot, jnp.int32)) + (0,) * (dst.ndim - 2)
-    return jax.lax.dynamic_update_slice(dst, src.astype(dst.dtype), start)
-
-
-# the pool is donated: the caller always rebinds CachePool.cache to the
-# result, so the update happens in place instead of copying the whole
-# preallocated pool on every request admission
-@partial(jax.jit, donate_argnums=(0,))
-def _insert_tree(pool, src, slot):
-    return jax.tree.map(lambda d, s: _insert_row(d, s, slot), pool, src)
-
-
-@partial(jax.jit, donate_argnums=(0,))
-def _insert_tree_batch(pool, src):
-    return jax.tree.map(lambda d, s: _insert_row(d, s, 0), pool, src)
-
-
-class SlotLayout(CacheLayout):
-    """One preallocated dense decode cache shared by all requests.
-
-    ``cache`` holds `num_slots` rows of `max_len` tokens (allocated once
-    at construction via :func:`repro.models.lm.init_cache`); slot and
-    page lifetime are managed here so the scheduler only deals in
-    request ids.  Pages are bookkeeping only — a request's cache is its
-    contiguous slot row, which is what the paged layout replaces.
-    """
-
-    def __init__(self, cfg: ModelConfig, num_slots: int, max_len: int,
-                 block_size: int = 16, num_blocks: Optional[int] = None,
-                 placer=None):
-        self.cfg = cfg
-        self.max_len = max_len
-        self.blocks = BlockManager(
-            num_blocks if num_blocks is not None
-            else num_slots * blocks_for(max_len, block_size),
-            block_size)
-        self.cache, axes = lm.init_cache(cfg, num_slots, max_len)
-        self.rec_mask = tuple(not _leaf_is_kv(a)
-                              for a in _axes_leaves(axes))
-        if placer is not None:
-            self.cache = placer(self.cache, axes)
-        self._init_slots(num_slots)
-
-    def can_admit(self, n_tokens: int) -> bool:
-        """Room for a request reserving `n_tokens` (prompt + max new)?"""
-        return bool(self._free_slots) and n_tokens <= self.max_len \
-            and self.blocks.can_allocate(n_tokens)
-
-    def admit(self, rid, n_tokens: int,
-              slot: Optional[int] = None) -> int:
-        """Claim a slot + pages for `rid`; returns the slot index.
-        ``slot`` forces a specific one (drafter mirror pools must stay
-        row-aligned with the target's)."""
-        if not self._free_slots:
-            raise RuntimeError("no free cache slots")
-        if n_tokens > self.max_len:
-            raise ValueError(
-                f"request needs {n_tokens} tokens > pool max_len "
-                f"{self.max_len}")
-        self.blocks.allocate(rid, n_tokens)
-        if slot is None:
-            slot = self._free_slots.pop()
-        else:
-            self._free_slots.remove(slot)
-        self._slot_of[rid] = slot
-        return slot
-
-    def insert(self, rid, prefill_cache) -> None:
-        """Overwrite `rid`'s slot row with a (batch=1) prefilled cache."""
-        self.cache = _insert_tree(self.cache, prefill_cache,
-                                  jnp.int32(self._slot_of[rid]))
-
-    def insert_batch(self, prefill_cache) -> None:
-        """Overwrite ALL slot rows with a (batch=num_slots) prefilled
-        cache — the engine path, where one uniform-length batch fills
-        the whole pool at once."""
-        B = jax.tree.leaves(prefill_cache)[0].shape[1]
-        assert B == self.num_slots, (B, self.num_slots)
-        self.cache = _insert_tree_batch(self.cache, prefill_cache)
-
-    def release(self, rid) -> int:
-        """Free `rid`'s slot + pages; returns the freed slot index."""
-        slot = self._slot_of.pop(rid)
-        self._free_slots.append(slot)
-        self.blocks.free(rid)
-        return slot
-
-    def as_dict(self) -> Dict[str, int]:
-        """Counters for the ``[serve] pool`` summary line."""
-        return {"num_slots": self.num_slots, "max_len": self.max_len,
-                "free_slots": self.free_slots, **self.blocks.as_dict()}
-
-
-
-# legacy names (PR-2/PR-3): the pools ARE the layouts now
-CachePool = SlotLayout
-PagedCachePool = PagedLayout

@@ -2,11 +2,11 @@
 
 Training already runs on a device mesh; this module puts the SERVING
 stack on one.  The existing :class:`repro.serve.session.DecodeSession`
-/ :class:`repro.serve.kv_cache.CacheLayout` machinery is reused
+/ :class:`repro.serve.kv_cache.PagedLayout` machinery is reused
 unchanged — the mesh runtime only decides *where things live* and *who
 decides*:
 
-**Axis layout** (the dry-run "serve" preset,
+**Axis layout** (the "serve" preset,
 :data:`repro.parallel.sharding.SERVE_RULES`):
 
   * **weights** — stationary, tensor-parallel over ``model`` (vocab /
@@ -14,10 +14,10 @@ decides*:
     are tiny activation all-reduces;
   * **decode batch** — the ``num_slots`` rows split over ``data``:
     tokens, write indices, block tables, logits;
-  * **cache leaves** — every one over ``data``: dense KV rows and
-    recurrent state on their batch dim, paged pools on the PAGE dim.
-    The paged pool becomes ``data``-many private sub-pools, each with
-    its own null page, each accounted by a host-local
+  * **cache leaves** — every one over ``data``: recurrent state on
+    its batch dim, paged pools on the PAGE dim.  The paged pool
+    becomes ``data``-many private sub-pools, each with its own null
+    page, each accounted by a host-local
     :class:`repro.serve.kv_cache.PageShard`; block tables hold global
     page ids and the shard_map gather dispatch
     (:func:`repro.kernels.ops.paged_attention`) rebases them
@@ -68,7 +68,7 @@ from repro.launch import specs as specs_lib
 from repro.models import lm
 from repro.parallel.sharding import (serve_rules, tree_shardings,
                                      use_sharding)
-from repro.serve.kv_cache import PagedLayout, SlotLayout, blocks_for
+from repro.serve.kv_cache import PagedLayout, blocks_for
 from repro.serve.scheduler import Request, Scheduler
 from repro.serve.session import DecodeSession, _draft_unroll
 from repro.serve.telemetry import stats_snapshot
@@ -497,13 +497,13 @@ def make_plan_channel(timeout_s: float = 60.0) -> PlanChannel:
 # sharded decode session
 # ---------------------------------------------------------------------------
 
-# The serving-mesh rule set the mesh jits trace under.  Two runtime
-# overrides on the dry-run preset: dense KV rows shard their HEADS
-# over `model` instead of the sequence dim (the per-row decode scatter
-# into the seq dim must stay shard-local), and recurrent STATE rows
-# stay whole per slot (splitting the state contraction over `model`
-# would reorder f32 accumulation and cost mesh-vs-single-device token
-# identity for hybrid/ssm stacks).
+# The serving-mesh rule set the mesh jits trace under.  Two overrides
+# on the preset: a one-shot prefill's K/V shards its HEADS over
+# `model`, as the page pools it is scattered into do, not its
+# sequence dim; and recurrent STATE rows stay whole per slot
+# (splitting the state contraction over `model` would reorder f32
+# accumulation and cost mesh-vs-single-device token identity for
+# hybrid/ssm stacks).
 MESH_SERVE_RULES = serve_rules(kv_seq=None, state=None)
 
 
@@ -515,13 +515,6 @@ MESH_SERVE_RULES = serve_rules(kv_seq=None, state=None)
 # paged-gather dispatch — into the other's lowering.  A separate
 # function object keyed on the mesh guarantees every mesh trace happens
 # inside the mesh's sharding context, and two meshes never alias.
-
-
-@partial(jax.jit, static_argnums=(1, 2), donate_argnums=(4,))
-def _mesh_step_fn(params, cfg, mesh, tokens, cache, index, valid):
-    with use_sharding(mesh, **MESH_SERVE_RULES):
-        return lm.lm_decode(params, cfg, tokens, cache, index,
-                            valid=valid)
 
 
 @partial(jax.jit, static_argnums=(1, 2), donate_argnums=(4,))
@@ -550,13 +543,6 @@ def _mesh_chunk_fn(params, cfg, mesh, toks, cache, tables, hist, plen,
 
 
 @partial(jax.jit, static_argnums=(1, 2, 7), donate_argnums=(4,))
-def _mesh_draft_fn(params, cfg, mesh, tok0, cache, index, valid, steps):
-    with use_sharding(mesh, **MESH_SERVE_RULES):
-        return _draft_unroll(params, cfg, tok0, cache, index, valid,
-                             steps, None)
-
-
-@partial(jax.jit, static_argnums=(1, 2, 7), donate_argnums=(4,))
 def _mesh_draft_tables_fn(params, cfg, mesh, tok0, cache, index, valid,
                           steps, tables):
     with use_sharding(mesh, **MESH_SERVE_RULES):
@@ -575,8 +561,7 @@ class MeshDecodeSession(DecodeSession):
     the paged-gather dispatch in ``kernels/ops.py`` lowers to its
     shard_map form.  ``params`` is the RAW (host / single-device)
     tree; the session places it — and re-places on ``set_params`` hot
-    swaps, skipping the transfer when handed the same object (the
-    engine calls ``set_params`` before every generate).
+    swaps, skipping the transfer when handed the same object.
     """
 
     def __init__(self, cfg: ModelConfig, params, layout, mesh, rules,
@@ -603,14 +588,8 @@ class MeshDecodeSession(DecodeSession):
     def _call_chunk(self, params, cfg, *args):
         return _mesh_chunk_fn(params, cfg, self.mesh, *args)
 
-    def _call_step(self, params, cfg, *args):
-        return _mesh_step_fn(params, cfg, self.mesh, *args)
-
     def _call_step_tables(self, params, cfg, *args):
         return _mesh_step_tables_fn(params, cfg, self.mesh, *args)
-
-    def _call_draft(self, params, cfg, *args):
-        return _mesh_draft_fn(params, cfg, self.mesh, *args)
 
     def _call_draft_tables(self, params, cfg, *args):
         return _mesh_draft_tables_fn(params, cfg, self.mesh, *args)
@@ -648,21 +627,6 @@ def param_placer(mesh, rules, cfg: ModelConfig):
         return jax.device_put(
             params, tree_shardings(mesh, axes, params, **rules))
     return place
-
-
-def make_engine_session(cfg: ModelConfig, params, mesh, batch: int,
-                        max_len: int) -> MeshDecodeSession:
-    """A mesh-sharded SlotLayout session for the batch Engine path."""
-    rules = MESH_SERVE_RULES
-    data, _ = mesh_axis_sizes(mesh)
-    if batch % data:
-        raise ValueError(
-            f"engine batch {batch} must be divisible by the mesh data "
-            f"axis ({data})")
-    layout = SlotLayout(cfg, batch, max_len,
-                        placer=cache_placer(mesh, rules))
-    return MeshDecodeSession(cfg, params, layout, mesh, rules,
-                             param_placer(mesh, rules, cfg))
 
 
 # ---------------------------------------------------------------------------
@@ -732,20 +696,14 @@ class MeshScheduler(Scheduler):
         self._group_decode = False
 
     # -- construction hooks --------------------------------------------------
-    def _make_layout(self, cfg: ModelConfig):
+    def _make_layout(self, cfg: ModelConfig) -> PagedLayout:
         g = self._geom
-        placer = cache_placer(self.mesh, self.rules)
-        if self.paged:
-            return PagedLayout(cfg, g["num_slots"], g["n_blocks"],
-                               block_size=g["block_size"],
-                               max_seq=g["max_seq"],
-                               pin_prefix=g["pin_prefix"],
-                               data_shards=self.data_shards,
-                               placer=placer)
-        return SlotLayout(cfg, g["num_slots"], g["max_len"],
-                          block_size=g["block_size"],
-                          num_blocks=g["num_blocks"],
-                          placer=placer)
+        return PagedLayout(cfg, g["num_slots"], g["n_blocks"],
+                           block_size=g["block_size"],
+                           max_seq=g["max_seq"],
+                           pin_prefix=g["pin_prefix"],
+                           data_shards=self.data_shards,
+                           placer=cache_placer(self.mesh, self.rules))
 
     def _make_session(self, cfg: ModelConfig, params,
                       layout) -> DecodeSession:
@@ -755,8 +713,7 @@ class MeshScheduler(Scheduler):
 
     # -- admission (shard-aligned drafter) -----------------------------------
     def _can_admit_head(self) -> bool:
-        if not self.paged or self.draft is None \
-                or self.data_shards == 1:
+        if self.draft is None or self.data_shards == 1:
             return super()._can_admit_head()
         req = self.queue[0]
         total = req.prompt_len + req.max_new

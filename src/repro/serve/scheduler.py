@@ -2,11 +2,9 @@
 
 The serving analogue of ``core/tournament.py``'s training orchestrator:
 a request queue in front of a slot-based decode batch backed by ONE
-preallocated :class:`repro.serve.kv_cache.PagedLayout` (or the PR-2
-dense :class:`~repro.serve.kv_cache.SlotLayout` with
-``layout="dense"``, kept as the benchmark baseline).  ALL model calls
-go through one :class:`repro.serve.session.DecodeSession` per set of
-weights — the scheduler never picks a decode entry point by layout.
+preallocated :class:`repro.serve.kv_cache.PagedLayout`.  ALL model
+calls go through one :class:`repro.serve.session.DecodeSession` per
+set of weights.
 
 Per scheduler step:
 
@@ -20,10 +18,10 @@ Per scheduler step:
      then swaps and resumes — strict per-request weight reproducibility.
   2. *admission* — pop queued requests while a slot AND a full
      token-budget page reservation (prompt + max new tokens) are
-     available.  On the paged layout a prompt whose prefix is already
-     resident (another live request's registered prompt pages) maps
-     those pages read-only into its block table and skips their
-     prefill compute entirely (copy-on-admit prefix sharing; with
+     available.  A prompt whose prefix is already resident (another
+     live request's registered prompt pages) maps those pages
+     read-only into its block table and skips their prefill compute
+     entirely (copy-on-admit prefix sharing; with
      ``pin_prefix=True`` registered prompt pages additionally survive
      idle periods in an eviction-priority tier).
   3. *chunked prefill* — attention-only stacks prefill in
@@ -54,11 +52,6 @@ Per scheduler step:
   5. *completion* — requests hitting EOS or their token budget free
      their slot + page refs immediately; the batch never stalls on its
      slowest member.
-
-``policy="static"`` degrades admission to classic static batching
-(admit only when the pool is empty) — the baseline the fig14 benchmark
-compares against, sharing every compiled kernel with the continuous
-path.
 """
 from __future__ import annotations
 
@@ -72,7 +65,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import lm
-from repro.serve.kv_cache import PagedLayout, SlotLayout, blocks_for
+from repro.serve.kv_cache import PagedLayout, blocks_for
 from repro.serve.metrics import ServeStats
 from repro.serve.sampling import sample_rows, seed_words
 from repro.serve.session import DecodeSession
@@ -150,8 +143,6 @@ class Scheduler:
                  max_len: int = 1024, block_size: int = 16,
                  num_blocks: Optional[int] = None,
                  max_seq: Optional[int] = None,
-                 layout: str = "paged",
-                 policy: str = "continuous",
                  prefill_chunk: int = 0,
                  prefix_sharing: bool = True,
                  pin_prefix: bool = False,
@@ -167,10 +158,6 @@ class Scheduler:
                  telemetry: bool = True,
                  trace_capacity: int = 8192,
                  journal=None, faults=None, arena=None):
-        if policy not in ("continuous", "static"):
-            raise ValueError(f"unknown policy {policy!r}")
-        if layout not in ("paged", "dense"):
-            raise ValueError(f"unknown layout {layout!r}")
         if swap_mode not in ("immediate", "drain"):
             raise ValueError(f"unknown swap_mode {swap_mode!r}")
         if cfg.family == "vlm":
@@ -181,9 +168,6 @@ class Scheduler:
             raise ValueError("spec_tokens > 0 needs draft_params "
                              "(the population drafter)")
         self.cfg = cfg
-        self.policy = policy
-        self.layout = layout
-        self.paged = layout == "paged"
         self.prefill_chunk = int(prefill_chunk)
         self.max_prefills_per_step = max_prefills_per_step
         self.min_prefill_bucket = min_prefill_bucket
@@ -204,19 +188,15 @@ class Scheduler:
         if draft_params is not None and self.draft_cfg is not cfg:
             from repro.serve.registry import check_draft_compat
             check_draft_compat(cfg, self.draft_cfg)
-        if max_seq is not None and max_seq != max_len and not self.paged:
-            raise ValueError("layout='dense' caps requests at max_len")
         n_blocks = num_blocks if num_blocks is not None \
             else num_slots * blocks_for(max_len, block_size)
         # geometry the layout factory reads (subclasses reuse it when
         # building mesh-sharded pools)
-        self._geom = {"num_slots": num_slots, "max_len": max_len,
-                      "block_size": block_size, "n_blocks": n_blocks,
-                      "num_blocks": num_blocks,
-                      "max_seq": max_seq or max_len,
+        self._geom = {"num_slots": num_slots, "block_size": block_size,
+                      "n_blocks": n_blocks, "max_seq": max_seq or max_len,
                       "pin_prefix": pin_prefix}
         self.pool = self._make_layout(cfg)
-        self.max_seq = self.pool.max_seq if self.paged else max_len
+        self.max_seq = self.pool.max_seq
         # ALL model calls go through sessions; the drafter is a second
         # session over its own (mirror-geometry) pool — same decode API
         self.session = self._make_session(cfg, params, self.pool)
@@ -230,18 +210,17 @@ class Scheduler:
         # state, so those families prefill at exact prompt length
         # (one compile per distinct length instead of per bucket) —
         # and one-shot: chunked prefill needs mid-prompt resume, which
-        # only the paged attention path supports.
+        # only attention stacks support.
         self._can_pad = all(s.kind in lm.ATTENTION_KINDS
                             for s in lm.layer_specs(cfg))
         self._draft_can_pad = all(
             s.kind in lm.ATTENTION_KINDS
             for s in lm.layer_specs(self.draft_cfg))
-        self._chunked = self.paged and self._can_pad
-        self.prefix_sharing = bool(prefix_sharing) and self._chunked
+        self.prefix_sharing = bool(prefix_sharing) and self._can_pad
         # ragged gather-width grouping only pays on the CPU oracle (the
         # Pallas kernel already skips per-row via pl.when) and needs
-        # every cache leaf slot-free (attention-only paged stacks)
-        self._group_decode = self.paged and self.pool.supports_row_subset \
+        # every cache leaf slot-free (attention-only stacks)
+        self._group_decode = self.pool.supports_row_subset \
             and jax.default_backend() != "tpu"
         self.queue: deque[Request] = deque()
         self.active: Dict[Any, _Active] = {}
@@ -253,11 +232,9 @@ class Scheduler:
         self._pending_draft: List[Request] = []
         self._by_slot: Dict[int, _Active] = {}
         self._next_token = np.zeros((num_slots,), np.int32)
-        # paged decode uses -1 as the "row holds no request" sentinel
-        # (KV writes route to the null page); dense keeps 0 (the row is
-        # the slot's own, writes are harmless)
-        self._idle_index = -1 if self.paged else 0
-        self._index = np.full((num_slots,), self._idle_index, np.int32)
+        # -1 is the "row holds no request" sentinel (KV writes route to
+        # the null page)
+        self._index = np.full((num_slots,), -1, np.int32)
         # per-row speculative depth (spec_adapt): proposals offered next
         # round for the request in each slot, adapted from its accept
         # history within [1, spec_tokens]
@@ -266,7 +243,7 @@ class Scheduler:
         self.spec_k_by_rid: Dict[Any, int] = {}
         self.results: Dict[Any, np.ndarray] = {}
         self.stats = ServeStats(slots=num_slots)
-        if self.paged and cfg.mla is not None:
+        if cfg.mla is not None:
             self.stats.latent_cache_bytes_per_token = \
                 self.pool.bytes_per_token
         # request tracing + phase attribution + profiler window;
@@ -298,16 +275,12 @@ class Scheduler:
         self._step_count = 0
 
     # -- construction hooks (the mesh scheduler overrides these) ------------
-    def _make_layout(self, cfg: ModelConfig):
+    def _make_layout(self, cfg: ModelConfig) -> PagedLayout:
         g = self._geom
-        if self.paged:
-            return PagedLayout(cfg, g["num_slots"], g["n_blocks"],
-                               block_size=g["block_size"],
-                               max_seq=g["max_seq"],
-                               pin_prefix=g["pin_prefix"])
-        return SlotLayout(cfg, g["num_slots"], g["max_len"],
-                          block_size=g["block_size"],
-                          num_blocks=g["num_blocks"])
+        return PagedLayout(cfg, g["num_slots"], g["n_blocks"],
+                           block_size=g["block_size"],
+                           max_seq=g["max_seq"],
+                           pin_prefix=g["pin_prefix"])
 
     def _make_session(self, cfg: ModelConfig, params,
                       layout) -> DecodeSession:
@@ -388,8 +361,6 @@ class Scheduler:
         return self._pool_can_admit(self.pool, total, head=True)
 
     def _pool_can_admit(self, pool, total: int, head: bool = False) -> bool:
-        if not self.paged:
-            return pool.can_admit(total)
         if not pool.free_slots:         # skip prefix hashing when full
             return False
         shared = ()
@@ -413,15 +384,6 @@ class Scheduler:
         now = time.perf_counter()
         self.telemetry.req_span(req.rid, "queued",
                                 getattr(req, "_submit_t", None), now)
-        if not self.paged:
-            slot = self.pool.admit(req.rid, total)
-            self._admit_draft(req, slot, total)
-            act = _Active(req=req, slot=slot, submit_t=getattr(
-                req, "_submit_t", time.perf_counter()))
-            self._spec_k[slot] = max(self.spec_tokens, 1)
-            self._pending_onepass.append(act)
-            self.telemetry.req_instant(req.rid, "admit", t=now, slot=slot)
-            return
         head = getattr(self, "_head_share", None)
         shared = head[1] if head is not None and head[0] == req.rid \
             else None
@@ -436,7 +398,7 @@ class Scheduler:
         self._spec_k[slot] = max(self.spec_tokens, 1)
         self.telemetry.req_instant(req.rid, "admit", t=now, slot=slot,
                                    shared_prefix_tokens=shared_len)
-        if self._chunked:
+        if self._can_pad:
             # chunk slices run in _prefill_step, interleaved with decode
             self.prefilling[req.rid] = act
         else:
@@ -448,10 +410,7 @@ class Scheduler:
         one-shot prompt prefill is deferred with the target's."""
         if self.draft is None:
             return
-        if self.paged:
-            d_slot, _ = self.draft.layout.admit(req.rid, total, slot=slot)
-        else:
-            d_slot = self.draft.layout.admit(req.rid, total, slot=slot)
+        d_slot, _ = self.draft.layout.admit(req.rid, total, slot=slot)
         assert d_slot == slot, (d_slot, slot)
         self._pending_draft.append(req)
 
@@ -462,21 +421,7 @@ class Scheduler:
         with self.telemetry.span("prefill.dispatch"):
             self.draft.prefill(req.rid, req.prompt, bucket=bucket)
 
-    def _prefill_dense(self, act: _Active) -> None:
-        req = act.req
-        P = req.prompt_len
-        bucket = self._bucket(P)
-        t0 = time.perf_counter()
-        with self.telemetry.span("prefill.dispatch"):
-            last = self.session.prefill(req.rid, req.prompt, bucket=bucket)
-        self.telemetry.req_span(req.rid, "prefill", t0, time.perf_counter(),
-                                tokens=P, bucket=bucket)
-        self.stats.prefills += 1
-        self.stats.prefill_tokens += P
-        self.stats.padded_prefill_tokens += bucket
-        self._start_decoding(act, last)
-
-    def _prefill_onepass_paged(self, act: _Active) -> None:
+    def _prefill_onepass(self, act: _Active) -> None:
         """Exact-length one-shot prefill + page scatter (recurrent /
         hybrid families: their state cannot resume mid-prompt)."""
         req = act.req
@@ -628,7 +573,7 @@ class Scheduler:
         del self.active[rid]
         del self._by_slot[slot]
         self._next_token[slot] = 0
-        self._index[slot] = self._idle_index
+        self._index[slot] = -1
 
     # -- cancellation / load shedding ---------------------------------------
     def cancel(self, rid) -> bool:
@@ -690,7 +635,7 @@ class Scheduler:
             self.prefilling.pop(rid, None)
             self._by_slot.pop(slot, None)
             self._next_token[slot] = 0
-            self._index[slot] = self._idle_index
+            self._index[slot] = -1
         if self._head_share is not None and self._head_share[0] == rid:
             self._head_share = None
         kind = "shed" if reason == "deadline" else "cancel"
@@ -712,9 +657,8 @@ class Scheduler:
         its own weights: draft tokens are only proposals, verified
         against the new target before acceptance."""
         self.session.set_params(params)
-        if self.paged:
-            self.pool.invalidate_prefix()
-            self._head_share = None
+        self.pool.invalidate_prefix()
+        self._head_share = None
         self.stats.hot_swaps += 1
         self.telemetry.event("hot_swap", step=self._step_count,
                              swaps=self.stats.hot_swaps)
@@ -840,22 +784,15 @@ class Scheduler:
         (host accounting only); returns the admitted rids in order —
         the decision record a mesh broadcasts."""
         admitted: List[Any] = []
-        in_flight = bool(self.active or self.prefilling)
         if self.draining:
             return admitted
         if self.faults is not None \
                 and self.faults.admission_blocked(self._step_count):
             return admitted       # injected pool exhaustion (oom@step)
-        if self.policy == "static":
-            if not in_flight:
-                while self.queue and self._can_admit_head():
-                    admitted.append(self.queue[0].rid)
-                    self._admit(self.queue.popleft())
-        else:
-            while (len(admitted) < self.max_prefills_per_step
-                   and self.queue and self._can_admit_head()):
-                admitted.append(self.queue[0].rid)
-                self._admit(self.queue.popleft())
+        while (len(admitted) < self.max_prefills_per_step
+               and self.queue and self._can_admit_head()):
+            admitted.append(self.queue[0].rid)
+            self._admit(self.queue.popleft())
         return admitted
 
     def _prefill_phase(self) -> None:
@@ -865,10 +802,7 @@ class Scheduler:
             self._prefill_draft(req)
         self._pending_draft.clear()
         for act in self._pending_onepass:
-            if self.paged:
-                self._prefill_onepass_paged(act)
-            else:
-                self._prefill_dense(act)
+            self._prefill_onepass(act)
         self._pending_onepass.clear()
         if self.prefilling:
             self._prefill_step()
@@ -954,7 +888,7 @@ class Scheduler:
         ``expert_tokens`` (layers, E), the real tokens each layer routed
         to each expert since the counters were last zeroed, and
         ``dropped_tokens`` (layers,).  None for stacks that keep none."""
-        counts = self.pool.moe_counts() if self.paged else None
+        counts = self.pool.moe_counts()
         if counts is not None:
             self.stats.moe_routed_tokens = [
                 int(n) for n in counts["expert_tokens"].sum(axis=0)]
@@ -996,21 +930,17 @@ class Scheduler:
 
     def _decode_round(self) -> None:
         tel = self.telemetry
-        if self.paged:
-            targets = {a.slot: int(self._index[a.slot])
-                       for a in self.active.values()}
-            with tel.span("kv.ensure"):
-                self._ensure_decode_pages(self.pool, targets)
-            groups = self._width_split()
-        else:
-            groups = [(0, None)]
+        targets = {a.slot: int(self._index[a.slot])
+                   for a in self.active.values()}
+        with tel.span("kv.ensure"):
+            self._ensure_decode_pages(self.pool, targets)
+        groups = self._width_split()
         self.stats.decode_steps += 1
         if len(groups) == 1:
             # common path: one full-batch dispatch
-            width = groups[0][0] if self.paged else None
             with tel.span("decode.dispatch"):
                 logits = self.session.step(self._next_token[:, None],
-                                           self._index, width=width)
+                                           self._index, width=groups[0][0])
             self.stats.decode_slot_steps += self.pool.num_slots
             self._sample_and_accept(logits, [(a.slot, a) for a in
                                              self.active.values()])
@@ -1084,15 +1014,12 @@ class Scheduler:
                 else self.spec_tokens
             cap[act.slot] = min(k_row + 1, act.req.max_new - act.ntok + 1)
         Kv = int(cap.max())
-        if self.paged:
-            targets = {a.slot: int(base[a.slot]) + int(cap[a.slot]) - 1
-                       for a in acts}
-            with tel.span("kv.ensure"):
-                self._ensure_decode_pages(self.pool, targets)
-                self._ensure_decode_pages(self.draft.layout, targets)
-            W = self._table_bucket(int((base + cap).max()))
-        else:
-            W = None
+        targets = {a.slot: int(base[a.slot]) + int(cap[a.slot]) - 1
+                   for a in acts}
+        with tel.span("kv.ensure"):
+            self._ensure_decode_pages(self.pool, targets)
+            self._ensure_decode_pages(self.draft.layout, targets)
+        W = self._table_bucket(int((base + cap).max()))
         block = np.zeros((B, Kv), np.int32)
         block[:, 0] = self._next_token
         picks = [(act.slot, act) for act in acts]
@@ -1118,7 +1045,7 @@ class Scheduler:
                 for t in range(Kv):
                     valid_t = (cap > t).astype(np.int32)
                     idx_t = np.where(self._index >= 0, base + t,
-                                     self._idle_index).astype(np.int32)
+                                     -1).astype(np.int32)
                     with tel.span("decode.dispatch"):
                         logits = self.draft.step(block[:, t:t + 1], idx_t,
                                                  valid=valid_t, width=W)

@@ -1,29 +1,26 @@
-"""DecodeSession: ONE decode surface for every cache layout and family.
+"""DecodeSession: ONE decode surface for every model family.
 
-Before this module the serving stack picked model entry points by hand
-— a dense decode vs a paged one, a full prefill vs a chunked slice —
-once per cache layout, and every caller (scheduler, engine, launcher,
-examples, benchmarks) re-encoded the choice.  A
-:class:`DecodeSession` pairs model weights with a
-:class:`repro.serve.kv_cache.CacheLayout` and exposes the whole decode
-lifecycle as four calls:
+A :class:`DecodeSession` pairs model weights with a
+:class:`repro.serve.kv_cache.PagedLayout` and exposes the whole decode
+lifecycle as five calls:
 
-  ``prefill(rid, prompt)``        full-prompt prefill into the layout
-  ``prefill_chunk(rid, ...)``     one chunked-prefill slice (paged)
-  ``step(tokens, index, ...)``    K >= 1 tokens per row, any layout
+  ``prefill(rid, prompt)``        one-shot prompt prefill into the pool
+  ``prefill_chunk(rid, ...)``     one chunked-prefill slice
+  ``step(tokens, index, ...)``    K >= 1 tokens per row
+  ``draft_block(tok0, ...)``      fused drafter round
   ``snapshot() / restore(...)``   recurrent-state rollback
 
 ``step`` is the single write primitive: ``tokens`` is (B, K) with
 K >= 1, so a speculative verify (K tokens at once) and a classic decode
-(K = 1) are the same call, on dense rows, paged pools, and hybrid
-stacks alike.  ``snapshot``/``restore`` bound what speculation can
-break: attention KV never needs rollback (stale positions are causally
-masked and overwritten), so a snapshot is exactly the recurrent leaves
-— empty, and free, for attention-only models.
+(K = 1) are the same call, on attention, latent and hybrid stacks
+alike.  ``snapshot``/``restore`` bound what speculation can break:
+attention KV never needs rollback (stale positions are causally masked
+and overwritten), so a snapshot is exactly the recurrent leaves —
+empty, and free, for attention-only models.
 
 The jitted executables are module-level and keyed by the (hashable)
-config, so scheduler, drafter, engine, and benchmark sessions of the
-same model share every compile.
+config, so scheduler, drafter and benchmark sessions of the same model
+share every compile.
 """
 from __future__ import annotations
 
@@ -36,7 +33,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import lm
-from repro.serve.kv_cache import CacheLayout, PagedLayout
+from repro.serve.kv_cache import PagedLayout
 
 # module-level jits (config is a hashable frozen dataclass): compiled
 # executables are shared across DecodeSession instances, so spinning up
@@ -56,11 +53,6 @@ def _chunk_fn(params, cfg, toks, cache, tables, hist, plen, last_pos):
 
 
 @partial(jax.jit, static_argnums=(1,), donate_argnums=(3,))
-def _step_fn(params, cfg, tokens, cache, index, valid):
-    return lm.lm_decode(params, cfg, tokens, cache, index, valid=valid)
-
-
-@partial(jax.jit, static_argnums=(1,), donate_argnums=(3,))
 def _step_tables_fn(params, cfg, tokens, cache, index, tables, valid):
     return lm.lm_decode(params, cfg, tokens, cache, index, tables=tables,
                         valid=valid)
@@ -77,14 +69,9 @@ def _draft_unroll(params, cfg, tok0, cache, index, valid, steps, tables):
     fed, logits_all = [], []
     for t in range(steps):
         valid_t = jnp.minimum(jnp.maximum(valid - t, 0), 1)
-        if tables is None:
-            idx_t = index + t
-            logits, cache = lm.lm_decode(params, cfg, tok, cache, idx_t,
-                                         valid=valid_t)
-        else:
-            idx_t = jnp.where(index >= 0, index + t, -1)
-            logits, cache = lm.lm_decode(params, cfg, tok, cache, idx_t,
-                                         tables=tables, valid=valid_t)
+        idx_t = jnp.where(index >= 0, index + t, -1)
+        logits, cache = lm.lm_decode(params, cfg, tok, cache, idx_t,
+                                     tables=tables, valid=valid_t)
         fed.append(tok[:, 0])
         logits_all.append(logits[:, 0])
         # greedy device feed; the scheduler redraws from the returned
@@ -95,12 +82,6 @@ def _draft_unroll(params, cfg, tok0, cache, index, valid, steps, tables):
 
 
 @partial(jax.jit, static_argnums=(1, 6), donate_argnums=(3,))
-def _draft_fn(params, cfg, tok0, cache, index, valid, steps):
-    return _draft_unroll(params, cfg, tok0, cache, index, valid, steps,
-                         None)
-
-
-@partial(jax.jit, static_argnums=(1, 6), donate_argnums=(3,))
 def _draft_tables_fn(params, cfg, tok0, cache, index, valid, steps,
                      tables):
     return _draft_unroll(params, cfg, tok0, cache, index, valid, steps,
@@ -108,7 +89,7 @@ def _draft_tables_fn(params, cfg, tok0, cache, index, valid, steps,
 
 
 class DecodeSession:
-    """Weights + a cache layout, driven through one decode API.
+    """Weights + a paged cache layout, driven through one decode API.
 
     The session owns the jit boundaries and the cache pytree rebinding
     (every step donates the layout's cache and rebinds the result);
@@ -117,15 +98,10 @@ class DecodeSession:
     directly.
     """
 
-    def __init__(self, cfg: ModelConfig, params, layout: CacheLayout):
+    def __init__(self, cfg: ModelConfig, params, layout: PagedLayout):
         self.cfg = cfg
         self.params = params
         self.layout = layout
-
-    @property
-    def paged(self) -> bool:
-        """True when the KV cache is the paged (scattered-page) layout."""
-        return isinstance(self.layout, PagedLayout)
 
     def set_params(self, params) -> None:
         """Hot-swap weights (cache layout depends only on the config)."""
@@ -142,14 +118,8 @@ class DecodeSession:
     def _call_chunk(self, *args):
         return _chunk_fn(*args)
 
-    def _call_step(self, *args):
-        return _step_fn(*args)
-
     def _call_step_tables(self, *args):
         return _step_tables_fn(*args)
-
-    def _call_draft(self, *args):
-        return _draft_fn(*args)
 
     def _call_draft_tables(self, *args):
         return _draft_tables_fn(*args)
@@ -157,13 +127,14 @@ class DecodeSession:
     # -- prefill -----------------------------------------------------------
     def prefill(self, rid, prompt: np.ndarray,
                 bucket: Optional[int] = None) -> jax.Array:
-        """Full-prompt prefill for ONE request, written into its
-        slot/pages.  ``bucket`` right-pads the prompt to a shape bucket
-        (attention-only stacks; logits still read at the true last
-        token); None prefills at exact length (recurrent families —
-        padding would poison their state).  Returns the last-token
-        logits row (V,) as float32, still on device (the caller copies
-        it to the host when it needs it).
+        """Full-prompt prefill for ONE request, scattered into its
+        pages (and its slot's recurrent-state rows).  ``bucket``
+        right-pads the prompt to a shape bucket (attention-only stacks;
+        logits still read at the true last token); None prefills at
+        exact length (recurrent families — padding would poison their
+        state).  Returns the last-token logits row (V,) as float32,
+        still on device (the caller copies it to the host when it needs
+        it).
         """
         P = int(len(prompt))
         L = bucket or P
@@ -172,19 +143,8 @@ class DecodeSession:
         logits, cache = self._call_prefill(
             self.params, self.cfg, jnp.asarray(toks),
             jnp.asarray([P - 1], jnp.int32))
-        if self.paged:
-            self.layout.insert_prefill(rid, cache, P)
-        else:
-            self.layout.insert(rid, cache)
+        self.layout.insert_prefill(rid, cache, P)
         return logits[0, -1].astype(jnp.float32)
-
-    def prefill_batch(self, tokens: jax.Array) -> jax.Array:
-        """Uniform-length batch prefill filling EVERY slot row (the
-        engine path; slot layouts only).  Returns logits (B, 1, V)."""
-        logits, cache = self._call_prefill(self.params, self.cfg,
-                                           tokens, None)
-        self.layout.insert_batch(cache)
-        return logits
 
     def prefill_chunk(self, rid, chunk: np.ndarray, hist_len: int,
                       prompt_len: int, chunk_bucket: int,
@@ -218,29 +178,23 @@ class DecodeSession:
         """One decode/verify step: K >= 1 tokens per row.
 
         tokens: (B, K) int32; index: (B,) first-token write positions
-        (-1 = idle row on paged layouts); valid: optional (B,) real
-        token counts (speculative verify / rollback replay); width:
-        block-table columns (paged; pow2-bucketed by the caller); rows:
-        restrict a paged step to these slots (ragged grouping — only
-        when ``layout.supports_row_subset``); tables: explicit
+        (-1 = idle row); valid: optional (B,) real token counts
+        (speculative verify / rollback replay); width: block-table
+        columns (pow2-bucketed by the caller); rows: restrict the step
+        to these slots (ragged grouping — only when
+        ``layout.supports_row_subset``); tables: explicit
         block-table array overriding the layout's (padded group calls).
         Returns logits (B, K, V) still on device (callers cast/copy).
         """
         tok = jnp.asarray(tokens, jnp.int32)
         idx = jnp.asarray(index, jnp.int32)
         v = None if valid is None else jnp.asarray(valid, jnp.int32)
-        if self.paged:
-            if tables is None:
-                tables = self.layout.step_kwargs(width=width,
-                                                 rows=rows)["tables"]
-            else:
-                tables = jnp.asarray(tables)
-            logits, self.layout.cache = self._call_step_tables(
-                self.params, self.cfg, tok, self.layout.cache, idx,
-                tables, v)
+        if tables is None:
+            tables = self.layout.step_tables(width=width, rows=rows)
         else:
-            logits, self.layout.cache = self._call_step(
-                self.params, self.cfg, tok, self.layout.cache, idx, v)
+            tables = jnp.asarray(tables)
+        logits, self.layout.cache = self._call_step_tables(
+            self.params, self.cfg, tok, self.layout.cache, idx, tables, v)
         return logits
 
     def draft_block(self, tok0: np.ndarray, index: np.ndarray,
@@ -252,9 +206,9 @@ class DecodeSession:
         greedy argmax.
 
         tok0: (B, 1) the pending token per row; index: (B,) its write
-        position (-1 = idle row on paged layouts); valid: (B,) real
-        steps per row (rows freeze beyond, like a masked multi-token
-        step); width: block-table columns (paged).  Returns (logits
+        position (-1 = idle row); valid: (B,) real steps per row (rows
+        freeze beyond, like a masked multi-token step); width:
+        block-table columns.  Returns (logits
         (B, steps, V), fed tokens (B, steps)) still on device — the
         caller redraws proposals from the logits with the request's
         real sampling function and repairs the cache where its samples
@@ -265,15 +219,10 @@ class DecodeSession:
         B = tok.shape[0]
         v = jnp.full((B,), steps, jnp.int32) if valid is None \
             else jnp.asarray(valid, jnp.int32)
-        if self.paged:
-            tables = self.layout.step_kwargs(width=width)["tables"]
-            logits, fed, self.layout.cache = self._call_draft_tables(
-                self.params, self.cfg, tok, self.layout.cache, idx, v,
-                steps, tables)
-        else:
-            logits, fed, self.layout.cache = self._call_draft(
-                self.params, self.cfg, tok, self.layout.cache, idx, v,
-                steps)
+        tables = self.layout.step_tables(width=width)
+        logits, fed, self.layout.cache = self._call_draft_tables(
+            self.params, self.cfg, tok, self.layout.cache, idx, v, steps,
+            tables)
         return logits, fed
 
     # -- rollback ----------------------------------------------------------

@@ -1,9 +1,6 @@
-"""Train/serve step builders — the functions the launcher jits and the
-dry-run lowers for every (arch x shape) cell.
+"""Train step builders — the functions the launchers jit.
 
-``make_lm_train_step``   -> train_4k cells (loss + grads + optimizer)
-``make_lm_prefill_step`` -> prefill_32k cells
-``make_lm_decode_step``  -> decode_32k / long_500k cells
+``make_lm_train_step``   -> LM loss + grads + optimizer
 ``make_gan_steps``       -> the paper's CycleGAN (generator+discriminator)
 """
 from __future__ import annotations
@@ -84,20 +81,6 @@ def make_lm_population_fns(cfg: ModelConfig, opt_cfg: OptimizerConfig,
         return new_state["params"], new_state["opt_state"], metrics
 
     return init, train_step, metric
-
-
-def make_lm_prefill_step(cfg: ModelConfig) -> Callable:
-    def prefill_step(params, batch):
-        return lm.lm_prefill(params, cfg, batch)
-
-    return prefill_step
-
-
-def make_lm_decode_step(cfg: ModelConfig) -> Callable:
-    def decode_step(params, tokens, cache, index):
-        return lm.lm_decode(params, cfg, tokens, cache, index)
-
-    return decode_step
 
 
 def init_lm_state(cfg: ModelConfig, opt_cfg: OptimizerConfig,

@@ -15,7 +15,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DOCS = os.path.join(ROOT, "docs")
 
 # every CLI module exposing build_parser() <-> its docs/flags.md section
-CLIS = ["serve", "ltfb", "distributed", "train", "dryrun", "lineage"]
+CLIS = ["serve", "ltfb", "distributed", "train", "lineage"]
 
 
 def _parser_flags(mod: str):

@@ -5,6 +5,7 @@ max_queue / cancel regressions the gateway relies on."""
 import asyncio
 import dataclasses
 import json
+import threading
 
 import jax
 import numpy as np
@@ -68,6 +69,25 @@ def _ndjson(resp: str):
 def _run(coro, timeout=300):
     return asyncio.new_event_loop().run_until_complete(
         asyncio.wait_for(coro, timeout))
+
+
+def _hold_after_first_token(sched, rid) -> threading.Event:
+    """Gate the scheduler's step: once ``rid`` has a token, ``step()``
+    does nothing until the returned event is set, so the request stays
+    in flight however fast the machine decodes.  The gateway's driver
+    keeps taking in, shedding and answering other requests meanwhile."""
+    release = threading.Event()
+    inner = sched.step
+
+    def step():
+        act = sched.active.get(rid)
+        if act is not None and act.tokens and not release.is_set():
+            release.wait(0.01)
+            return
+        inner()
+
+    sched.step = step
+    return release
 
 
 # -- scheduler-level admission regressions ----------------------------------
@@ -194,29 +214,33 @@ def test_gateway_sheds_overload_with_429(served):
 
 def test_gateway_deadline_shed_is_429(served):
     cfg, params = served
-    sched = Scheduler(cfg, params, num_slots=1, max_len=72)
+    sched = Scheduler(cfg, params, num_slots=1, max_len=24)
+    release = _hold_after_first_token(sched, "hog")
     gw = Gateway(sched)
 
     async def go():
         await gw.start()
         r, w = await asyncio.open_connection("127.0.0.1", gw.port)
-        body = json.dumps({"prompt": _prompt(cfg), "max_new": 64,
+        body = json.dumps({"prompt": _prompt(cfg), "max_new": 8,
                            "rid": "hog"}).encode()
         w.write((f"POST /v1/generate HTTP/1.1\r\nHost: t\r\n"
                  f"Content-Length: {len(body)}\r\n\r\n").encode() + body)
         await w.drain()
-        await r.readuntil(b"token")    # slot occupied
+        await r.readuntil(b"token")    # slot occupied, held there
         late = await _http(gw.port, "POST", "/v1/generate",
                            {"prompt": _prompt(cfg), "max_new": 4,
                             "rid": "late", "ttft_deadline_ms": 0.001})
+        release.set()
+        hog = await r.read()           # the hog streams to its end
         w.close()
         await gw.stop()
-        return late
+        return late, hog
 
-    late = _run(go())
+    late, hog = _run(go())
     assert _status(late) == 429
     assert "deadline" in late
     assert sched.stats.shed_deadline == 1
+    assert len(sched.results["hog"]) == 8 and b"error" not in hog
 
 
 def test_gateway_bad_request_is_400(served):
@@ -381,23 +405,26 @@ def test_gateway_idempotency_conflict_while_in_flight(served):
     """A retry racing the original gets 409 + Retry-After instead of a
     duplicate stream; seeding from a journal map works the same way."""
     cfg, params = served
-    sched = Scheduler(cfg, params, num_slots=1, max_len=136)
+    sched = Scheduler(cfg, params, num_slots=1, max_len=24)
+    release = _hold_after_first_token(sched, "slow")
     gw = Gateway(sched)
 
     async def go():
         await gw.start()
         r, w = await asyncio.open_connection("127.0.0.1", gw.port)
-        body = json.dumps({"prompt": _prompt(cfg), "max_new": 128,
+        body = json.dumps({"prompt": _prompt(cfg), "max_new": 8,
                            "rid": "slow"}).encode()
         w.write((f"POST /v1/generate HTTP/1.1\r\nHost: t\r\n"
                  f"Idempotency-Key: race\r\n"
                  f"Content-Length: {len(body)}\r\n\r\n").encode() + body)
         await w.drain()
-        await r.readuntil(b"token")            # admitted, streaming
+        await r.readuntil(b"token")            # admitted, held in flight
         dup = await _http(gw.port, "POST", "/v1/generate",
                           {"prompt": _prompt(cfg), "max_new": 4},
                           headers={"Idempotency-Key": "race"})
-        w.transport.abort()                    # let the run end fast
+        release.set()
+        await r.read()                         # the original completes
+        w.close()
         await gw.stop()
         return dup
 

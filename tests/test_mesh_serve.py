@@ -1,5 +1,5 @@
 """Mesh-serving tests: 1-vs-8-emulated-device token parity across
-layouts/families, host-0 admission broadcast determinism (follower
+families, host-0 admission broadcast determinism (follower
 replay over the wire encoding), drain-mode hot swap on the mesh, the
 shard_map paged-gather dispatch vs the global oracle, and the
 satellite serving features (fused draft round, per-row speculative
@@ -69,9 +69,8 @@ def trace(cfg, n=5, seed=3):
     return [rng.integers(0, cfg.vocab_size, 5 + 3 * i).astype(np.int32)
             for i in range(n)]
 
-def serve(cls, cfg, params, prompts, layout="paged", **kw):
-    s = cls(cfg, params, num_slots=4, max_len=40, block_size=4,
-            layout=layout, **kw)
+def serve(cls, cfg, params, prompts, **kw):
+    s = cls(cfg, params, num_slots=4, max_len=40, block_size=4, **kw)
     for i, p in enumerate(prompts):
         s.submit(Request(rid=i, prompt=p, max_new=6))
     return s.run(max_steps=400), s
@@ -83,34 +82,24 @@ def serve(cls, cfg, params, prompts, layout="paged", **kw):
 # ---------------------------------------------------------------------------
 
 
-def test_mesh_token_parity_attention_paged_and_dense():
-    """qwen3 on a 4x2 (data, model) mesh: paged AND dense layouts are
-    token-identical to the single-device scheduler on the same trace."""
-    _run_mesh_script(_PRELUDE + r"""
-cfg = f32_cfg("qwen3-0.6b")
-params, _ = init_lm(cfg, jax.random.PRNGKey(0))
-prompts = trace(cfg)
-for layout in ("paged", "dense"):
-    base, _ = serve(Scheduler, cfg, params, prompts, layout=layout)
-    got, s = serve(MeshScheduler, cfg, params, prompts, layout=layout,
-                   mesh_shape=(4, 2))
-    assert s.pool.num_slots == 4 and s.data_shards == 4
-    for i in base:
-        assert base[i].tolist() == got[i].tolist(), (layout, i)
-print("OK")
-""")
-
-
-def test_mesh_token_parity_hybrid():
-    """jamba (mamba/attention/moe hybrid) on a 2x2 mesh: the paged
-    pools shard over data, the recurrent state rows shard over data,
-    tokens unchanged."""
-    _run_mesh_script(_PRELUDE + r"""
-cfg = f32_cfg("jamba-1.5-large-398b")
+@pytest.mark.parametrize("arch,data,model", [
+    ("qwen3-0.6b", 4, 2),              # attention, GQA
+    ("deepseek-moe-16b", 2, 2),        # attention + MoE
+    ("jamba-1.5-large-398b", 2, 2),    # mamba/attention/moe hybrid
+    ("xlstm-125m", 2, 2),              # pure recurrent
+], ids=["qwen3", "deepseek-moe", "jamba", "xlstm"])
+def test_mesh_token_parity(arch, data, model):
+    """On a (data, model) mesh the paged pools shard over data by page
+    and the recurrent state rows by slot: tokens identical to the
+    single-device scheduler on the same trace."""
+    _run_mesh_script(_PRELUDE + f"""
+cfg = f32_cfg({arch!r})
 params, _ = init_lm(cfg, jax.random.PRNGKey(0))
 prompts = trace(cfg, n=4)
 base, _ = serve(Scheduler, cfg, params, prompts)
-got, _ = serve(MeshScheduler, cfg, params, prompts, mesh_shape=(2, 2))
+got, s = serve(MeshScheduler, cfg, params, prompts,
+               mesh_shape=({data}, {model}))
+assert s.pool.num_slots == 4 and s.data_shards == {data}
 for i in base:
     assert base[i].tolist() == got[i].tolist(), i
 print("OK")
@@ -318,30 +307,6 @@ print("OK")
 """)
 
 
-def test_engine_mesh_generate_parity():
-    """Engine.generate over the mesh == Engine.generate single-device
-    (the dry-run decode cell's weights-stationary layout, live)."""
-    _run_mesh_script(r"""
-import dataclasses
-import jax, jax.numpy as jnp, numpy as np
-from repro.configs.registry import get_config
-from repro.models.lm import init_lm
-from repro.serve.engine import Engine
-from repro.serve.mesh import make_serve_mesh
-
-cfg = dataclasses.replace(get_config("qwen3-0.6b", smoke=True),
-                          dtype="float32")
-params, _ = init_lm(cfg, jax.random.PRNGKey(0))
-toks = jnp.asarray(np.random.default_rng(0).integers(
-    0, cfg.vocab_size, (8, 6)).astype(np.int32))
-base = Engine(cfg, params, max_len=32).generate(toks, 5)
-mesh = make_serve_mesh(4, 2)
-got = Engine(cfg, params, max_len=32, mesh=mesh).generate(toks, 5)
-assert np.asarray(base).tolist() == np.asarray(got).tolist()
-print("OK")
-""")
-
-
 # ---------------------------------------------------------------------------
 # satellites: fused draft round, per-row depth, draft compatibility
 # ---------------------------------------------------------------------------
@@ -357,8 +322,8 @@ def _spec_serve(cfg, params, prompts, draft=None, k=0, **kw):
 
 def test_fused_draft_round_is_two_dispatches():
     """The fused draft step collapses a round from K+1 draft dispatches
-    to ONE (plus the verify): tokens identical either way, on dense and
-    hybrid (rollback) stacks."""
+    to ONE (plus the verify): tokens identical either way, on attention
+    and hybrid (rollback) stacks."""
     for arch in ("qwen3-0.6b", "jamba-1.5-large-398b"):
         cfg = _f32_cfg(arch)
         params, _ = init_lm(cfg, KEY)

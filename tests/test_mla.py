@@ -322,8 +322,27 @@ def test_configs_hold_the_published_numbers():
     assert abs(small.param_count() - 4.009e9) / 4.009e9 < 0.002
 
 
-def test_latent_stack_needs_the_paged_pool():
+@pytest.mark.parametrize("arch", ["deepseek-v2-lite", "qwen3-0.6b"],
+                         ids=["latent", "attention"])
+def test_latent_stack_needs_the_paged_pool(arch):
+    """Attention and latent stacks keep their cache in page pools only:
+    a cache without ``pages`` is refused."""
     from repro.models.lm import init_cache
 
     with pytest.raises(ValueError, match="paged pool"):
-        init_cache(get_config("deepseek-v2-lite", smoke=True), 2, 16)
+        init_cache(get_config(arch, smoke=True), 2)
+
+
+def test_recurrent_stack_needs_no_pages():
+    """A pure-recurrent stack (xLSTM) keeps only per-slot state rows:
+    its cache needs no page pool, and no leaf is paged."""
+    from repro.models.lm import init_cache
+
+    cfg = get_config("xlstm-125m", smoke=True)
+    cache, axes = init_cache(cfg, 3)
+    leaves = jax.tree.leaves(cache)
+    assert leaves and all(x.shape[1] == 3 for x in leaves)
+    is_axes = lambda t: isinstance(t, tuple) and all(  # noqa: E731
+        a is None or isinstance(a, str) for a in t)
+    assert not any("pages" in a for a in jax.tree.leaves(
+        axes, is_leaf=is_axes))

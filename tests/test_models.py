@@ -2,8 +2,8 @@
 
 Every assigned architecture instantiates its REDUCED config, runs one
 forward/train step on CPU, and asserts output shapes + no NaNs.  The
-parity tests prove prefill+decode == full forward for every family
-(the strongest correctness property of the serving path).
+parity tests prove prefill + paged decode == full forward for every
+family (the strongest correctness property of the serving path).
 """
 import dataclasses
 
@@ -15,8 +15,8 @@ import pytest
 from repro.configs.base import MeshConfig, OptimizerConfig, replace
 from repro.configs.registry import LM_ARCH_IDS, get_config
 from repro.data.tokens import train_batch
-from repro.models.lm import (init_cache, init_lm, lm_decode, lm_forward,
-                             lm_prefill)
+from repro.models.lm import init_lm, lm_decode, lm_forward, lm_prefill
+from repro.serve.kv_cache import PagedLayout
 from repro.train.steps import init_lm_state, make_lm_train_step
 
 KEY = jax.random.PRNGKey(0)
@@ -60,7 +60,10 @@ def test_arch_smoke_train_step(arch):
 
 @pytest.mark.parametrize("arch", LM_ARCH_IDS)
 def test_prefill_decode_parity(arch):
-    """prefill(prompt) + decode steps == full forward, per family."""
+    """prefill(prompt) + decode steps == full forward, per family: the
+    prompt's cache is scattered into a page pool
+    (``PagedLayout.insert_prefill``) and every later token is decoded
+    through the block tables."""
     cfg = get_config(arch, smoke=True)
     cfg = dataclasses.replace(cfg, dtype="float32")
     if cfg.moe is not None:   # dropless so train-mode forward matches
@@ -81,19 +84,18 @@ def test_prefill_decode_parity(arch):
     toks = jax.random.randint(KEY, (B, S), 0, cfg.vocab_size)
     logits_full, _ = lm_forward(params, cfg, {"tokens": toks})
     lg_pre, cache0 = lm_prefill(params, cfg, {"tokens": toks[:, :Sp]})
-    cache_full, _ = init_cache(cfg, B, S)
-
-    def fit(dst, src):
-        if dst.shape == src.shape:
-            return src
-        pad = [(0, d - s) for d, s in zip(dst.shape, src.shape)]
-        return jnp.pad(src, pad)
-
-    cache = jax.tree.map(fit, cache_full, cache0)
+    lay = PagedLayout(cfg, num_slots=B, num_pages=2 * B * S // 4,
+                      block_size=4)
+    for b in range(B):
+        lay.admit(b, S, slot=b)
+        lay.insert_prefill(b, jax.tree.map(lambda x: x[:, b:b + 1], cache0),
+                           Sp)
+        lay.ensure(b, S)
+    cache, tables = lay.cache, jnp.asarray(lay.tables)
     errs = [float(jnp.max(jnp.abs(lg_pre[:, -1] - logits_full[:, Sp - 1])))]
     for i in range(Sp, S):
         lg, cache = lm_decode(params, cfg, toks[:, i:i + 1], cache,
-                              jnp.int32(i))
+                              jnp.full((B,), i, jnp.int32), tables=tables)
         errs.append(float(jnp.max(jnp.abs(lg[:, 0] - logits_full[:, i]))))
     assert max(errs) < 1e-3, (arch, errs)
 

@@ -10,9 +10,9 @@ import numpy as np
 import pytest
 
 from repro.configs.base import replace
-from repro.configs.registry import get_config
+from repro.configs.registry import LM_ARCH_IDS, get_config
 from repro.models.lm import init_lm, lm_forward
-from repro.serve.kv_cache import BlockManager, CachePool, blocks_for
+from repro.serve.kv_cache import BlockManager, PagedLayout, blocks_for
 from repro.serve.scheduler import Request, Scheduler
 
 KEY = jax.random.PRNGKey(0)
@@ -53,15 +53,17 @@ def test_block_manager_accounting():
     bm = BlockManager(num_blocks=8, block_size=4)
     assert blocks_for(1, 4) == 1 and blocks_for(4, 4) == 1 \
         and blocks_for(5, 4) == 2
-    bm.allocate("a", 10)          # 3 blocks
+    bm.reserve("a", 10)           # 3 blocks budgeted ...
+    assert bm.used_blocks == 0 and bm.pending_blocks == 3
+    bm.ensure("a", 10)            # ... and materialized
     assert bm.used_blocks == 3 and bm.free_blocks == 5
     assert bm.can_allocate(20) and not bm.can_allocate(21)
     bm.extend("a", 13)            # grow to 4 blocks
     assert bm.used_blocks == 4 and bm.high_water == 4
     with pytest.raises(ValueError):
-        bm.allocate("a", 4)       # double-alloc
+        bm.reserve("a", 4)        # double-alloc
     with pytest.raises(RuntimeError):
-        bm.allocate("b", 100)     # over budget
+        bm.reserve("b", 100)      # over budget
     assert len(bm.free("a")) == 4      # all refcounts hit zero
     assert bm.used_blocks == 0 and bm.high_water == 4
     assert bm.allocs == 4 and bm.frees == 4
@@ -69,15 +71,18 @@ def test_block_manager_accounting():
 
 def test_cache_pool_slot_lifecycle():
     cfg = _f32_cfg("qwen3-0.6b")
-    pool = CachePool(cfg, num_slots=2, max_len=16, block_size=4)
+    pool = PagedLayout(cfg, num_slots=2, num_pages=8, block_size=4,
+                       max_seq=16)
     assert pool.can_admit(16) and not pool.can_admit(17)
-    s0 = pool.admit("r0", 12)
-    s1 = pool.admit("r1", 12)
+    s0, _ = pool.admit("r0", 12)
+    s1, _ = pool.admit("r1", 12)
+    pool.ensure("r0", 12)
+    pool.ensure("r1", 12)
     assert {s0, s1} == {0, 1} and pool.free_slots == 0
     assert not pool.can_admit(4)            # no slot left
     pool.release("r0")
     assert pool.free_slots == 1 and pool.blocks.used_blocks == 3
-    assert pool.admit("r2", 8) == s0        # slot reuse
+    assert pool.admit("r2", 8)[0] == s0     # slot reuse
     pool.release("r1")
     pool.release("r2")
     assert pool.free_slots == 2 and pool.blocks.used_blocks == 0
@@ -109,6 +114,25 @@ def test_scheduler_greedy_parity_and_slot_reuse():
     assert sched.pool.blocks.allocs == sched.pool.blocks.frees > 0
     # never more in flight than slots
     assert sched.stats.queue_depth_max >= 1
+
+
+@pytest.mark.parametrize("arch", [a for a in LM_ARCH_IDS
+                                  if get_config(a, smoke=True).family
+                                  != "vlm"])
+def test_scheduler_serves_reference_tokens(arch):
+    """Every family the scheduler serves: three requests over two
+    slots (so a slot is reused), each served token the argmax of a
+    full-context forward over prompt + the tokens served before it."""
+    cfg = _f32_cfg(arch)
+    params, _ = init_lm(cfg, KEY)
+    toks = _prompts(cfg, 3, 12)
+    sched = Scheduler(cfg, params, num_slots=2, max_len=24, block_size=4)
+    reqs = [Request(rid=i, prompt=toks[i, :5 + 3 * i], max_new=3)
+            for i in range(3)]
+    for r in reqs:
+        sched.submit(r)
+    assert len(sched.run(max_steps=200)) == 3
+    _assert_greedy_parity(cfg, params, sched, reqs)
 
 
 @pytest.mark.parametrize("arch", ["jamba-1.5-large-398b", "xlstm-125m"])
@@ -172,32 +196,6 @@ def test_scheduler_submit_validation():
                              max_new=4, temperature=0.7))
 
 
-def test_scheduler_static_policy_needs_more_steps():
-    """Same trace, same kernels: static batching must spend at least as
-    many decode steps as continuous (strictly more on mixed lengths)."""
-    cfg = _f32_cfg("qwen3-0.6b")
-    params, _ = init_lm(cfg, KEY)
-    toks = _prompts(cfg, 6, 8)
-    lens = [8, 4, 8, 4, 8, 4]
-    news = [4, 12, 4, 12, 4, 12]
-
-    def serve(policy):
-        s = Scheduler(cfg, params, num_slots=2, max_len=24, block_size=4,
-                      policy=policy)
-        for i in range(6):
-            s.submit(Request(rid=i, prompt=toks[i, :lens[i]],
-                             max_new=news[i]))
-        r = s.run(max_steps=500)
-        assert len(r) == 6
-        return s
-
-    st, ct = serve("static"), serve("continuous")
-    assert ct.stats.decode_steps < st.stats.decode_steps
-    # identical outputs: policy changes scheduling, not results
-    for i in range(6):
-        assert st.results[i].tolist() == ct.results[i].tolist()
-
-
 def test_scheduler_hot_swap_mid_stream():
     """Swapping weights between steps changes subsequent tokens without
     disturbing the in-flight cache bookkeeping."""
@@ -222,60 +220,6 @@ def test_scheduler_hot_swap_mid_stream():
     n_before = 5    # 1 prefill token + 4 decode steps
     assert swapped[:n_before].tolist() == base[:n_before].tolist()
     assert swapped.tolist() != base.tolist()
-
-
-# ---------------------------------------------------------------------------
-# engine satellites
-# ---------------------------------------------------------------------------
-
-
-def test_engine_sample_rejects_missing_key():
-    from repro.serve.engine import Engine
-
-    cfg = get_config("qwen3-0.6b", smoke=True)
-    params, _ = init_lm(cfg, KEY)
-    engine = Engine(cfg, params, max_len=32)
-    prompts = jnp.ones((1, 8), jnp.int32)
-    with pytest.raises(ValueError, match="PRNG key"):
-        engine.generate(prompts, steps=4, temperature=0.8)
-    # sampling with a key still works
-    out = engine.generate(prompts, steps=4, temperature=0.8, key=KEY)
-    assert out.shape == (1, 12)
-
-
-def test_engine_cache_template_allocated_once(monkeypatch):
-    from repro.models import lm as lm_mod
-    from repro.serve.engine import Engine
-
-    cfg = get_config("qwen3-0.6b", smoke=True)
-    params, _ = init_lm(cfg, KEY)
-    engine = Engine(cfg, params, max_len=32)
-    calls = []
-    orig = lm_mod.init_cache
-    monkeypatch.setattr(lm_mod, "init_cache",
-                        lambda *a, **k: calls.append(a) or orig(*a, **k))
-    prompts = jnp.ones((2, 8), jnp.int32)
-    engine.generate(prompts, steps=4)
-    engine.generate(prompts, steps=4)
-    engine.generate(prompts, steps=4)
-    assert len(calls) == 1      # template hoisted out of generate()
-
-
-def test_engine_greedy_matches_full_forward_argmax():
-    """Satellite: greedy generate == full-context argmax, token for
-    token."""
-    cfg = _f32_cfg("qwen3-0.6b")
-    params, _ = init_lm(cfg, KEY)
-    from repro.serve.engine import Engine
-
-    engine = Engine(cfg, params, max_len=24)
-    toks = jnp.asarray(_prompts(cfg, 2, 8))
-    out = np.asarray(engine.generate(toks, steps=6))
-    for b in range(2):
-        for i in range(6):
-            lg, _ = lm_forward(
-                params, cfg, {"tokens": jnp.asarray(out[None, b, :8 + i])})
-            assert int(jnp.argmax(lg[0, -1])) == int(out[b, 8 + i]), (b, i)
 
 
 # ---------------------------------------------------------------------------

@@ -1,5 +1,5 @@
-"""Decode-API tests: the family-agnostic DecodeSession / CacheLayout
-protocol, K-token write/verify parity, recurrent snapshot/restore
+"""Decode-API tests: the family-agnostic DecodeSession over the paged
+layout, K-token write/verify parity, recurrent snapshot/restore
 round-trips, population speculative decoding (token-identity vs
 target-only decode), prefix pinning, and the ragged gather-width
 split."""
@@ -12,8 +12,9 @@ import pytest
 
 from repro.configs.base import replace
 from repro.configs.registry import get_config
-from repro.models.lm import init_lm, lm_decode, lm_prefill
-from repro.serve.kv_cache import PagedLayout, SlotLayout
+from repro.models.lm import init_cache, init_lm, lm_decode, lm_prefill
+from repro.serve import session as session_mod
+from repro.serve.kv_cache import PagedLayout
 from repro.serve.scheduler import Request, Scheduler
 from repro.serve.session import DecodeSession
 
@@ -33,41 +34,83 @@ def _prompts(cfg, n, max_len, seed=1):
         jax.random.PRNGKey(seed), (n, max_len), 0, cfg.vocab_size), np.int32)
 
 
+def _prefilled(cfg, params, toks, total, block_size=4):
+    """A session over a fresh paged layout with row b of ``toks``
+    prefilled as request b in slot b, pages ensured for ``total``
+    tokens."""
+    B = toks.shape[0]
+    lay = PagedLayout(cfg, B, B * -(-total // block_size),
+                      block_size=block_size)
+    sess = DecodeSession(cfg, params, lay)
+    for b in range(B):
+        lay.admit(b, total, slot=b)
+        sess.prefill(b, toks[b])
+        lay.ensure(b, total)
+    return sess
+
+
 # ---------------------------------------------------------------------------
 # DecodeSession parity vs the direct model entry points
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("arch", ["qwen3-0.6b", "jamba-1.5-large-398b"])
+@pytest.mark.parametrize("arch", ["qwen3-0.6b", "jamba-1.5-large-398b",
+                                  "xlstm-125m"])
 def test_session_step_matches_direct_lm_decode(arch):
-    """session.step on a SlotLayout is the old dense decode loop: same
-    tokens as calling lm_prefill + lm_decode by hand."""
+    """session.prefill + session.step give the same tokens as calling
+    lm_prefill, PagedLayout.insert_prefill and lm_decode(tables=) by
+    hand."""
     cfg = _f32_cfg(arch)
     params, _ = init_lm(cfg, KEY)
     toks = _prompts(cfg, 2, 6)
 
-    # by hand: the pre-DecodeSession flow
+    # by hand: the model entry points over a paged pool
     logits, cache = lm_prefill(params, cfg, {"tokens": jnp.asarray(toks)})
     hand = [np.asarray(jnp.argmax(logits[:, -1], axis=-1))]
-    from repro.models.lm import init_cache
-    full, _ = init_cache(cfg, 2, 16)
-    cache = jax.tree.map(
-        lambda d, s: jax.lax.dynamic_update_slice(
-            d, s.astype(d.dtype), (0,) * d.ndim), full, cache)
+    lay = PagedLayout(cfg, 2, 8, block_size=4)
+    for b in range(2):
+        lay.admit(b, 16, slot=b)
+        lay.insert_prefill(b, jax.tree.map(lambda x: x[:, b:b + 1], cache),
+                           6)
+        lay.ensure(b, 16)
+    cache, tables = lay.cache, jnp.asarray(lay.tables)
     for i in range(3):
         logits, cache = lm_decode(params, cfg, hand[-1][:, None], cache,
-                                  jnp.int32(6 + i))
+                                  jnp.full((2,), 6 + i, jnp.int32),
+                                  tables=tables)
         hand.append(np.asarray(jnp.argmax(logits[:, -1], axis=-1)))
 
-    sess = DecodeSession(cfg, params, SlotLayout(cfg, 2, 16))
-    logits = sess.prefill_batch(jnp.asarray(toks))
-    got = [np.asarray(jnp.argmax(logits[:, -1], axis=-1))]
+    lay = PagedLayout(cfg, 2, 8, block_size=4)
+    sess = DecodeSession(cfg, params, lay)
+    got = []
+    for b in range(2):
+        lay.admit(b, 16, slot=b)
+        got.append(int(jnp.argmax(sess.prefill(b, toks[b]))))
+        lay.ensure(b, 16)
+    got = [np.asarray(got)]
     index = np.full((2,), 6, np.int32)
     for i in range(3):
-        logits = sess.step(got[-1][:, None], index + i)
+        logits = sess.step(got[-1][:, None], index + i, width=4)
         got.append(np.asarray(jnp.argmax(logits[:, -1], axis=-1)))
     for h, g in zip(hand, got):
         assert h.tolist() == g.tolist()
+
+
+def test_session_jits_only_the_paged_programs():
+    """The session's jitted programs are exactly the paged four, and the
+    decode step lowers under the name the chip benchmark reads its
+    trace by (``jit__step_tables_fn``)."""
+    jitted = {name for name, fn in vars(session_mod).items()
+              if isinstance(fn, jax.stages.Wrapped)}
+    assert jitted == {"_prefill_fn", "_chunk_fn", "_step_tables_fn",
+                      "_draft_tables_fn"}
+    cfg = _f32_cfg("qwen3-0.6b")
+    params = jax.eval_shape(lambda: init_lm(cfg, KEY)[0])
+    cache = jax.eval_shape(lambda: init_cache(cfg, 2, pages=(8, 4))[0])
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa
+    text = session_mod._step_tables_fn.lower(
+        params, cfg, i32(2, 1), cache, i32(2), i32(2, 4), None).as_text()
+    assert "module @jit__step_tables_fn" in text
 
 
 # ---------------------------------------------------------------------------
@@ -75,13 +118,9 @@ def test_session_step_matches_direct_lm_decode(arch):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("arch,layout", [
-    ("qwen3-0.6b", "paged"),
-    ("qwen3-0.6b", "dense"),
-    ("jamba-1.5-large-398b", "paged"),
-    ("xlstm-125m", "dense"),
-])
-def test_k_token_step_matches_sequential(arch, layout):
+@pytest.mark.parametrize("arch", ["qwen3-0.6b", "deepseek-moe-16b",
+                                  "jamba-1.5-large-398b", "xlstm-125m"])
+def test_k_token_step_matches_sequential(arch):
     """One step(tokens, k=K) writes the same cache state and returns
     the same per-position logits as K single-token steps — the verify
     primitive speculative decoding relies on."""
@@ -92,16 +131,11 @@ def test_k_token_step_matches_sequential(arch, layout):
     feed = _prompts(cfg, 1, K, seed=9)[0]       # arbitrary verify block
 
     def make_sess():
-        lay = PagedLayout(cfg, 1, 12, block_size=4) if layout == "paged" \
-            else SlotLayout(cfg, 1, 24, block_size=4)
+        lay = PagedLayout(cfg, 1, 12, block_size=4)
         sess = DecodeSession(cfg, params, lay)
-        if layout == "paged":
-            lay.admit("r", 6 + K + 1)
-        else:
-            lay.admit("r", 6 + K + 1)
+        lay.admit("r", 6 + K + 1)
         sess.prefill("r", prompt)
-        if layout == "paged":
-            lay.ensure("r", 6 + K)
+        lay.ensure("r", 6 + K + 1)
         return sess
 
     # K sequential single-token steps
@@ -109,22 +143,19 @@ def test_k_token_step_matches_sequential(arch, layout):
     seq_logits = []
     for t in range(K):
         lg = seq.step(feed[t].reshape(1, 1), np.asarray([6 + t], np.int32),
-                      width=4 if layout == "paged" else None)
+                      width=4)
         seq_logits.append(np.asarray(lg[0, 0].astype(jnp.float32)))
     # one K-token verify step
     multi = make_sess()
-    lg = multi.step(feed.reshape(1, K), np.asarray([6], np.int32),
-                    width=4 if layout == "paged" else None)
+    lg = multi.step(feed.reshape(1, K), np.asarray([6], np.int32), width=4)
     lg = np.asarray(lg[0].astype(jnp.float32))
     for t in range(K):
         np.testing.assert_allclose(lg[t], seq_logits[t],
                                    atol=1e-4, rtol=1e-4)
     # the cache states agree too: one more step from each must match
     nxt = np.asarray([[int(np.argmax(seq_logits[-1]))]], np.int32)
-    a = seq.step(nxt, np.asarray([6 + K], np.int32),
-                 width=4 if layout == "paged" else None)
-    b = multi.step(nxt, np.asarray([6 + K], np.int32),
-                   width=4 if layout == "paged" else None)
+    a = seq.step(nxt, np.asarray([6 + K], np.int32), width=4)
+    b = multi.step(nxt, np.asarray([6 + K], np.int32), width=4)
     np.testing.assert_allclose(np.asarray(a.astype(jnp.float32)),
                                np.asarray(b.astype(jnp.float32)),
                                atol=1e-4, rtol=1e-4)
@@ -169,10 +200,8 @@ def test_snapshot_restore_roundtrip(arch):
     cfg = _f32_cfg(arch)
     params, _ = init_lm(cfg, KEY)
     toks = _prompts(cfg, 2, 6)
-    lay = SlotLayout(cfg, 2, 24, block_size=4)
-    sess = DecodeSession(cfg, params, lay)
-    assert lay.has_recurrent
-    sess.prefill_batch(jnp.asarray(toks))
+    sess = _prefilled(cfg, params, toks, 24)
+    assert sess.layout.has_recurrent
     index = np.full((2,), 6, np.int32)
 
     snap = sess.snapshot()
@@ -197,9 +226,7 @@ def test_snapshot_is_a_copy_not_a_view():
     cfg = _f32_cfg("xlstm-125m")
     params, _ = init_lm(cfg, KEY)
     toks = _prompts(cfg, 1, 6)
-    lay = SlotLayout(cfg, 1, 16)
-    sess = DecodeSession(cfg, params, lay)
-    sess.prefill_batch(jnp.asarray(toks))
+    sess = _prefilled(cfg, params, toks, 16)
     snap = sess.snapshot()
     before = [np.asarray(s) for s in snap]
     for _ in range(3):                   # donating steps mutate the pool
@@ -269,16 +296,16 @@ def test_spec_decode_temperature_identity():
         == {k: v.tolist() for k, v in serve(draft, 2).items()}
 
 
-def test_spec_decode_dense_layout_and_eos():
-    """Spec rounds on the dense layout, with an EOS that lands inside
-    an accepted block: generation stops AT the eos token."""
+def test_spec_decode_and_eos():
+    """Spec rounds with an EOS that lands inside an accepted block:
+    generation stops AT the eos token."""
     cfg = _f32_cfg("qwen3-0.6b")
     params, _ = init_lm(cfg, KEY)
     toks = _prompts(cfg, 1, 8)
 
     def serve(dp, k, eos=None):
         s = Scheduler(cfg, params, num_slots=1, max_len=32, block_size=4,
-                      layout="dense", draft_params=dp, spec_tokens=k)
+                      draft_params=dp, spec_tokens=k)
         s.submit(Request(rid=0, prompt=toks[0], max_new=8, eos_id=eos))
         return s.run(max_steps=200)[0]
 

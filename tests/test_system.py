@@ -2,7 +2,7 @@
 
 The full LTFB pipeline: synthetic JAG -> bundled files -> distributed
 data store -> CycleGAN trainers -> tournament -> validation; plus the
-serving engine and the checkpoint/restart lifecycle.
+checkpoint/restart lifecycle.
 """
 
 import jax
@@ -102,31 +102,3 @@ def test_full_pipeline_store_to_training(tmp_path):
     finally:
         loader.close()
     assert store.stats.file_opens == len(part)   # preload: one open each
-
-
-def test_serve_engine_generates():
-    from repro.configs.registry import get_config
-    from repro.models.lm import init_lm
-    from repro.serve.engine import Engine
-
-    cfg = get_config("qwen3-0.6b", smoke=True)
-    params, _ = init_lm(cfg, jax.random.PRNGKey(0))
-    engine = Engine(cfg, params, max_len=48)
-    prompts = jnp.ones((2, 16), jnp.int32)
-    out = engine.generate(prompts, steps=8)
-    assert out.shape == (2, 24)
-    assert bool(jnp.all(out >= 0)) and bool(jnp.all(out < cfg.vocab_size))
-    # determinism of greedy decode
-    out2 = engine.generate(prompts, steps=8)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(out2))
-
-
-def test_dryrun_registry_covers_spec():
-    """32 cells: 10 archs x 3 shapes + 2 sub-quadratic long_500k."""
-    from repro.configs.registry import dryrun_cells
-    cells = dryrun_cells()
-    assert len(cells) == 32
-    archs = {a for a, _ in cells}
-    assert len(archs) == 10
-    long_archs = {a for a, s in cells if s == "long_500k"}
-    assert long_archs == {"xlstm-125m", "jamba-1.5-large-398b"}
