@@ -112,14 +112,16 @@ def paged_attention_sharded(mesh, q, k_pages, v_pages, layer, tables,
 
 def paged_attention(q, k_pages, v_pages, layer, tables, lengths,
                     interpret: bool = None):
-    """Gather-decode/verify attention over scattered KV pages of one
-    layer of a stacked pool.
+    """Decode/verify attention over scattered KV pages of one layer of
+    a stacked pool.
 
     q: (B, H, D), or (B, K, H, D) for a K-token speculative-verify
     step; k_pages/v_pages: (layers, P, bs, Hkv, D); layer: int32
     scalar; tables: (B, W); lengths:
     (B,) valid KV tokens for the FIRST query of each row (query t sees
-    ``lengths + t``) -> same rank as q.
+    ``lengths + t``; 0 marks an idle row, whose output is zeros) ->
+    same rank as q.  The kernel streams each row's live pages a block
+    at a time and reads nothing for an idle row.
 
     Dispatch (decided at trace time — deliberately NOT a jit boundary,
     so a mesh trace can never reuse a single-device lowering):

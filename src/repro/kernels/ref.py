@@ -39,13 +39,14 @@ def paged_attention_ref(q: jax.Array, k_pages: jax.Array,
     physical page ids; lengths:
     (B,) valid KV tokens for the FIRST query of each row — query t of a
     row sees ``lengths[b] + t`` tokens, the intra-block causal
-    staircase.  Returns the same rank as ``q``.  Gathers each row's
-    pages into logical order and runs masked attention; HBM traffic is
-    O(B * W * bs) — the Pallas kernel performs the same gather
-    page-by-page in VMEM.  The gather width W should be bucketed by the
-    caller to the batch's true maximum page count (the scheduler
-    additionally GROUPS rows by pow2 width so one long request does not
-    widen every row's gather on CPU).
+    staircase; 0 marks an idle row, which returns zeros.  Returns the
+    same rank as ``q``.  Gathers each row's pages into logical order
+    and runs masked attention; HBM traffic is O(B * W * bs), where the
+    Pallas kernel streams only each row's live pages through VMEM.  The
+    gather width W should be bucketed by the caller to the batch's true
+    maximum page count (the scheduler additionally GROUPS rows by pow2
+    width so one long request does not widen every row's gather on
+    CPU).
     """
     squeeze = q.ndim == 3
     if squeeze:
@@ -66,6 +67,7 @@ def paged_attention_ref(q: jax.Array, k_pages: jax.Array,
     s = jnp.where(valid[:, :, None, None, :], s, -jnp.inf)
     w = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bthgk,bkhd->bthgd", w, vg)
+    out = jnp.where((lengths > 0)[:, None, None, None, None], out, 0.0)
     out = out.reshape(B, K, H, D).astype(q.dtype)
     return out[:, 0] if squeeze else out
 

@@ -458,9 +458,10 @@ def attention_decode_paged(params: Params, cfg: ModelConfig, x: jax.Array,
     and read by the kernel at ``layer`` (carried through the layer
     scan, never sliced out); index: int32 (B,) per-row write
     position of the FIRST token, with -1 marking inactive rows (their
-    KV is routed to the null page and their output is garbage the
-    caller discards); tables: (B, W) int32 physical page ids; valid:
-    optional int32 (B,) count of real tokens per row — tokens t >=
+    KV is routed to the null page, their attention reads no page and
+    is zeros, and the caller discards their output); tables: (B, W)
+    int32 physical page ids; valid: optional int32 (B,) count of real
+    tokens per row — tokens t >=
     valid[b] (speculative padding / replay no-ops) scatter to the null
     page so they can never corrupt a page the row does not own yet.
     Token t writes at ``index[b] + t`` and attends causally over
@@ -490,7 +491,8 @@ def attention_decode_paged(params: Params, cfg: ModelConfig, x: jax.Array,
     v = _scatter_pages(cache["v"], layer,
                        v_new.reshape(B * K, *v_new.shape[2:]),
                        page.reshape(-1), off.reshape(-1))
-    lengths = widx + 1                       # KV tokens seen by query 0
+    # KV tokens seen by query 0; idle rows read nothing (length 0)
+    lengths = jnp.where(index >= 0, widx + 1, 0)
     # the compiled Pallas kernel on TPU, the jnp oracle elsewhere
     out = ops.paged_attention(q, k, v, layer, tables, lengths)
     out = out.reshape(B, K, cfg.q_dim) @ params["wo"]

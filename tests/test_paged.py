@@ -35,22 +35,36 @@ def _prompts(cfg, n, max_len, seed=1):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("B,H,Hkv,D,bs,W", [
-    (2, 4, 4, 32, 4, 3),     # MHA
-    (3, 8, 2, 32, 8, 2),     # GQA 4:1
-    (2, 4, 1, 64, 4, 4),     # MQA
+@pytest.mark.parametrize("B,H,Hkv,D,bs,W,lengths,block_pages", [
+    pytest.param(2, 4, 4, 32, 4, 3, None, 16, id="2-4-4-32-4-3"),   # MHA
+    pytest.param(3, 8, 2, 32, 8, 2, None, 16, id="3-8-2-32-8-2"),   # GQA 4:1
+    pytest.param(2, 4, 1, 64, 4, 4, None, 16, id="2-4-1-64-4-4"),   # MQA
+    # an idle row (length 0) between live ones: zeros from both
+    pytest.param(3, 4, 2, 32, 4, 6, (9, 0, 17), 2, id="idle-row"),
+    # 26 tokens over blocks of 2 pages (8 tokens): four blocks, the last
+    # one part-way filled
+    pytest.param(2, 4, 2, 32, 4, 7, (26, 3), 2, id="multi-block"),
+    # W = 7 is no multiple of block_pages = 3: the last block is short
+    pytest.param(2, 4, 2, 32, 4, 7, (24, 11), 3, id="ragged-width"),
+    # length 6: tokens 6..9 of a 4-token verify cross the 8-token block
+    # boundary at 8
+    pytest.param(2, 8, 2, 32, 4, 5, (6, 14), 2, id="staircase-block"),
+    pytest.param(2, 4, 2, 32, 4, 5, (13, 4), 1, id="one-page-blocks"),
+    pytest.param(2, 4, 2, 32, 4, 3, (9, 5), 8, id="blocks-past-width"),
 ])
-@pytest.mark.parametrize("K", [1, 3])
+@pytest.mark.parametrize("K", [1, 3, 4])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("layer", [0, 2])
-def test_paged_attention_kernel_matches_ref(B, H, Hkv, D, bs, W, K, dtype,
-                                            layer):
+def test_paged_attention_kernel_matches_ref(B, H, Hkv, D, bs, W, lengths,
+                                            block_pages, K, dtype, layer):
     """Pallas gather-decode/verify kernel (interpret) == jnp oracle
     over scattered pages of one layer of a stacked pool, null-page rows
     included; K > 1 exercises the speculative-verify staircase (query t
     reaches lengths + t).  Every layer of the pool holds other values,
-    so a wrong or ignored layer index reads the wrong pages."""
-    from repro.kernels.ops import paged_attention
+    so a wrong or ignored layer index reads the wrong pages.  Rows of
+    length 0 are idle: both return zeros for them.  The kernel walks a
+    row's pages ``block_pages`` at a time."""
+    from repro.kernels.paged_attention import paged_attention
     from repro.kernels.ref import paged_attention_ref
 
     P, layers = 9, 3           # pool pages (+1 null), stacked layers
@@ -62,29 +76,57 @@ def test_paged_attention_kernel_matches_ref(B, H, Hkv, D, bs, W, K, dtype,
     vp = jax.random.normal(ks[2], (layers, P + 1, bs, Hkv, D), dtype)
     rng = np.random.default_rng(0)
     # scattered, non-contiguous tables; trailing entries null
-    tables = rng.permutation(P)[:B * W].reshape(B, W).astype(np.int32)
-    lengths = rng.integers(1, W * bs - K + 2, size=(B,)).astype(np.int32)
+    tables = rng.permutation(max(P, B * W))[:B * W].reshape(B, W) % P
+    tables = tables.astype(np.int32)
+    if lengths is None:
+        lengths = rng.integers(1, W * bs - K + 2, size=(B,))
+    lengths = np.minimum(np.asarray(lengths, np.int32), W * bs - K + 1)
     for b in range(B):
-        used = blocks_for(int(lengths[b]) + K - 1, bs)
+        used = blocks_for(int(lengths[b]) + K - 1, bs) if lengths[b] else 0
         tables[b, used:] = P    # null page
     args = (jnp.asarray(tables), jnp.asarray(lengths))
     out = paged_attention(q, kp, vp, jnp.int32(layer), *args,
-                          interpret=True)
+                          block_pages=block_pages, interpret=True)
     ref = paged_attention_ref(q, kp, vp, jnp.int32(layer), *args)
     assert out.shape == q.shape
+    assert not np.isnan(np.asarray(ref, np.float32)).any()
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                atol=tol, rtol=tol)
+    idle = lengths == 0
+    assert not np.asarray(out, np.float32)[idle].any()
     # the oracle on the layer alone (a one-layer stack) agrees, and
     # another layer's pages give another answer
     alone = paged_attention_ref(q, kp[layer:layer + 1], vp[layer:layer + 1],
                                 0, *args)
     np.testing.assert_array_equal(np.asarray(alone, np.float32),
                                   np.asarray(ref, np.float32))
-    other = paged_attention(q, kp, vp, jnp.int32(1), *args, interpret=True)
+    other = paged_attention(q, kp, vp, jnp.int32(1), *args,
+                            block_pages=block_pages, interpret=True)
     assert not np.allclose(np.asarray(other, np.float32),
                            np.asarray(out, np.float32), atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("K", [1, 4], ids=["decode", "verify"])
+def test_paged_attention_grid_has_no_table_width(K):
+    """The kernel's grid is one step per row: a table-width (page)
+    dimension in it would bring back a pipeline step per page of every
+    slot, live or idle.  Read from the traced jaxpr, at a table width
+    (24) unlike the batch (4) and every other size."""
+    from repro.kernels.paged_attention import paged_attention
+
+    B, H, Hkv, D, bs, W = 4, 8, 2, 128, 16, 24
+    S = jax.ShapeDtypeStruct
+    pool = S((2, 65, bs, Hkv, D), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(
+        lambda q, k, v, t, n: paged_attention(q, k, v, 1, t, n))(
+            S((B, K, H, D), jnp.bfloat16), pool, pool,
+            S((B, W), jnp.int32), S((B,), jnp.int32))
+    calls = [e for e in jaxpr.jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    assert tuple(calls[0].params["grid_mapping"].grid) == (B,)
 
 
 @pytest.mark.parametrize("mode", ["decode", "chunk"])

@@ -80,6 +80,30 @@ def test_paged_kernel_compiles_at_qwen3_shapes(one_chip, k_tokens):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("width", [192, 128], ids=["chat", "overload"])
+@pytest.mark.parametrize("k_tokens", [1, 4], ids=["decode", "verify"])
+def test_paged_kernel_compiles_at_serving_cell_shapes(one_chip, k_tokens,
+                                                      width):
+    """The qwen3 cells' widest kernel calls: 64 rows over 192 table
+    columns (chat, 3,072 tokens) or 128 (overload, 2,048), a 2,560-page
+    pool (+ the null page), 28 layers stacked; Mosaic checks the real
+    page block against VMEM and the tiling."""
+    H, Hkv, D = QWEN3.num_heads, QWEN3.num_kv_heads, QWEN3.head_dim
+    rows, pages = 64, 2560
+    q = (rows, H, D) if k_tokens == 1 else (rows, k_tokens, H, D)
+    pool = (QWEN3.num_layers, pages + 1, BS, Hkv, D)
+    compiled = jax.jit(pa.paged_attention).lower(
+        _spec(q, jnp.bfloat16, one_chip),
+        _spec(pool, jnp.bfloat16, one_chip),
+        _spec(pool, jnp.bfloat16, one_chip),
+        _spec((), jnp.int32, one_chip),
+        _spec((rows, width), jnp.int32, one_chip),
+        _spec((rows,), jnp.int32, one_chip)).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
+        == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024 ** 2
+
+
 @pytest.mark.parametrize("k_tokens", [1, 4], ids=["decode", "verify"])
 def test_latent_kernel_compiles_at_deepseek_v2_widths(one_chip, k_tokens):
     """16 query heads over one latent row of 576 values (512 of them the
